@@ -38,14 +38,9 @@ def load():
             return _LIB
         _TRIED = True
         try:
-            # make's dependency check is cheap and keeps the binary in sync
-            # with edited sources; fall back to a prebuilt .so if make is
-            # unavailable but the artifact exists
-            try:
-                _build()
-            except (RuntimeError, subprocess.SubprocessError, OSError):
-                if not os.path.exists(_SO):
-                    raise
+            # always through make: its dependency check is cheap, and a
+            # binary this checkout did not build is never loaded
+            _build()
             lib = ctypes.CDLL(_SO)
             _bind(lib)  # AttributeError here = stale-ABI binary
         except (OSError, RuntimeError, subprocess.SubprocessError,

@@ -158,10 +158,8 @@ class AutoTuner:
         the reference's planner-then-trials flow."""
         import jax
 
-        from .mesh import _device_pool
-
         if world_size is None:
-            world_size = len(_device_pool(2))
+            world_size = jax.device_count()
         steps = int(self.cfg.get("steps_per_trial", 3))
         cands = self.plan(world_size)
         if not cands:

@@ -21,11 +21,7 @@ def init(role_maker=None, is_collective=True, strategy=None, log_level="INFO"):
     _strategy = strategy or DistributedStrategy()
     if _strategy.world_degree == 1:
         # default: all devices to data parallel, reference-style
-        from .mesh import _device_pool
-
-        pool = _device_pool(2)
-        if len(pool) > 1:
-            _strategy.hybrid_configs.dp_degree = len(pool)
+        _strategy.hybrid_configs.dp_degree = jax.device_count()
     hcg = HybridCommunicateGroup(_strategy)
     set_hybrid_communicate_group(hcg)
     return hcg

@@ -39,9 +39,7 @@ def init_parallel_env(strategy: DistributedStrategy | None = None):
             return ParallelEnv()
         if strategy is None:
             strategy = DistributedStrategy()
-            from .mesh import _device_pool
-
-            strategy.hybrid_configs.dp_degree = len(_device_pool(2))
+            strategy.hybrid_configs.dp_degree = jax.device_count()
         set_hybrid_communicate_group(HybridCommunicateGroup(strategy))
         return ParallelEnv()
     coord = os.environ.get("PADDLE_TPU_COORDINATOR")
@@ -66,10 +64,8 @@ def init_parallel_env(strategy: DistributedStrategy | None = None):
                 raise
     if strategy is None:
         strategy = DistributedStrategy()
-        # default: pure DP over every device in the mesh pool
-        from .mesh import _device_pool
-
-        strategy.hybrid_configs.dp_degree = len(_device_pool(2))
+        # default: pure DP over every device
+        strategy.hybrid_configs.dp_degree = jax.device_count()
     hcg = HybridCommunicateGroup(strategy)
     set_hybrid_communicate_group(hcg)
     _initialized = True

@@ -25,7 +25,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from ..core.jaxcompat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from .. import nn
 

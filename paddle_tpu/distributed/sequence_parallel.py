@@ -20,7 +20,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from ..core.jaxcompat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 __all__ = ["ring_attention", "ulysses_attention"]
 

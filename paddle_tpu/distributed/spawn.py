@@ -97,13 +97,14 @@ def spawn(func, args=(), nprocs=-1, join=True, daemon=False, **options):
     training. Options: start_method ('spawn' default — the CUDA-safe choice
     in the reference; JAX parents are multithreaded so fork carries the same
     hazard), env (dict of extra child env vars, e.g. JAX_PLATFORMS/XLA_FLAGS
-    for CPU test meshes), ips / coordinator for multi-host."""
-    if nprocs == -1:
-        nprocs = int(os.environ.get("PADDLE_TPU_NUM_DEVICES", "0")) or None
-        if nprocs is None:
-            import jax
+    for CPU test meshes), ips / coordinator for multi-host.
 
-            nprocs = max(jax.local_device_count(), 1)
+    ``nprocs=-1`` means ``$PADDLE_TPU_NUM_DEVICES`` or one process: on one
+    host a single process drives every local chip. The parent never asks
+    JAX for a device count — a process that has initialised a backend holds
+    the chips its children need."""
+    if nprocs == -1:
+        nprocs = int(os.environ.get("PADDLE_TPU_NUM_DEVICES", "0")) or 1
     start_method = options.get("start_method", "spawn")
     ctx = mp.get_context(start_method)
     return_queue = ctx.Queue()

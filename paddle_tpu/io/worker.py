@@ -14,7 +14,7 @@ array payload.
 Fork-safety: workers NEVER touch jax — the default collate runs a
 numpy-only twin (``_np_collate``), and Tensor leaves from custom collates
 are unwrapped to numpy before transport. (A forked child driving the
-parent's TPU client/tunnel would be undefined behavior, same reason the
+parent's TPU client would be undefined behavior, same reason the
 reference forbids CUDA in workers.)
 
 Batch order is deterministic: batch i is assigned to worker ``i % W`` and

@@ -10,8 +10,6 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
-from . import active_platform
-
 NEG = -1.0e30
 BT = 8  # batch rows per grid program (one sublane tile)
 
@@ -24,10 +22,6 @@ def i0():
     # index-map constants must be i32: under jax_enable_x64 a python literal
     # traces as i64 and Mosaic rejects the mixed index tuple
     return jnp.int32(0)
-
-
-def interpret_mode() -> bool:
-    return active_platform() not in ("tpu",)
 
 
 def lanes(s: int) -> int:

@@ -43,8 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from ..core.jaxcompat import shape_dtype_struct as _sds, typeof as _typeof
-from . import x64_off
+from . import interpret_mode as _interpret_mode, x64_off
 
 __all__ = ["flash_attention_pallas", "flash_attn_varlen_pallas"]
 
@@ -316,14 +315,8 @@ def _out_vma(*examples):
     for e in examples:
         if e is None:
             continue
-        vma |= getattr(_typeof(e), "vma", frozenset())
+        vma |= jax.typeof(e).vma
     return vma
-
-
-def _interpret_mode() -> bool:
-    from . import active_platform
-
-    return active_platform() not in ("tpu",)
 
 
 def _use_jnp_mirror(vma, dropout_p=0.0, bq=128, bk=128) -> bool:
@@ -581,10 +574,11 @@ def _core_fwd(q, k, v, qseg, kseg, mask, seed, causal, sm_scale,
                 pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM),
             ],
             out_shape=[
-                _sds((BH, Sq, D), q.dtype, vma=vma),
-                _sds((BH, Sq, 1), jnp.float32, vma=vma),
+                jax.ShapeDtypeStruct((BH, Sq, D), q.dtype, vma=vma),
+                jax.ShapeDtypeStruct((BH, Sq, 1), jnp.float32, vma=vma),
             ],
             interpret=interpret,
+            name="flash_attention_fwd",
         )(q, k, v, *extra_args)
     return (out, lse), False
 
@@ -684,8 +678,9 @@ def _flash_core_bwd(causal, sm_scale, dropout_p, heads, mask_mode, res, cot):
             ] + dq_specs,
             out_specs=pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0),
                                    memory_space=pltpu.VMEM),
-            out_shape=_sds((BH, Sq, D), q.dtype, vma=vma),
+            out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype, vma=vma),
             interpret=interpret,
+            name="flash_attention_bwd_dq",
         )(q, k, v, g, lse, delta, glse, *dq_args)
 
         dk, dv = pl.pallas_call(
@@ -708,10 +703,11 @@ def _flash_core_bwd(causal, sm_scale, dropout_p, heads, mask_mode, res, cot):
                 pl.BlockSpec((1, bk, D), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM),
             ],
             out_shape=[
-                _sds((BH, Sk, D), k.dtype, vma=vma),
-                _sds((BH, Sk, D), v.dtype, vma=vma),
+                jax.ShapeDtypeStruct((BH, Sk, D), k.dtype, vma=vma),
+                jax.ShapeDtypeStruct((BH, Sk, D), v.dtype, vma=vma),
             ],
             interpret=interpret,
+            name="flash_attention_bwd_dkv",
         )(q, k, v, g, lse, delta, glse, *dkv_args)
     return (dq, dk, dv) + _int_cots()
 
@@ -834,16 +830,70 @@ def flash_attention_pallas(q, k, v, attn_mask=None, dropout_p=0.0,
         mask, mask_mode = _canon_mask(attn_mask, B, Hq, Sq, Sk)
     seed = _dropout_seed(fixed_seed) if dropout_p > 0 else None
 
-    # [B, S, H, D] -> [B*H, S, D]
-    def to_bhsd(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * Hq, x.shape[1], D)
+    def attend(q, k, v, mask, seed):
+        # [B, S, H, D] -> [B*H, S, D] (B, H local to the shard under a mesh)
+        b, h = q.shape[0], q.shape[2]
 
-    out, _ = _flash_core(to_bhsd(q), to_bhsd(k), to_bhsd(v), None, None,
-                         mask, seed, is_causal, sm_scale,
-                         # lint: allow-host-sync(dropout_p is a Python scalar at trace time)
-                         float(dropout_p),
-                         Hq, mask_mode)
-    return out.reshape(B, Hq, Sq, D).transpose(0, 2, 1, 3)
+        def to_bhsd(x):
+            return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], D)
+
+        out, _ = _flash_core(to_bhsd(q), to_bhsd(k), to_bhsd(v), None, None,
+                             mask, seed, is_causal, sm_scale,
+                             # lint: allow-host-sync(dropout_p is a Python scalar at trace time)
+                             float(dropout_p),
+                             h, mask_mode)
+        return out.reshape(b, h, Sq, D).transpose(0, 2, 1, 3)
+
+    specs = _mesh_shard_specs(B, Hq, mask_mode if mask is not None else None)
+    if specs is None:
+        return attend(q, k, v, mask, seed)
+    mesh, qkv_spec, axes = specs
+
+    def attend_shard(q, k, v, mask, seed):
+        if seed is not None and axes:  # decorrelate the shards' dropout
+            seed = seed + jax.lax.axis_index(axes).astype(jnp.int32)
+        return attend(q, k, v, mask, seed)
+
+    rep = jax.sharding.PartitionSpec()
+    return jax.shard_map(
+        attend_shard, mesh=mesh,
+        in_specs=(qkv_spec, qkv_spec, qkv_spec, rep, rep),
+        out_specs=qkv_spec)(q, k, v, mask, seed)
+
+
+def _mesh_shard_specs(B, H, mask_mode):
+    """How to run the Mosaic kernel under the installed hybrid mesh, or None
+    to call it directly.
+
+    GSPMD cannot partition a Mosaic custom call ("Mosaic kernels cannot be
+    automatically partitioned"), so on a multi-device TPU mesh the kernel
+    runs once per shard under ``shard_map``: batch over the data axes, heads
+    over 'mp' (the layout ColumnParallelLinear already gives q/k/v).
+    Interpret mode lowers to plain HLO that GSPMD partitions itself, and a
+    caller already inside a manual region (pp pipeline, ring attention)
+    keeps its own mapping. Returns ``(mesh, qkv_spec, sharded_axes)``."""
+    if _interpret_mode() or jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    from ..distributed.mesh import current_mesh
+
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return None
+    if mask_mode not in (None, "one"):
+        raise NotImplementedError(
+            f"flash attention under a {mesh.size}-device mesh takes no mask "
+            f"or one shared by every batch row and head; got a "
+            f"'{mask_mode}'-mode mask")
+    shape = dict(zip(mesh.axis_names, mesh.devices.shape))
+    data = tuple(a for a in ("dp", "sharding") if shape[a] > 1)
+    if B % math.prod(shape[a] for a in data):
+        data = ()  # batch not divisible: every data rank attends to all rows
+    heads = ("mp",) if shape["mp"] > 1 else ()
+    if H % shape["mp"]:
+        raise ValueError(
+            f"{H} attention heads do not divide over mp={shape['mp']}")
+    spec = jax.sharding.PartitionSpec(data or None, None, heads or None, None)
+    return mesh, spec, data + heads
 
 
 def _segments_from_cu(cu, total, pad_to, pad_id):

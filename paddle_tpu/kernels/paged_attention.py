@@ -35,15 +35,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import active_platform, x64_off
+from . import interpret_mode as _interpret_mode, x64_off
 
 __all__ = ["paged_attention", "paged_attention_pallas", "paged_attention_ref"]
 
 NEG_INF = -1e30
-
-
-def _interpret_mode() -> bool:
-    return active_platform() not in ("tpu",)
 
 
 # ---------------------------------------------------------------------------
@@ -90,9 +86,9 @@ def _paged_kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, block_size, sm_scale, max_blocks):
     """Grid (slots, kv_heads, max_blocks); scalar-prefetch refs first.
 
-    q_ref: [1, rep, D] — this kv head's query rows for slot s
+    q_ref: [1, 1, rep, D] — this kv head's query rows for slot s
     k_ref/v_ref: [1, 1, 1, bs, D] — pool block bt[s, j] for this head
-    o_ref: [1, rep, D]; m/l/acc: VMEM scratch carried across j.
+    o_ref: [1, 1, rep, D]; m/l/acc: VMEM scratch carried across j.
     """
     s = pl.program_id(0)
     j = pl.program_id(2)
@@ -107,12 +103,15 @@ def _paged_kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
     # blocks entirely past the context frontier contribute nothing
     @pl.when(j * block_size < ctx)
     def _attend():
-        q = q_ref[0].astype(jnp.float32) * sm_scale          # [rep, D]
+        q = q_ref[0, 0].astype(jnp.float32) * sm_scale       # [rep, D]
         k = k_ref[0, 0, 0].astype(jnp.float32)               # [bs, D]
         v = v_ref[0, 0, 0].astype(jnp.float32)
+        # explicit DEFAULT: the package-wide tensorfloat32 default would
+        # ask Mosaic for Precision.HIGH, which it does not lower
         s_blk = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [rep, bs]
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT)             # [rep, bs]
         pos = j * jnp.int32(block_size) + jax.lax.broadcasted_iota(
             jnp.int32, s_blk.shape, 1)
         s_blk = jnp.where(pos < ctx, s_blk, NEG_INF)
@@ -124,12 +123,13 @@ def _paged_kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT)
 
     @pl.when(j == max_blocks - 1)
     def _finalize():
         l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
 def paged_attention_pallas(q, kv_pool, block_tables, context_lens, *,
@@ -155,21 +155,26 @@ def paged_attention_pallas(q, kv_pool, block_tables, context_lens, *,
         interpret = _interpret_mode()
     bt = block_tables.astype(jnp.int32)
     ctx = context_lens.astype(jnp.int32)
-    q3 = q.reshape(S, Hkv, rep, D).reshape(S, Hkv * rep, D)
+    # [S, Hkv, rep, D]: a (rep, D) block is then the full extent of the
+    # last two dims, which Mosaic tiles for any rep (a (1, rep, D) block
+    # over [S, Hq, D] is neither a multiple of 8 rows nor the full dim)
+    q4 = q.reshape(S, Hkv, rep, D)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block_tables, context_lens
         grid=(S, Hkv, M),
         in_specs=[
             # this slot's query rows for kv head h: rows [h*rep, (h+1)*rep)
-            pl.BlockSpec((1, rep, D), lambda s, h, j, bt, ctx: (s, h, 0)),
+            pl.BlockSpec((1, 1, rep, D),
+                         lambda s, h, j, bt, ctx: (s, h, 0, 0)),
             # K / V pool block bt[s, j] for head h (same pool array twice)
             pl.BlockSpec((1, 1, 1, bs, D),
                          lambda s, h, j, bt, ctx: (bt[s, j], 0, h, 0, 0)),
             pl.BlockSpec((1, 1, 1, bs, D),
                          lambda s, h, j, bt, ctx: (bt[s, j], 1, h, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, rep, D), lambda s, h, j, bt, ctx: (s, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, rep, D),
+                               lambda s, h, j, bt, ctx: (s, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((rep, 1), jnp.float32),   # m
             pltpu.VMEM((rep, 1), jnp.float32),   # l
@@ -182,9 +187,10 @@ def paged_attention_pallas(q, kv_pool, block_tables, context_lens, *,
         out = pl.pallas_call(
             kern,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((S, Hkv * rep, D), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((S, Hkv, rep, D), q.dtype),
             interpret=interpret,
-        )(bt, ctx, q3, kv_pool, kv_pool)
+            name="paged_attention",
+        )(bt, ctx, q4, kv_pool, kv_pool)
     return out.reshape(S, Hq, D)
 
 

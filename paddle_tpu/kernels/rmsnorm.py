@@ -19,23 +19,18 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from ..core.jaxcompat import shape_dtype_struct as _sds, typeof as _typeof
 
-from . import active_platform, x64_off
+from . import interpret_mode as _interpret_mode, x64_off
 
 __all__ = ["rmsnorm_residual_pallas", "rmsnorm_pallas"]
 
 _BLOCK_ROWS = 256
 
 
-def _interpret_mode() -> bool:
-    return active_platform() not in ("tpu",)
-
-
 def _vma(*xs):
     out = frozenset()
     for x in xs:
-        out |= getattr(_typeof(x), "vma", frozenset())
+        out |= jax.typeof(x).vma
     return out
 
 
@@ -126,8 +121,8 @@ def _fwd(x, resid, w, eps, has_resid):
             out_specs=[_row_spec(br, F),
                        pl.BlockSpec((br, 1), lambda i: (i, 0),
                                     memory_space=pltpu.VMEM)],
-            out_shape=[_sds((R, F), x.dtype, vma=vma),
-                       _sds((R, 1), jnp.float32, vma=vma)],
+            out_shape=[jax.ShapeDtypeStruct((R, F), x.dtype, vma=vma),
+                       jax.ShapeDtypeStruct((R, 1), jnp.float32, vma=vma)],
             interpret=interp,
         )(*args)
     return out, rstd
@@ -175,8 +170,8 @@ def _core_bwd(eps, has_resid, res, g):
             out_specs=[_row_spec(br, F),
                        pl.BlockSpec((8, F), lambda i: (i, 0),
                                     memory_space=pltpu.VMEM)],
-            out_shape=[_sds((R, F), x.dtype, vma=vma),
-                       _sds((8 * (R // br), F),
+            out_shape=[jax.ShapeDtypeStruct((R, F), x.dtype, vma=vma),
+                       jax.ShapeDtypeStruct((8 * (R // br), F),
                                             jnp.float32, vma=vma)],
             interpret=interp,
         )(*args)

@@ -32,8 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import interpret_mode as _interpret_mode
 from ._lattice import (BT as _BT, NEG as _NEG, i0 as _i0,
-                       interpret_mode as _interpret_mode,
                        lanes as _lanes, neg32 as _neg32,
                        shift_left as _shift_l, shift_right as _shift_r)
 

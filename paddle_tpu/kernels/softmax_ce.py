@@ -16,23 +16,18 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from ..core.jaxcompat import shape_dtype_struct as _sds, typeof as _typeof
 
-from . import active_platform, x64_off
+from . import interpret_mode as _interpret_mode, x64_off
 
 __all__ = ["softmax_ce_pallas"]
 
 _BLOCK_ROWS = 8
 
 
-def _interpret_mode() -> bool:
-    return active_platform() not in ("tpu",)
-
-
 def _vma(*xs):
     out = frozenset()
     for x in xs:
-        out |= getattr(_typeof(x), "vma", frozenset())
+        out |= jax.typeof(x).vma
     return out
 
 
@@ -99,8 +94,8 @@ def _fwd(x, labels):
                 pl.BlockSpec((br, 1), lambda i: (i, 0), memory_space=pltpu.VMEM),
                 pl.BlockSpec((br, 1), lambda i: (i, 0), memory_space=pltpu.VMEM),
             ],
-            out_shape=[_sds((N, 1), jnp.float32, vma=vma),
-                       _sds((N, 1), jnp.float32, vma=vma)],
+            out_shape=[jax.ShapeDtypeStruct((N, 1), jnp.float32, vma=vma),
+                       jax.ShapeDtypeStruct((N, 1), jnp.float32, vma=vma)],
             interpret=interp,
         )(x, labels.reshape(N, 1).astype(jnp.int32))
     return loss[:, 0], lse
@@ -135,7 +130,7 @@ def _core_bwd(res, g):
             ],
             out_specs=pl.BlockSpec((br, V), lambda i: (i, 0),
                                    memory_space=pltpu.VMEM),
-            out_shape=_sds((N, V), x.dtype, vma=vma),
+            out_shape=jax.ShapeDtypeStruct((N, V), x.dtype, vma=vma),
             interpret=interp,
         )(x, labels.reshape(N, 1).astype(jnp.int32), lse,
           g.reshape(N, 1).astype(jnp.float32))

@@ -66,7 +66,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import telemetry
-from ..kernels import active_platform
 from ..nn.decode import sample_logits
 from ..nn.layer import functional_call, functional_state
 from ..utils import faults
@@ -275,7 +274,9 @@ class LLMEngine:
         self._py_fns: dict = {}            # trace key -> python callable
         self.decode_traces = 0
         self.prefill_traces: dict[int, int] = {}
-        self._donate = (2,) if active_platform() == "tpu" else ()
+        # the KV pool is donated to every step: the step's output pool
+        # reuses its buffer instead of holding two pools in device memory
+        self._donate = (2,)
 
         # roofline cost model (telemetry.cost): each new trace is walked
         # for FLOPs/HBM bytes at creation (jaxpr only, no extra compile);

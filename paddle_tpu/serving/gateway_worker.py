@@ -18,7 +18,6 @@ The spec arrives in ``$PADDLE_GATEWAY_SPEC`` (JSON)::
      "stats_interval_s": 0.05,
      "router": {...},                   # FleetRouter kwargs
      "gateway": {...},                  # Gateway kwargs (journal_dir etc.)
-     "jax_cache_dir": "...",            # shared persistent compile cache
      "ready_file": "/path/ready.json"}  # written once serving + recovered
 
 Once the fleet is healthy and the gateway has finished recovery and is
@@ -44,16 +43,9 @@ def main() -> int:
             "xla_cpu_multi_thread_eigen" not in flags:
         os.environ["XLA_FLAGS"] = \
             flags + " --xla_cpu_multi_thread_eigen=false"
-    if spec.get("jax_cache_dir"):
-        try:
-            import jax
+    from ..utils import compile_cache
 
-            jax.config.update("jax_compilation_cache_dir",
-                              spec["jax_cache_dir"])
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.5)
-        except Exception:  # lint: allow-silent(persistent compile cache is optional; worker runs without it)
-            pass
+    compile_cache.enable()
     from .engine import LLMEngine
     from .gateway import Gateway
     from .replica_worker import build_model

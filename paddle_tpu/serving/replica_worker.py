@@ -91,18 +91,11 @@ def main() -> int:
             "xla_cpu_multi_thread_eigen" not in flags:
         os.environ["XLA_FLAGS"] = \
             flags + " --xla_cpu_multi_thread_eigen=false"
-    if spec.get("jax_cache_dir"):
-        # share one persistent compilation cache across the fleet: every
-        # replica compiles the same traces, only the first should pay XLA
-        try:
-            import jax
+    # one persistent compilation cache for the fleet: every replica compiles
+    # the same traces, only the first should pay XLA
+    from ..utils import compile_cache
 
-            jax.config.update("jax_compilation_cache_dir",
-                              spec["jax_cache_dir"])
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.5)
-        except Exception:  # lint: allow-silent(persistent compile cache is optional; worker runs without it)
-            pass
+    compile_cache.enable()
     from ..telemetry import reqtrace
     from . import kv_fabric
     from .engine import LLMEngine

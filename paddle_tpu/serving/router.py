@@ -616,7 +616,12 @@ class ProcReplica:
     paddle_tpu.serving.replica_worker`` with a model/engine spec in its
     environment and speaks newline-JSON over its stdin/stdout. This is the
     replica a chaos suite can really SIGKILL mid-decode; the router sees
-    EOF/ESRCH and fails its streams over."""
+    EOF/ESRCH and fails its streams over.
+
+    The child runs on whatever backend its environment gives JAX. A chip
+    belongs to one process at a time, so start at most one ``ProcReplica``
+    per chip, and none from a parent that has itself initialised a TPU
+    backend; :class:`LocalReplica` engines share their parent's process."""
 
     kind = "proc"
 
@@ -650,7 +655,6 @@ class ProcReplica:
                    PADDLE_REPLICA_SPEC=json.dumps(self.spec),
                    PADDLE_REPLICA_RID=self.rid,
                    PYTHONPATH=pythonpath)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         env.update(self.extra_env)
         stderr = (open(self.log_path, "ab") if self.log_path
                   else subprocess.DEVNULL)

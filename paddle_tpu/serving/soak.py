@@ -101,7 +101,7 @@ class SoakConfig:
 
     ``fleet_spec`` is the same replica spec dict ``ProcReplica`` /
     ``replica_worker.build_model`` consume (``llama_tiny`` + ``engine``
-    + ``warmup`` + ``jax_cache_dir``). ``chaos`` is the rolling plan:
+    + ``warmup``). ``chaos`` is the rolling plan:
     a list of actions applied round-robin, one per epoch —
 
     - ``{"kind": "none"}`` — quiet epoch (the control);

@@ -6,7 +6,6 @@ import pytest
 
 import paddle_tpu as paddle
 
-from _jax_compat_marks import needs_partial_manual_shard_map
 import paddle_tpu.distributed as dist
 import paddle_tpu.nn as nn
 from paddle_tpu.distributed import DistributedEngine, DistributedStrategy
@@ -151,8 +150,27 @@ class TestEngineHybrid:
         assert l5 < l0
 
 
+class TestMesh:
+    def test_too_few_devices_is_an_error(self, monkeypatch):
+        """build_mesh takes the default backend's devices or fails: it never
+        moves the mesh to another platform to find enough of them."""
+        import jax
+
+        from paddle_tpu.distributed import mesh as mesh_mod
+
+        n = jax.device_count()
+        with pytest.raises(ValueError, match=f"only {n} available"):
+            mesh_mod.build_mesh(degrees={"dp": 2 * n})
+        with pytest.raises(ValueError, match="only 2 available"):
+            mesh_mod.build_mesh(degrees={"dp": 4}, devices=jax.devices()[:2])
+        # the old escape hatches are gone, not just unused
+        monkeypatch.setenv("PADDLE_TPU_MESH_PLATFORM", "tpu")
+        mesh = mesh_mod.build_mesh(degrees={"dp": 2})
+        assert [d.platform for d in mesh.devices.flat] == ["cpu", "cpu"]
+        assert not hasattr(mesh_mod, "_device_pool")
+
+
 class TestPipeline:
-    @needs_partial_manual_shard_map
     def test_spmd_pipeline_matches_sequential(self):
         import jax
         import jax.numpy as jnp

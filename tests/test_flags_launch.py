@@ -95,6 +95,21 @@ class TestLaunchCLI:
         assert r.returncode == 3
         assert "rank 1 failed" in r.stderr
 
+    def test_tpu_backend_is_one_process_per_host(self, capsys):
+        """A chip belongs to one process: --backend tpu refuses a second
+        worker on the node instead of letting the first take every chip,
+        and it overrides an environment that would hide the device."""
+        from paddle_tpu.distributed.launch.main import _parse, _worker_env
+
+        with pytest.raises(SystemExit):
+            _parse(["--backend", "tpu", "--nproc_per_node", "2", "train.py"])
+        assert "one process per host" in capsys.readouterr().err
+        args = _parse(["--backend", "tpu", "train.py"])
+        assert os.environ.get("JAX_PLATFORMS") == "cpu"   # tests/conftest.py
+        assert _worker_env(args, "127.0.0.1:1", 0)["JAX_PLATFORMS"] == "tpu"
+        auto = _parse(["train.py"])
+        assert _worker_env(auto, "127.0.0.1:1", 0)["JAX_PLATFORMS"] == "cpu"
+
 
 class TestPackaging:
     def test_pyproject_is_installable_metadata(self):
@@ -106,7 +121,12 @@ class TestPackaging:
         with open(os.path.join(REPO, "pyproject.toml"), "rb") as f:
             meta = tomllib.load(f)
         assert meta["project"]["name"] == "paddle-tpu"
-        assert "jax" in meta["project"]["dependencies"]
+        # pinned to the installed minor: the code has no branch for another
+        import jax
+
+        minor = ".".join(jax.__version__.split(".")[:2])
+        assert f"jax>={minor},<0.10" in meta["project"]["dependencies"]
+        assert f"jaxlib>={minor},<0.10" in meta["project"]["dependencies"]
 
     def test_elastic_level2_scale_down_and_resume(self, tmp_path):
         """VERDICT r2 #9 done-criterion: kill one worker -> the pod

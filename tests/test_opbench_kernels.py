@@ -3,20 +3,11 @@ and streaming softmax-CE — interpret-mode parity vs the XLA compositions.
 On-chip win/loss measurements live in tools/op_bench_r5.py ->
 OPBENCH_r05.json; these tests gate correctness only."""
 import numpy as np
-import pytest
 
 import jax
 import jax.numpy as jnp
 
 from paddle_tpu import kernels
-
-
-@pytest.fixture(autouse=True)
-def _cpu():
-    kernels.set_platform("cpu")
-    with jax.default_device(jax.devices("cpu")[0]):
-        yield
-    kernels.set_platform(None)
 
 
 class TestFusedRMSNorm:

@@ -16,7 +16,7 @@ from paddle_tpu.models import llama_tiny
 from paddle_tpu.models.llama_pipeline import LlamaPipelineTrainer
 from paddle_tpu.optimizer import AdamW
 
-from _jax_compat_marks import needs_partial_manual_shard_map
+pytestmark = pytest.mark.usefixtures("uninstall_mesh")
 
 
 def _losses(schedule, steps=3, degrees=None, n_micro=4, seed=0):
@@ -36,7 +36,6 @@ def _losses(schedule, steps=3, degrees=None, n_micro=4, seed=0):
     return out
 
 
-@needs_partial_manual_shard_map
 def test_1f1b_matches_fill_drain():
     l_1f1b = _losses("1f1b")
     l_gpipe = _losses("fthenb")
@@ -45,7 +44,6 @@ def test_1f1b_matches_fill_drain():
     np.testing.assert_allclose(l_1f1b, l_gpipe, rtol=2e-4, atol=2e-5)
 
 
-@needs_partial_manual_shard_map
 def test_1f1b_pp4():
     # deeper pipeline, micro-batches > 2*stages (real steady state)
     losses = _losses("1f1b", steps=2, degrees={"pp": 4, "dp": 2}, n_micro=8)

@@ -17,7 +17,7 @@ from paddle_tpu.models import llama_tiny
 from paddle_tpu.models.llama_pipeline import LlamaPipelineTrainer
 from paddle_tpu.optimizer import AdamW
 
-from _jax_compat_marks import needs_partial_manual_shard_map
+pytestmark = pytest.mark.usefixtures("uninstall_mesh")
 
 
 def _run_step(use_pallas: bool, seed=0):
@@ -39,13 +39,11 @@ def _run_step(use_pallas: bool, seed=0):
         kernels.set_use_pallas(None)
 
 
-@needs_partial_manual_shard_map
 def test_pipeline_trainer_with_pallas_flash_attention():
     loss = _run_step(use_pallas=True)
     assert np.isfinite(loss)
 
 
-@needs_partial_manual_shard_map
 def test_pipeline_pallas_matches_xla_attention():
     # same init seed => same params; the two attention impls must agree
     loss_pallas = _run_step(use_pallas=True)

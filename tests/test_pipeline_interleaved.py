@@ -12,7 +12,8 @@ from paddle_tpu.distributed.mesh import build_mesh
 from paddle_tpu.distributed.pipeline import (
     interleave_stage_params, spmd_pipeline_interleaved, stack_stage_params,
 )
-from _jax_compat_marks import needs_partial_manual_shard_map
+
+pytestmark = pytest.mark.usefixtures("uninstall_mesh")
 
 
 def _chunk_fn(params, h):
@@ -40,7 +41,6 @@ def _sequential(per_stage, x):
 
 
 class TestInterleaved:
-    @needs_partial_manual_shard_map
     def test_matches_sequential(self):
         per_stage, stacked, x = _setup()
         mesh = build_mesh(degrees={"pp": 2, "dp": 2, "mp": 2})
@@ -59,7 +59,6 @@ class TestInterleaved:
         np.testing.assert_array_equal(
             np.asarray(inter["w"][1, 2]), np.asarray(stacked["w"][5]))
 
-    @needs_partial_manual_shard_map
     def test_gradients_match_sequential(self):
         per_stage, stacked, x = _setup(M=3, mb=2)
         mesh = build_mesh(degrees={"pp": 2, "dp": 2, "mp": 2})
@@ -84,7 +83,6 @@ class TestInterleaved:
                                        np.asarray(g_seq[k]),
                                        rtol=1e-3, atol=1e-5)
 
-    @needs_partial_manual_shard_map
     def test_gradients_with_remat(self):
         """remat=True (the default; jax.checkpoint inside scan-in-scan +
         ppermute) must produce the same grads as remat=False."""
@@ -104,7 +102,6 @@ class TestInterleaved:
                                        np.asarray(g_plain[k]),
                                        rtol=1e-4, atol=1e-6)
 
-    @needs_partial_manual_shard_map
     def test_llama_trainer_interleaved_matches_fthenb(self):
         """pp_schedule='interleaved' on the Llama trainer is the same math as
         fill-drain, re-laid-out over virtual chunks — losses must match."""
@@ -130,7 +127,6 @@ class TestInterleaved:
         np.testing.assert_allclose(losses("interleaved"), losses("fthenb"),
                                    rtol=2e-4, atol=2e-5)
 
-    @needs_partial_manual_shard_map
     def test_deeper_ring_pp4_vpp2(self):
         per_stage, stacked, x = _setup(n_stages=4, vpp=2, M=6)
         mesh = build_mesh(degrees={"pp": 4, "dp": 2})

@@ -95,8 +95,16 @@ class TestJaxprCost:
         assert cost.lookup("t.callable", "B1", ("other", 2)) is None
         assert cost.lookup("t.callable", "nope", ("a", 1)) is None
 
+    def test_peaks_are_keyed_by_device_kind(self):
+        v5e = cost.platform_peaks("TPU v5 lite")
+        assert (v5e["flops_per_s"], v5e["bytes_per_s"]) == (197e12, 819e9)
+        assert cost.platform_peaks()["device_kind"] == "cpu"
+        with pytest.raises(ValueError, match="TPU v9 mega"):
+            cost.platform_peaks("TPU v9 mega")   # unknown: no default
+
     def test_roofline_math(self):
-        peaks = {"platform": "x", "flops_per_s": 100.0, "bytes_per_s": 10.0}
+        peaks = {"device_kind": "x", "flops_per_s": 100.0,
+                 "bytes_per_s": 10.0}
         est = {"flops": 200.0, "bytes": 10.0}       # compute-bound: 2s
         assert cost.roofline_time_s(est, peaks) == pytest.approx(2.0)
         est = {"flops": 10.0, "bytes": 100.0}       # memory-bound: 10s

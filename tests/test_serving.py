@@ -116,6 +116,26 @@ class TestPagedAttentionKernel:
             np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
                                        atol=1e-5)
 
+    @pytest.mark.parametrize("Hq,Hkv", [(8, 8), (8, 2)])   # MHA: rep = 1
+    def test_lowers_for_mosaic(self, Hq, Hkv):
+        """What the interpreter never checks: Mosaic's block-shape rule (the
+        query block must tile for any rep) and its dot precisions (the
+        package default, tensorfloat32, is not one). Lowering for the TPU
+        platform needs no TPU."""
+        S, D, bs, N, M = 4, 128, 16, 9, 2
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype)
+
+        text = jax.jit(
+            lambda *a: paged_attention_pallas(*a, interpret=False)).trace(
+            sds((S, Hq, D), jnp.bfloat16),
+            sds((N, 2, Hkv, bs, D), jnp.bfloat16),
+            sds((S, M), jnp.int32), sds((S,), jnp.int32)).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert any("paged_attention" in l and "tpu_custom_call" in l
+                   for l in text.splitlines())
+
     def test_single_token_context(self):
         q, pool, bt, _ = self._case(2)
         ctx = jnp.ones(q.shape[0], jnp.int32)
@@ -288,15 +308,18 @@ class TestEngine:
 
     def test_eos_stops_early(self):
         model = _tiny_model()
-        # run greedy once to learn the 2nd generated token, then set it as
-        # the eos and expect a "stop" finish after exactly 2 tokens
+        # run greedy once, take as eos the first generated token past the
+        # first that differs from all before it (random weights may repeat
+        # a token), and expect a "stop" finish exactly where it appears
         full = naive_generate(model, [5, 4, 3],
-                              SamplingParams(max_new_tokens=4))
+                              SamplingParams(max_new_tokens=8))
+        stop = next(i for i in range(1, len(full))
+                    if full[i] not in full[:i])
         eng = LLMEngine(model, block_size=8, max_slots=1, max_model_len=64,
-                        eos_token_id=full[1])
-        req = eng.add_request([5, 4, 3], SamplingParams(max_new_tokens=4))
+                        eos_token_id=full[stop])
+        req = eng.add_request([5, 4, 3], SamplingParams(max_new_tokens=8))
         eng.run()
-        assert req.output_tokens == full[:2]
+        assert req.output_tokens == full[:stop + 1]
         assert req.finish_reason == "stop"
 
     def test_request_validation(self):

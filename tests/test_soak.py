@@ -54,7 +54,6 @@ class TestSoakSmoke:
                        "max_model_len": 24},
             "warmup": [4, 8, 16],
             "stats_interval_s": 0.05,
-            "jax_cache_dir": os.path.join(str(tmp_path), "jax-cache"),
         }
         cfg = SoakConfig(
             spec=spec, fleet_spec=fleet_spec, workdir=str(tmp_path),

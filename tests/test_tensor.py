@@ -91,3 +91,16 @@ def test_parameter():
     p = paddle.Parameter(np.ones((2, 2), np.float32))
     assert not p.stop_gradient
     assert p.persistable
+
+
+def test_tpu_place_never_resolves_to_a_cpu_device():
+    """A Place is a handle onto a device of ITS backend: without a TPU,
+    Place("tpu") raises instead of handing back whatever exists."""
+    from paddle_tpu.core.device import Place
+
+    assert Place("cpu", 0).jax_device.platform == "cpu"
+    with pytest.raises(RuntimeError, match="tpu"):
+        Place("tpu", 0).jax_device
+    with pytest.raises(RuntimeError, match="device"):
+        Place("cpu", 10_000).jax_device
+    assert not Place("cpu").is_tpu_place() and Place("tpu").is_tpu_place()

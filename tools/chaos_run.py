@@ -184,6 +184,9 @@ import time
 
 import numpy as np
 
+# a CPU battery: its replica children must not contend for a chip
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
 sys.path.insert(0, ".")
 
 import paddle_tpu  # noqa: E402
@@ -804,9 +807,6 @@ def _fleet_spec(args, workdir, max_len):
                    "max_model_len": max_len},
         "warmup": list(range(1, args.prompt_len + 1)),
         "stats_interval_s": 0.05,
-        # all replicas share one persistent compile cache: only the first
-        # pays XLA for each trace, which keeps the battery's wall time sane
-        "jax_cache_dir": os.path.join(workdir, "jax-cache"),
     }
 
 
@@ -2864,7 +2864,6 @@ def run_soak_suite(args, workdir=None, scenario=None):
             # prompt cap (32 needs a >16-token warmup to compile P=32)
             "warmup": [4, 8, 16, 24, 32],
             "stats_interval_s": 0.05,
-            "jax_cache_dir": os.path.join(workdir, "jax-cache"),
         }
         degrade = [
             {"kind": "plan",

@@ -83,7 +83,6 @@ def build_config(args) -> SoakConfig:
                    "max_slots": args.slots, "max_model_len": max_len},
         "warmup": warm,
         "stats_interval_s": 0.05,
-        "jax_cache_dir": os.path.join(workdir, "jax-cache"),
     }
     chaos = [a for a in ROLLING_PLANS
              if not (a["kind"] in ("kill", "churn")
