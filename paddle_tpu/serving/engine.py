@@ -157,7 +157,12 @@ def _engine_metrics(label: str) -> SimpleNamespace:
         queue_time=H("serving_queue_time_seconds",
                      "request arrival to slot admission"),
         decode_step=H("serving_decode_step_seconds",
-                      "wall time of one fused decode step", _TOKEN_BUCKETS),
+                      "one fused decode step, from the start of batch "
+                      "assembly until its tokens are on the host",
+                      _TOKEN_BUCKETS),
+        admit_delay=H("serving_admit_delay_seconds",
+                      "gateway read of a request to the engine accepting "
+                      "it (observed by the replica on add_request)"),
     )
 
 
@@ -176,8 +181,10 @@ class LLMEngine:
                    raises ``QueueFull`` (None = unbounded)
     max_preemptions_per_request: requeue cap before a thrashing request is
                    failed (preemption-storm protection)
-    watchdog_timeout_s: decode steps slower than this are counted as
-                   watchdog trips in ``stats()`` (None = off)
+    watchdog_timeout_s: decode steps slower than this — batch assembly to
+                   the tokens' arrival on the host, so a slow device step
+                   trips it — are counted as watchdog trips in ``stats()``
+                   (None = off)
     stall_limit:   consecutive no-progress engine steps tolerated before
                    the queue head is failed instead of spinning forever
     slo_ttft_s / slo_tpot_s: latency SLOs for the rolling-window
@@ -417,27 +424,36 @@ class LLMEngine:
             self._serve_start = time.monotonic()
         had_work = self.scheduler.has_work()
         self._progressed = False
-        self._sweep_deadlines()
-        for slot, req in self.scheduler.admit():
+        # the phases below are flat spans that tile the iteration (docs/
+        # OBSERVABILITY.md "Phase spans"): no span encloses another, so a
+        # device-idle gap in a profiler trace is named by the phase the
+        # engine thread spent in it
+        with telemetry.span("engine.schedule"):
+            self._sweep_deadlines()
+            admitted = self.scheduler.admit()
+        for slot, req in admitted:
             self._progressed = True
             try:
                 faults.inject("serving.prefill", rid=req.rid)
                 self._run_prefill(slot, req)
             except Exception as e:          # isolate: fail ONE request
                 self._fail(slot, e)
-        if self.scheduler.running:
-            self.scheduler.ensure_decode_capacity()
-            self._collect_scheduler_failures()
-        if self.scheduler.running:
-            self._run_decode()
-        self._check_stall(had_work)
-        self._sync_gauges()
-        # steady-state watermark: stamp only when no request is mid-decode
-        # (blocks legitimately grow while sequences do) — blocks that never
-        # return to the pool across drains show up as monotonic "kv_blocks"
-        # growth and trip the leak sentinel
-        if not self.scheduler.running:
-            self._mm.note_step()
+        with telemetry.span("engine.schedule"):
+            if self.scheduler.running:
+                self.scheduler.ensure_decode_capacity()
+                self._collect_scheduler_failures()
+        step_acct = self._run_decode() if self.scheduler.running else None
+        with telemetry.span("engine.account"):
+            if step_acct is not None:
+                self._account_decode(*step_acct)
+            self._check_stall(had_work)
+            self._sync_gauges()
+            # steady-state watermark: stamp only when no request is
+            # mid-decode (blocks legitimately grow while sequences do) —
+            # blocks that never return to the pool across drains show up as
+            # monotonic "kv_blocks" growth and trip the leak sentinel
+            if not self.scheduler.running:
+                self._mm.note_step()
         return self.scheduler.has_work()
 
     def run(self):
@@ -950,41 +966,34 @@ class LLMEngine:
         if cached:
             self._run_tail_prefill(slot, req, toks, cached)
             return
-        L = len(toks)
-        P = self._bucket(L)
-        padded = np.zeros(P, np.int32)
-        padded[:L] = toks
-        bt = self.cache.table_array([req.rid], P // self.block_size)[0]
-        sp = req.sampling
-        new_trace = P not in self._prefill_fns
-        self._mm.set("activations_estimate", self._act_estimate(P))
-        fn = self._get_prefill_fn(P)
-        call_args = (
-            self.params, self.buffers, self.cache.pool,
-            jnp.asarray(padded), jnp.int32(L), jnp.asarray(bt),
-            jnp.float32(sp.temperature), jnp.int32(sp.top_k),
-            jnp.float32(sp.top_p), jnp.int32(sp.seed),
-            jnp.int32(len(req.output_tokens)))
-        cost_est = (self._trace_cost("prefill", f"P{P}", P, call_args)
-                    if new_trace else None)
         t0 = time.monotonic()
-        with telemetry.span("engine.prefill", rid=req.rid, tokens=L,
-                            padded=P, engine=self.engine_label,
+        with telemetry.span("engine.prefill", rid=req.rid, tokens=len(toks),
+                            engine=self.engine_label,
                             **({"trace_id": req.trace_id}
-                               if req.trace_id else {})):
-            tok, pool = fn(*call_args)
-        wall = time.monotonic() - t0
-        self._watcher.record_call(
-            "engine.prefill",
+                               if req.trace_id else {})) as span:
+            L = len(toks)
+            P = span.attrs["padded"] = self._bucket(L)
+            padded = np.zeros(P, np.int32)
+            padded[:L] = toks
+            bt = self.cache.table_array([req.rid], P // self.block_size)[0]
+            sp = req.sampling
+            new_trace = P not in self._prefill_fns
+            self._mm.set("activations_estimate", self._act_estimate(P))
+            fn = self._get_prefill_fn(P)
+            call_args = (
+                self.params, self.buffers, self.cache.pool,
+                jnp.asarray(padded), jnp.int32(L), jnp.asarray(bt),
+                jnp.float32(sp.temperature), jnp.int32(sp.top_k),
+                jnp.float32(sp.top_p), jnp.int32(sp.seed),
+                jnp.int32(len(req.output_tokens)))
+            cost_est = (self._trace_cost("prefill", f"P{P}", P, call_args)
+                        if new_trace else None)
+            tok, self.cache.pool = fn(*call_args)
+        self._finish_prefill(
+            slot, req, toks, tok, t0, f"P{P}",
             (("tokens", (P,), "int32"),
              ("block_table", (P // self.block_size,), "int32")),
-            wall_s=wall if new_trace else None, cost=cost_est)
-        if not new_trace:
-            self._note_roofline("prefill", f"P{P}", wall)
-        self._charge_tenant(req.tenant, "prefill", f"P{P}")
-        self.cache.pool = pool
-        self.cache.commit_prefix(req.rid, toks)
-        self._emit(slot, req, int(tok))
+            new_trace, cost_est)
 
     def _run_tail_prefill(self, slot: int, req: Request, toks, cached: int):
         """Prefill only the tokens past the matched prefix: the cached
@@ -992,53 +1001,69 @@ class LLMEngine:
         jitted step gathers their K/V, writes the tail's, and samples from
         the last valid position — positionally offset by the hit length."""
         bs = self.block_size
-        npb = cached // bs                      # matched blocks (full)
-        tail = toks[cached:]
-        L = len(tail)
-        P = self._bucket(L)
-        NPB = 1 << (npb - 1).bit_length()       # pad to power of two
-        table = self.cache.tables[req.rid]
-        pbt = np.zeros(NPB, np.int32)
-        pbt[:npb] = table[:npb]
-        bt = np.zeros(P // bs, np.int32)
-        tail_blocks = table[npb:npb + P // bs]
-        bt[:len(tail_blocks)] = tail_blocks
-        padded = np.zeros(P, np.int32)
-        padded[:L] = tail
-        sp = req.sampling
-        new_trace = (P, NPB) not in self._prefill_fns
-        self._mm.set("activations_estimate", self._act_estimate(P))
-        fn = self._get_tail_prefill_fn(P, NPB)
-        call_args = (
-            self.params, self.buffers, self.cache.pool,
-            jnp.asarray(padded), jnp.int32(L), jnp.asarray(bt),
-            jnp.asarray(pbt), jnp.int32(cached),
-            jnp.float32(sp.temperature), jnp.int32(sp.top_k),
-            jnp.float32(sp.top_p), jnp.int32(sp.seed),
-            jnp.int32(len(req.output_tokens)))
-        bucket = f"P{P}-NPB{NPB}"
-        cost_est = (self._trace_cost("prefill", bucket, (P, NPB), call_args)
-                    if new_trace else None)
         t0 = time.monotonic()
-        with telemetry.span("engine.prefill", rid=req.rid, tokens=L,
-                            padded=P, cached=cached,
+        with telemetry.span("engine.prefill", rid=req.rid,
+                            tokens=len(toks) - cached, cached=cached,
                             engine=self.engine_label,
                             **({"trace_id": req.trace_id}
-                               if req.trace_id else {})):
-            tok, pool = fn(*call_args)
-        wall = time.monotonic() - t0
-        self._watcher.record_call(
-            "engine.prefill",
+                               if req.trace_id else {})) as span:
+            npb = cached // bs                      # matched blocks (full)
+            tail = toks[cached:]
+            L = len(tail)
+            P = span.attrs["padded"] = self._bucket(L)
+            NPB = 1 << (npb - 1).bit_length()       # pad to power of two
+            table = self.cache.tables[req.rid]
+            pbt = np.zeros(NPB, np.int32)
+            pbt[:npb] = table[:npb]
+            bt = np.zeros(P // bs, np.int32)
+            tail_blocks = table[npb:npb + P // bs]
+            bt[:len(tail_blocks)] = tail_blocks
+            padded = np.zeros(P, np.int32)
+            padded[:L] = tail
+            sp = req.sampling
+            new_trace = (P, NPB) not in self._prefill_fns
+            self._mm.set("activations_estimate", self._act_estimate(P))
+            fn = self._get_tail_prefill_fn(P, NPB)
+            call_args = (
+                self.params, self.buffers, self.cache.pool,
+                jnp.asarray(padded), jnp.int32(L), jnp.asarray(bt),
+                jnp.asarray(pbt), jnp.int32(cached),
+                jnp.float32(sp.temperature), jnp.int32(sp.top_k),
+                jnp.float32(sp.top_p), jnp.int32(sp.seed),
+                jnp.int32(len(req.output_tokens)))
+            bucket = f"P{P}-NPB{NPB}"
+            cost_est = (self._trace_cost("prefill", bucket, (P, NPB),
+                                         call_args)
+                        if new_trace else None)
+            tok, self.cache.pool = fn(*call_args)
+        self._finish_prefill(
+            slot, req, toks, tok, t0, bucket,
             (("tokens", (P,), "int32"),
              ("block_table", (P // bs,), "int32"),
              ("prefix_table", (NPB,), "int32")),
-            wall_s=wall if new_trace else None, cost=cost_est)
-        if not new_trace:
-            self._note_roofline("prefill", bucket, wall)
-        self._charge_tenant(req.tenant, "prefill", bucket)
-        self.cache.pool = pool
-        self.cache.commit_prefix(req.rid, toks)
-        self._emit(slot, req, int(tok))
+            new_trace, cost_est)
+
+    def _finish_prefill(self, slot: int, req: Request, toks, tok,
+                        t0: float, bucket: str, signature, new_trace: bool,
+                        cost_est):
+        """What both prefills do once the step is dispatched: book what
+        needs no result while the device runs, wait for the first token,
+        hand it on, and book the step's time. ``wall`` ends at the result
+        — at dispatch the device has only been asked."""
+        with telemetry.span("engine.overlap"):
+            self.cache.commit_prefix(req.rid, toks)
+            self._charge_tenant(req.tenant, "prefill", bucket)
+        with telemetry.span("engine.prefill_wait"):
+            tok = int(tok)
+        wall = time.monotonic() - t0
+        with telemetry.span("engine.emit"):
+            self._emit(slot, req, tok)
+        with telemetry.span("engine.account"):
+            self._watcher.record_call(
+                "engine.prefill", signature,
+                wall_s=wall if new_trace else None, cost=cost_est)
+            if not new_trace:
+                self._note_roofline("prefill", bucket, wall)
 
     # ------------------------------------------------------------------
     # decode
@@ -1069,7 +1094,15 @@ class LLMEngine:
         self._py_fns["decode"] = decode
         return self._decode_fn
 
-    def _run_decode(self):
+    # the decode StepTimeline's phases, in the order _run_decode goes
+    # through them (a failed step is attributed as far as it got); "wait"
+    # runs from the end of the dispatch to the tokens' arrival on the host
+    _DECODE_PHASES = ("assemble", "upload", "dispatch", "wait", "emit")
+
+    def _assemble_decode(self):
+        """The running slots and the step's host-side batch: one NumPy
+        array per traced input of ``decode``, inactive slots reading one
+        garbage scratch token."""
         # per-slot chaos boundary: a fault targeted at one request drops
         # only that request from the batch (FAILED, error attached)
         for slot, req in sorted(self.scheduler.running.items()):
@@ -1079,12 +1112,8 @@ class LLMEngine:
                 self._fail(slot, e)
         running = dict(self.scheduler.running)  # slot -> req snapshot
         if not running:
-            return
+            return running, None
         S = self.max_slots
-        # decode StepTimeline: host batch assembly is the "data" phase, the
-        # fused jitted call the "compute" phase (recorded in the finally
-        # below so failed steps are attributed too)
-        t_step0 = time.monotonic()
         tokens = np.zeros(S, np.int32)
         ctx = np.ones(S, np.int32)       # inactive: 1 garbage scratch token
         temps = np.zeros(S, np.float32)
@@ -1104,51 +1133,73 @@ class LLMEngine:
             seeds[slot] = req.sampling.seed
             steps[slot] = len(req.output_tokens)
         bt = self.cache.table_array(sids, self.max_blocks)
-        data_s = time.monotonic() - t_step0
+        return running, (tokens, bt, ctx, temps, top_ks, top_ps, seeds, steps)
 
+    def _run_decode(self):
+        """One fused decode step over the running slots. Returns what
+        ``step``'s ``engine.account`` phase books (:meth:`_account_decode`),
+        or None when the step did not run to its end."""
+        # marks[i + 1] - marks[i] is the time of _DECODE_PHASES[i]
+        marks = [time.monotonic()]
+        with telemetry.span("engine.assemble"):
+            running, host = self._assemble_decode()
+        if not running:
+            return None
+        marks.append(time.monotonic())
         new_trace = self._decode_fn is None
-        self._mm.set("activations_estimate", self._act_estimate(S))
-        # batch-level decode ticks carry every member request's trace
-        # context so per-request merged traces can include them
-        span_kw = {}
-        tids = [r.trace_id for r in running.values() if r.trace_id]
-        if tids:
-            span_kw["trace_ids"] = tids
         cost_est = None
-        t0 = time.monotonic()
+        done = False
         try:
-            with telemetry.span("engine.decode", batch=len(running),
-                                engine=self.engine_label, **span_kw):
-                faults.inject("serving.decode", batch=len(running))
-                fn = self._get_decode_fn()
-                call_args = (
-                    self.params, self.buffers, self.cache.pool,
-                    jnp.asarray(tokens), jnp.asarray(bt), jnp.asarray(ctx),
-                    jnp.asarray(temps), jnp.asarray(top_ks),
-                    jnp.asarray(top_ps), jnp.asarray(seeds),
-                    jnp.asarray(steps))
-                cost_est = (
-                    self._trace_cost("decode", "decode", "decode", call_args)
-                    if new_trace else None)
-                toks, pool = fn(*call_args)
-        except Exception as e:
-            # the fused step died: every request in the batch fails, the
-            # engine itself (and the waiting queue) survives
-            for slot in list(running):
-                if slot in self.scheduler.running:
-                    self._fail(slot, e)
-            return
+            try:
+                with telemetry.span("engine.upload"):
+                    self._mm.set("activations_estimate",
+                                 self._act_estimate(self.max_slots))
+                    faults.inject("serving.decode", batch=len(running))
+                    fn = self._get_decode_fn()
+                    call_args = (self.params, self.buffers, self.cache.pool,
+                                 *(jnp.asarray(a) for a in host))
+                    if new_trace:
+                        cost_est = self._trace_cost(
+                            "decode", "decode", "decode", call_args)
+                marks.append(time.monotonic())
+                # batch-level decode ticks carry every member request's
+                # trace context so per-request merged traces include them
+                tids = [r.trace_id for r in running.values() if r.trace_id]
+                with telemetry.span("engine.decode", batch=len(running),
+                                    engine=self.engine_label,
+                                    **({"trace_ids": tids} if tids else {})):
+                    toks, self.cache.pool = fn(*call_args)
+                marks.append(time.monotonic())
+            except Exception as e:
+                # the fused step died: every request in the batch fails,
+                # the engine itself (and the waiting queue) survives
+                for slot in list(running):
+                    if slot in self.scheduler.running:
+                        self._fail(slot, e)
+                return None
+            # while the device runs the step: the bookkeeping that needs
+            # no result, so that none of it delays the next dispatch
+            with telemetry.span("engine.overlap"):
+                share = 1.0 / len(running)
+                for req in running.values():
+                    self._charge_tenant(req.tenant, "decode", "decode", share)
+                if self.prefix_cache:
+                    # a decode write that just filled its block completes
+                    # another full token-block: index it so later
+                    # admissions can share it
+                    for slot, req in running.items():
+                        if (slot in self.scheduler.running
+                                and req.total_len % self.block_size == 0):
+                            self.cache.commit_prefix(req.rid,
+                                                     req.prefill_tokens)
+            with telemetry.span("engine.decode_wait"):
+                toks = np.asarray(toks)
+            done = True
         finally:
-            self.last_decode_s = time.monotonic() - t0
-            self._decode_tl.record_step(
-                time.monotonic() - t_step0,
-                {"data": data_s, "compute": self.last_decode_s})
-            self._watcher.record_call(
-                "engine.decode",
-                (("tokens", (S,), "int32"),
-                 ("block_tables", (S, self.max_blocks), "int32")),
-                wall_s=self.last_decode_s if new_trace else None,
-                cost=cost_est)
+            # the step's clocks end at the result: at dispatch the device
+            # has only been asked (failed steps are clocked too)
+            marks.append(time.monotonic())
+            self.last_decode_s = marks[-1] - marks[0]
             self._m.decode_step.observe(self.last_decode_s)
             if (self.watchdog_timeout_s is not None
                     and self.last_decode_s > self.watchdog_timeout_s):
@@ -1158,22 +1209,31 @@ class LLMEngine:
                     "engine.watchdog_trip", engine=self.engine_label,
                     decode_s=self.last_decode_s,
                     limit_s=self.watchdog_timeout_s)
-        if not new_trace:
-            self._note_roofline("decode", "decode", self.last_decode_s)
-        share = 1.0 / len(running)
-        for req in running.values():
-            self._charge_tenant(req.tenant, "decode", "decode", share)
-        self.cache.pool = pool
-        if self.prefix_cache:
-            # a decode write that just filled its block completes another
-            # full token-block: index it so later admissions can share it
+            if not done:
+                self._account_decode(marks, len(running), new_trace,
+                                     cost_est, done=False)
+        with telemetry.span("engine.emit"):
             for slot, req in running.items():
-                if (slot in self.scheduler.running
-                        and req.total_len % self.block_size == 0):
-                    self.cache.commit_prefix(req.rid, req.prefill_tokens)
-        toks = np.asarray(toks)
-        for slot, req in running.items():
-            self._emit(slot, req, int(toks[slot]))
+                self._emit(slot, req, int(toks[slot]))
+        marks.append(time.monotonic())
+        return marks, len(running), new_trace, cost_est
+
+    def _account_decode(self, marks, n_running, new_trace, cost_est,
+                        done=True):
+        """Book one decode step's time once it is known: its phases and
+        occupancy into the StepTimeline, the call into the compile watcher
+        and, for a step that ran to its end, its roofline fraction."""
+        phases = {ph: t1 - t0 for ph, t0, t1 in
+                  zip(self._DECODE_PHASES, marks, marks[1:])}
+        self._decode_tl.record_step(marks[-1] - marks[0], phases,
+                                    occupancy=n_running / self.max_slots)
+        self._watcher.record_call(
+            "engine.decode",
+            (("tokens", (self.max_slots,), "int32"),
+             ("block_tables", (self.max_slots, self.max_blocks), "int32")),
+            wall_s=self.last_decode_s if new_trace else None, cost=cost_est)
+        if done and not new_trace:
+            self._note_roofline("decode", "decode", self.last_decode_s)
 
     def _emit(self, slot: int, req: Request, token: int):
         req.emit(token)

@@ -241,6 +241,12 @@ def _gateway_metrics() -> SimpleNamespace:
             "gateway_auth_failures_total",
             "requests answered 401 (missing/unknown API key, or the "
             "gateway.auth fault site failing closed)"),
+        relay=reg.histogram(
+            "gateway_token_relay_seconds",
+            "engine emit of a token to its SSE chunk written (replica "
+            "event, router callback, asyncio queue, socket write)",
+            buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+                     0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5)),
         tenant_shed=reg.counter(
             "gateway_tenant_shed_total",
             "requests answered 429 by the tenant's own token bucket "
@@ -565,7 +571,7 @@ class Gateway:
                 st.tokens.append(int(tok))
                 i = len(st.tokens) - 1
                 subs = list(st.subscribers)
-            push(subs, ("tok", i, int(tok)))
+            push(subs, ("tok", i, int(tok), rr.last_emit_unix))
 
         def on_watermark(rr, n):
             if self.journal is None:
@@ -599,7 +605,7 @@ class Gateway:
                 except JournalError:
                     pass   # crash-equivalent: recovery re-runs the tail
             st.done.set()
-            push(subs, ("done", None, None))
+            push(subs, ("done", None, None, None))
 
         return on_token, on_watermark, on_finish
 
@@ -650,7 +656,7 @@ class Gateway:
                 on_finish=on_fin, trace_id=jid,
                 on_watermark=on_wm if self.journal is not None else None,
                 watermark_every=self.journal_watermark_every,
-                tenant=st.tenant)
+                tenant=st.tenant, t_front_unix=p.get("t_front_unix"))
         except Exception as e:
             # the client is getting an error response right now — undo
             # the reservation, and make sure a future recovery does not
@@ -672,8 +678,8 @@ class Gateway:
                     "non-blocking socketpair write, never blocks"):
                 for loop, q in subs:
                     try:
-                        loop.call_soon_threadsafe(q.put_nowait,
-                                                  ("done", None, None))
+                        loop.call_soon_threadsafe(
+                            q.put_nowait, ("done", None, None, None))
                     except RuntimeError:
                         pass
             if journaled:
@@ -883,6 +889,9 @@ class Gateway:
     async def _handle(self, req, writer) -> bool:
         """Serve one request; returns True to keep the connection alive."""
         t0 = time.monotonic()
+        # when the front door had the request in hand: the admit delay the
+        # replica observes (serving_admit_delay_seconds) runs from here
+        req.t_read_unix = telemetry.mono_to_unix(t0)
         route = f"{req.method} {req.path}"
         self._m.requests.labels(route=route).inc()
         try:
@@ -1287,6 +1296,7 @@ class Gateway:
         tenant = self._resolve_tenant(req)          # 401 before parsing
         p = self._parse_body(req, chat)
         p["tenant"] = tenant
+        p["t_front_unix"] = req.t_read_unix
         # per-tenant token bucket: the admission charge is the worst-case
         # tokens this request occupies the engine for (prompt + output
         # budget, the same cost the scheduler's DRR uses). A bucket shed
@@ -1377,7 +1387,7 @@ class Gateway:
         q, _, terminal = self._subscribe(st, len(st.tokens))
         try:
             while not terminal and not st.done.is_set():
-                kind, _, _ = await q.get()
+                kind, _, _, _ = await q.get()
                 if kind == "done":
                     break
         finally:
@@ -1440,7 +1450,7 @@ class Gateway:
             await writer.drain()
             if not terminal:
                 while True:
-                    kind, i, tok = await q.get()
+                    kind, i, tok, t_emit = await q.get()
                     if kind == "done":
                         break
                     if i < idx:
@@ -1452,6 +1462,9 @@ class Gateway:
                     idx = i + 1
                     self._m.tokens.inc()
                     await writer.drain()
+                    if t_emit is not None:
+                        self._m.relay.observe(max(0.0, telemetry.mono_to_unix(
+                            time.monotonic()) - t_emit))
             finish = st.finish_reason or st.state
             final = self._sse_chunk(
                 st, finish=finish,

@@ -17,14 +17,15 @@ Protocol (one JSON object per line):
     stdin  <- {"op": "add", "gid": 7, "prompt": [...],
                "sampling": {...}, "deadline_s": 1.5 | null,
                "trace_id": "req-ab12cd" | null,
-               "tenant": "acme" | null, "priority": 0}
+               "tenant": "acme" | null, "priority": 0,
+               "t_front_unix": 1.7e9 | null, "t_sent_unix": 1.7e9 | null}
               {"op": "cancel", "gid": 7}
               {"op": "kv_fetch", "fid": 3, "hashes": [...],
                "max_frames": 64, "max_bytes": 33554432}
               {"op": "kv_ingest", "frames": [...]}
               {"op": "close"}
     stdout -> {"ev": "hello", "pid": 1234}
-              {"ev": "token", "gid": 7, "tok": 42, "i": 0}
+              {"ev": "token", "gid": 7, "tok": 42, "i": 0, "t": 1.7e9}
               {"ev": "done", "gid": 7, "state": "finished",
                "reason": "length", "error": null, "n": 16}
               {"ev": "stats", "stats": {... replica_stats() ...},
@@ -50,6 +51,11 @@ lease expire).
 engine stamps it on every span the request produces, and the heartbeat
 streams those spans back so the router can merge one Chrome trace per
 request across replica hops (docs/OBSERVABILITY.md "Request tracing").
+``t_front_unix`` (the gateway had read the request; null on a re-dispatch)
+and ``t_sent_unix`` (the router sent this command) end in
+``router.note_admit`` when the engine accepts the request; a token's ``t``
+is when the engine emitted it, which the gateway measures its relay from.
+All three are unix time, optional, and ignored by a peer that predates them.
 
 Anything that is not protocol (import-time warnings, stray prints) fails
 JSON parsing on the router side and is ignored; diagnostics belong on
@@ -96,10 +102,11 @@ def main() -> int:
     from ..utils import compile_cache
 
     compile_cache.enable()
+    from .. import telemetry
     from ..telemetry import reqtrace
     from . import kv_fabric
     from .engine import LLMEngine
-    from .router import replica_stats, sampling_from_dict
+    from .router import handle_command, replica_stats
 
     model = build_model(spec)
     engine = LLMEngine(model, **(spec.get("engine") or {}))
@@ -172,7 +179,8 @@ def main() -> int:
     def on_token(gid: int):
         def cb(req, tok):
             emit({"ev": "token", "gid": gid, "tok": int(tok),
-                  "i": len(req.output_tokens) - 1})
+                  "i": len(req.output_tokens) - 1,
+                  "t": telemetry.mono_to_unix(time.monotonic())})
         return cb
 
     def sweep():
@@ -206,63 +214,35 @@ def main() -> int:
 
     last_pub = 0.0
     closing = False
+    # the same flat phase spans as LocalReplica._drive (docs/
+    # OBSERVABILITY.md "Phase spans")
     while not closing:
-        try:
-            has_work = engine.scheduler.has_work()
-            cmd = cmds.get(block=not has_work, timeout=0.02)
-        except queue.Empty:
-            cmd = None
-        if cmd is not None:
-            op = cmd.get("op")
-            if op == "close":
-                closing = True
-            elif op == "add":
-                gid = cmd["gid"]
+        cmd = None
+        if not engine.scheduler.has_work():
+            with telemetry.span("replica.idle"):
                 try:
-                    tracked[gid] = engine.add_request(
-                        cmd["prompt"],
-                        sampling_from_dict(cmd.get("sampling")),
-                        on_token=on_token(gid),
-                        deadline_s=cmd.get("deadline_s"),
-                        trace_id=cmd.get("trace_id"),
-                        tenant=cmd.get("tenant") or "anonymous",
-                        priority=cmd.get("priority") or 0)
-                except Exception as e:
-                    emit({"ev": "done", "gid": gid, "state": "failed",
-                          "reason": "add_failed",
-                          "error": f"{type(e).__name__}: {e}", "n": 0})
-            elif op == "cancel":
-                req = tracked.get(cmd["gid"])
-                if req is not None:
-                    engine.cancel(req.rid)
-            elif op == "kv_fetch":
-                fid = cmd.get("fid")
+                    cmd = cmds.get(timeout=0.02)
+                except queue.Empty:
+                    pass
+        with telemetry.span("replica.inbox"):
+            if cmd is None:
                 try:
-                    frames = engine.export_kv_frames(
-                        cmd.get("hashes") or [],
-                        max_frames=cmd.get("max_frames"),
-                        max_bytes=cmd.get("max_bytes"))
-                    emit({"ev": "kv_blocks", "fid": fid, "frames": frames,
-                          "error": None})
-                except Exception as e:
-                    emit({"ev": "kv_blocks", "fid": fid, "frames": [],
-                          "error": f"{type(e).__name__}: {e}"})
-            elif op == "kv_ingest":
-                try:
-                    rep = engine.ingest_kv_frames(cmd.get("frames") or [])
-                except Exception as e:  # lint: allow-silent(error is captured into the kv_ingested reply)
-                    rep = {"ingested": 0, "corrupt": 0, "errors": 1,
-                           "error": f"{type(e).__name__}: {e}"}
-                emit({"ev": "kv_ingested", **rep})
+                    cmd = cmds.get_nowait()
+                except queue.Empty:
+                    pass
+            if cmd is not None:
+                closing = handle_command(engine, cmd, tracked,
+                                         on_token, emit)
         if closing:
             break
         if engine.scheduler.has_work():
             engine.step()
-        sweep()
-        now = time.monotonic()
-        if now - last_pub >= stats_interval:
-            last_pub = now
-            heartbeat()
+        with telemetry.span("replica.sweep"):
+            sweep()
+            now = time.monotonic()
+            if now - last_pub >= stats_interval:
+                last_pub = now
+                heartbeat()
 
     engine.close()
     sweep()

@@ -269,7 +269,8 @@ class RouterRequest:
                  deadline: float | None = None, on_token=None,
                  on_finish=None, trace_id: str | None = None,
                  on_watermark=None, watermark_every: int = 8,
-                 tenant: str = "anonymous"):
+                 tenant: str = "anonymous",
+                 t_front_unix: float | None = None):
         self.gid = gid
         self.prompt = [int(t) for t in prompt]
         self.sampling = dict(sampling)
@@ -296,6 +297,12 @@ class RouterRequest:
         self.arrival_time = time.monotonic()
         self.first_token_time: float | None = None
         self.finish_time: float | None = None
+        # front-door delay and token relay (docs/OBSERVABILITY.md): when
+        # the gateway had read the request, and when the engine emitted
+        # the token that ``on_token`` is being called with (unix time:
+        # either may come from another process)
+        self.t_front_unix = t_front_unix
+        self.last_emit_unix: float | None = None
         self._done = threading.Event()
         # request-trace context (telemetry.reqtrace): the id every hop's
         # spans carry; remote_spans are the replica-side spans streamed
@@ -363,6 +370,79 @@ def replica_stats(engine) -> dict:
         # step in the window. The soak harness asserts this stays empty.
         "leaks": sorted(engine._mm.leak_report()),
     }
+
+
+def note_admit(engine, req, cmd: dict):
+    """Front-door delay, counted where it ends: the engine has just
+    accepted ``req`` from the ``add`` command ``cmd``. Observes
+    ``serving_admit_delay_seconds`` (gateway read -> accepted; first
+    dispatches only, a failover's stamp is its first attempt's) and emits
+    the request's ``replica.inbox_wait`` span (router send -> accepted),
+    the hop between ``router.dispatch`` and the engine's ``queued``."""
+    now = time.monotonic()
+    now_unix = telemetry.mono_to_unix(now)
+    if cmd.get("t_front_unix") is not None:
+        engine._m.admit_delay.observe(
+            max(0.0, now_unix - cmd["t_front_unix"]))
+    if cmd.get("t_sent_unix") is not None and req.trace_id:
+        telemetry.tracer().emit(
+            "replica.inbox_wait",
+            now - max(0.0, now_unix - cmd["t_sent_unix"]), now,
+            attrs={"trace_id": req.trace_id, "gid": cmd.get("gid"),
+                   "engine": engine.engine_label})
+
+
+def handle_command(engine, cmd: dict, tracked: dict, on_token, emit) -> bool:
+    """Act on one command of the replica protocol (``replica_worker`` has
+    it in full) for a replica's driver loop: ``tracked`` maps gid to the
+    engine's request, ``on_token(gid)`` makes a request's token callback,
+    ``emit(ev)`` sends an event to the router. True when the command asks
+    the driver to close."""
+    op = cmd.get("op")
+    if op in ("close", "abort"):
+        return True
+    if op == "add":
+        gid = cmd["gid"]
+        try:
+            req = engine.add_request(
+                cmd["prompt"],
+                sampling_from_dict(cmd.get("sampling")),
+                on_token=on_token(gid),
+                deadline_s=cmd.get("deadline_s"),
+                trace_id=cmd.get("trace_id"),
+                tenant=cmd.get("tenant") or "anonymous",
+                priority=cmd.get("priority") or 0)
+        except Exception as e:
+            emit({"ev": "done", "gid": gid, "state": "failed",
+                  "reason": "add_failed",
+                  "error": f"{type(e).__name__}: {e}", "n": 0})
+        else:
+            tracked[gid] = req
+            note_admit(engine, req, cmd)
+    elif op == "cancel":
+        req = tracked.get(cmd["gid"])
+        if req is not None:
+            engine.cancel(req.rid)
+    elif op == "kv_fetch":
+        fid = cmd.get("fid")
+        try:
+            frames = engine.export_kv_frames(
+                cmd.get("hashes") or [],
+                max_frames=cmd.get("max_frames"),
+                max_bytes=cmd.get("max_bytes"))
+            emit({"ev": "kv_blocks", "fid": fid, "frames": frames,
+                  "error": None})
+        except Exception as e:
+            emit({"ev": "kv_blocks", "fid": fid, "frames": [],
+                  "error": f"{type(e).__name__}: {e}"})
+    elif op == "kv_ingest":
+        try:
+            rep = engine.ingest_kv_frames(cmd.get("frames") or [])
+        except Exception as e:  # lint: allow-silent(error is captured into the kv_ingested reply)
+            rep = {"ingested": 0, "corrupt": 0, "errors": 1,
+                   "error": f"{type(e).__name__}: {e}"}
+        emit({"ev": "kv_ingested", **rep})
+    return False
 
 
 # LocalReplica drivers build their engines under one lock: the factory
@@ -517,63 +597,35 @@ class LocalReplica:
         def on_token(gid):
             def cb(req, tok):
                 self._emit(gen, {"ev": "token", "gid": gid, "tok": int(tok),
-                                 "i": len(req.output_tokens) - 1})
+                                 "i": len(req.output_tokens) - 1,
+                                 "t": telemetry.mono_to_unix(
+                                     time.monotonic())})
             return cb
 
+        # The phases of one iteration are flat spans that tile it, on this
+        # thread only (docs/OBSERVABILITY.md "Phase spans"): a profiler
+        # trace names each device-idle gap by the longest span under it, so
+        # a span that enclosed the others would take every gap.
         while not self._killed and gen == self._gen:
-            # 1) commands (non-blocking while the engine has work; short
-            #    block when idle so the thread doesn't spin)
-            try:
-                has_work = engine.scheduler.has_work()
-                cmd = inbox.get(block=not has_work, timeout=0.02)
-            except queue.Empty:
-                cmd = None
-            if cmd is not None:
-                op = cmd.get("op")
-                if op in ("close", "abort"):
-                    closing = True
-                elif op == "add":
-                    gid = cmd["gid"]
+            # 1) one command a turn (not blocking while the engine has
+            #    work; a short block when idle so the thread doesn't spin)
+            cmd = None
+            if not engine.scheduler.has_work():
+                with telemetry.span("replica.idle"):
                     try:
-                        req = engine.add_request(
-                            cmd["prompt"],
-                            sampling_from_dict(cmd.get("sampling")),
-                            on_token=on_token(gid),
-                            deadline_s=cmd.get("deadline_s"),
-                            trace_id=cmd.get("trace_id"),
-                            tenant=cmd.get("tenant") or "anonymous",
-                            priority=cmd.get("priority") or 0)
-                        tracked[gid] = req
-                    except Exception as e:
-                        self._emit(gen, {
-                            "ev": "done", "gid": gid, "state": "failed",
-                            "reason": "add_failed",
-                            "error": f"{type(e).__name__}: {e}", "n": 0})
-                elif op == "cancel":
-                    req = tracked.get(cmd["gid"])
-                    if req is not None:
-                        engine.cancel(req.rid)
-                elif op == "kv_fetch":
-                    fid = cmd.get("fid")
+                        cmd = inbox.get(timeout=0.02)
+                    except queue.Empty:
+                        pass
+            with telemetry.span("replica.inbox"):
+                if cmd is None:
                     try:
-                        frames = engine.export_kv_frames(
-                            cmd.get("hashes") or [],
-                            max_frames=cmd.get("max_frames"),
-                            max_bytes=cmd.get("max_bytes"))
-                        self._emit(gen, {"ev": "kv_blocks", "fid": fid,
-                                         "frames": frames, "error": None})
-                    except Exception as e:
-                        self._emit(gen, {
-                            "ev": "kv_blocks", "fid": fid, "frames": [],
-                            "error": f"{type(e).__name__}: {e}"})
-                elif op == "kv_ingest":
-                    try:
-                        rep = engine.ingest_kv_frames(
-                            cmd.get("frames") or [])
-                    except Exception as e:  # lint: allow-silent(error is captured into the kv_ingested reply)
-                        rep = {"ingested": 0, "corrupt": 0, "errors": 1,
-                               "error": f"{type(e).__name__}: {e}"}
-                    self._emit(gen, {"ev": "kv_ingested", **rep})
+                        cmd = inbox.get_nowait()
+                    except queue.Empty:
+                        pass
+                if cmd is not None:
+                    closing = handle_command(
+                        engine, cmd, tracked, on_token,
+                        lambda ev: self._emit(gen, ev))
             # 2) one engine iteration
             if closing:
                 break
@@ -585,11 +637,12 @@ class LocalReplica:
                                      "error": f"{type(e).__name__}: {e}"})
                     return
             # 3) terminal sweeps + heartbeat
-            self._sweep(gen, tracked)
-            now = time.monotonic()
-            if now - last_pub >= self.stats_interval_s:
-                last_pub = now
-                heartbeat()
+            with telemetry.span("replica.sweep"):
+                self._sweep(gen, tracked)
+                now = time.monotonic()
+                if now - last_pub >= self.stats_interval_s:
+                    last_pub = now
+                    heartbeat()
         if self._killed or gen != self._gen:
             return                         # abandoned, simulating SIGKILL
         engine.close()                     # graceful: terminal-ize leftovers
@@ -1026,7 +1079,8 @@ class FleetRouter:
                on_watermark=None, watermark_every: int = 8,
                replay_tokens=None,
                bypass_shed: bool = False,
-               tenant: str = "anonymous") -> RouterRequest:
+               tenant: str = "anonymous",
+               t_front_unix: float | None = None) -> RouterRequest:
         """Place and dispatch one request; returns the live
         :class:`RouterRequest`. Raises :class:`RouterShed` (shed — retry
         later) or :class:`NoHealthyReplica` (no capacity at all).
@@ -1042,7 +1096,9 @@ class FleetRouter:
         and swallowed, and ``on_token`` fires only for genuinely new
         tokens. ``bypass_shed`` admits the request even when every healthy
         replica sheds (recovery re-submissions were *already* accepted —
-        shedding them now would lose them)."""
+        shedding them now would lose them). ``t_front_unix`` is when the
+        front door had read the request (unix time); it rides the ``add``
+        command so the replica can observe the delay up to admission."""
         if self.closed:
             raise NoHealthyReplica("router is closed")
         faults.inject("router.submit", priority=priority)
@@ -1054,7 +1110,8 @@ class FleetRouter:
                            priority=priority, deadline=deadline,
                            on_token=on_token, on_finish=on_finish,
                            trace_id=trace_id, on_watermark=on_watermark,
-                           watermark_every=watermark_every, tenant=tenant)
+                           watermark_every=watermark_every, tenant=tenant,
+                           t_front_unix=t_front_unix)
         if replay_tokens:
             rr.tokens = [int(t) for t in replay_tokens]
             rr.suppress = len(rr.tokens)
@@ -1455,7 +1512,10 @@ class FleetRouter:
                 rep.send({"op": "add", "gid": rr.gid, "prompt": rr.prompt,
                           "sampling": rr.sampling, "deadline_s": deadline_s,
                           "trace_id": rr.trace_id, "tenant": rr.tenant,
-                          "priority": rr.priority})
+                          "priority": rr.priority,
+                          "t_front_unix": (rr.t_front_unix
+                                           if not rr.dispatches else None),
+                          "t_sent_unix": telemetry.mono_to_unix(time.monotonic())})
             except (BrokenPipeError, faults.FaultError) as e:
                 self._breaker_record(rep.rid, ok=False)
                 exclude.add(rep.rid)
@@ -1503,7 +1563,7 @@ class FleetRouter:
     def _on_event(self, rep, ev: dict):
         kind = ev.get("ev")
         if kind == "token":
-            self._on_token(rep, ev["gid"], ev["tok"], ev["i"])
+            self._on_token(rep, ev["gid"], ev["tok"], ev["i"], ev.get("t"))
         elif kind == "done":
             self._on_done(rep, ev)
         elif kind == "stats":
@@ -1572,7 +1632,8 @@ class FleetRouter:
                         continue
                     rr.remote_spans.append({**s, "replica": rep.rid})
 
-    def _on_token(self, rep, gid: int, tok: int, i: int):
+    def _on_token(self, rep, gid: int, tok: int, i: int,
+                  t_emit_unix: float | None = None):
         cb = None
         with self._lock:
             rr = self._requests.get(gid)
@@ -1604,6 +1665,7 @@ class FleetRouter:
             if i != len(rr.tokens):
                 return                      # duplicate/out-of-order: drop
             rr.tokens.append(int(tok))
+            rr.last_emit_unix = t_emit_unix
             if rr.first_token_time is None:
                 rr.first_token_time = time.monotonic()
             cb = rr.on_token

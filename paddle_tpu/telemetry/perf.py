@@ -635,6 +635,7 @@ class StepTimeline:
         self._lock = locksan.Lock("perf.step_timeline")
         self._totals: deque = deque(maxlen=self.window)
         self._phases: dict[str, deque] = {}
+        self._occupancy: deque = deque(maxlen=self.window)
         self.steps = 0
         self.regressions = 0
         self.last_regression: dict | None = None
@@ -650,7 +651,11 @@ class StepTimeline:
         return _PhaseCtx(mine, name)
 
     # -- the core record (step() feeds it; tests can too) ---------------
-    def record_step(self, total_s: float, phases: dict):
+    def record_step(self, total_s: float, phases: dict,
+                    occupancy: float | None = None):
+        """``occupancy`` is the share of the step's batch that did work
+        (the serving engine: running slots / ``max_slots``); it is kept
+        over the same window and reported beside the times."""
         if not ENABLED[0]:
             return    # telemetry.disable(): one flag check, like every
         total_s = float(total_s)  # other write path
@@ -664,6 +669,8 @@ class StepTimeline:
             for ph, v in phases.items():
                 self._phases.setdefault(
                     ph, deque(maxlen=self.window)).append(v)
+            if occupancy is not None:
+                self._occupancy.append(float(occupancy))
             self.steps += 1
         pm = _perf_metrics()
         pm.step_s.labels(timeline=self.name).observe(total_s)
@@ -721,12 +728,17 @@ class StepTimeline:
                     "mean": s / len(vals),
                     "frac": s / total_sum if total_sum else 0.0,
                 }
+            if self._occupancy:
+                occ = sorted(self._occupancy)
+                out["occupancy"] = {"mean": sum(occ) / len(occ),
+                                    "p50": _pct(occ, 0.5)}
         return out
 
     def clear(self):
         with self._lock:
             self._totals.clear()
             self._phases.clear()
+            self._occupancy.clear()
             self.steps = 0
             self.regressions = 0
             self.last_regression = None

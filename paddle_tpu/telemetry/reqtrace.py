@@ -11,8 +11,8 @@ leaves no single timeline anyone can read. This module is the glue:
   command). Replica-side engine spans carry it as a span attr
   (``trace_id=...``, or ``trace_ids=[...]`` for batch-level decode ticks
   shared by several requests).
-- **Wire format** — :func:`drain_request_spans` scans the process-global
-  tracer for spans newer than a watermark that carry trace context and
+- **Wire format** — :func:`drain_request_spans` takes from the process-global
+  tracer the spans recorded since its last call that carry trace context and
   serializes them with **unix** timestamps (``tracing.mono_to_unix``), so
   hops from different processes land on one wall-clock timeline; replicas
   attach the drained spans to their periodic heartbeat events, which is
@@ -69,19 +69,18 @@ def spans_to_wire(spans) -> list[dict]:
     return [span_to_wire(s) for s in spans if _carries_context(s.attrs)]
 
 
-def drain_request_spans(last_span_id: int, *,
+def drain_request_spans(mark: int, *,
                         engine_label=None) -> tuple[list[dict], int]:
-    """New trace-context-carrying spans since ``last_span_id`` from the
-    process-global tracer, serialized for the pipe. ``engine_label``
-    filters to one engine's spans — two LocalReplica drivers share a
-    process tracer, and each must heartbeat only its own engine's spans.
-    Returns (wire spans, new watermark)."""
+    """New trace-context-carrying spans since ``mark`` from the
+    process-global tracer, serialized for the pipe. ``mark`` is what the
+    last call returned (0 the first time): the tracer's count of spans
+    recorded, so each call walks only what arrived since, whatever the
+    ring holds (``Tracer.since``). ``engine_label`` filters to one engine's
+    spans — two LocalReplica drivers share a process tracer, and each must
+    heartbeat only its own engine's spans. Returns (wire spans, new mark)."""
+    new, mark = tracer().since(mark)
     out = []
-    wm = int(last_span_id)
-    for s in tracer().spans():
-        if s.span_id <= last_span_id:
-            continue
-        wm = max(wm, s.span_id)
+    for s in new:
         a = s.attrs
         if not _carries_context(a):
             continue
@@ -89,7 +88,7 @@ def drain_request_spans(last_span_id: int, *,
                 str(a.get("engine")) != str(engine_label):
             continue
         out.append(span_to_wire(s))
-    return out, wm
+    return out, mark
 
 
 def wire_trace_ids(wire_span: dict) -> tuple:

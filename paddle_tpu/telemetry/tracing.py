@@ -26,6 +26,7 @@ import json
 import os
 import threading
 import time
+from collections import deque
 
 from .metrics import ENABLED
 from ..analysis import locksan
@@ -104,13 +105,24 @@ class Span:
 class Tracer:
     """Bounded in-process span log. Finished spans append under a lock;
     beyond ``capacity`` the oldest are evicted (``dropped`` counts them) —
-    tracing a long serving run must never grow without bound."""
+    tracing a long serving run must never grow without bound. The ring is
+    a ``deque(maxlen)`` and ``appended`` counts every span ever recorded,
+    so an append costs the same full as empty and :meth:`since` hands a
+    reader only what arrived after its last look."""
 
     def __init__(self, capacity: int = 65536):
         self.capacity = int(capacity)
-        self._spans: list[Span] = []
+        self._spans: deque[Span] = deque(maxlen=self.capacity)
         self._lock = locksan.Lock("tracing.ring")
+        self.appended = 0
         self.dropped = 0
+
+    def _append(self, sp: Span):
+        with self._lock:
+            if len(self._spans) == self.capacity:
+                self.dropped += 1
+            self._spans.append(sp)
+            self.appended += 1
 
     # -- recording -------------------------------------------------------
     def emit(self, name, t0, t1, attrs=None, parent_id=None,
@@ -120,18 +132,25 @@ class Tracer:
             return None
         sp = Span(name, next(_SPAN_IDS), parent_id, float(t0), float(t1),
                   dict(attrs) if attrs else {}, tid=tid, tid_name=tid_name)
-        with self._lock:
-            self._spans.append(sp)
-            if len(self._spans) > self.capacity:
-                excess = len(self._spans) - self.capacity
-                del self._spans[:excess]
-                self.dropped += excess
+        self._append(sp)
         return sp
 
     # -- inspection ------------------------------------------------------
     def spans(self) -> list[Span]:
         with self._lock:
             return list(self._spans)
+
+    def since(self, mark: int) -> tuple[list[Span], int]:
+        """The spans recorded after ``mark`` (a count this method returned
+        earlier; 0 = everything still held), oldest first, and the new
+        mark. Walks back from the tail, so the cost is that of the new
+        spans and not of the ring."""
+        with self._lock:
+            n = max(0, min(self.appended - int(mark), len(self._spans)))
+            new = list(itertools.islice(reversed(self._spans), n))
+            mark = self.appended
+        new.reverse()
+        return new, mark
 
     def find(self, name: str) -> list[Span]:
         return [s for s in self.spans() if s.name == name]
@@ -240,12 +259,7 @@ class _SpanCtx:
             self.attrs.setdefault("error", exc_type.__name__)
         sp = Span(self.name, self.span_id, self.parent_id, self.t0, t1,
                   self.attrs)
-        with self.tracer._lock:
-            self.tracer._spans.append(sp)
-            if len(self.tracer._spans) > self.tracer.capacity:
-                excess = len(self.tracer._spans) - self.tracer.capacity
-                del self.tracer._spans[:excess]
-                self.tracer.dropped += excess
+        self.tracer._append(sp)
         self.span = sp
         return False
 

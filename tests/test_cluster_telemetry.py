@@ -563,10 +563,16 @@ class TestMultiProcess:
             deadline = time.time() + 60
             while time.time() < deadline:
                 report = mon.poll()
-                if report["hang"]["hung"]:
+                # the planted hang is the one at the 3rd collective. While
+                # the two processes start, whichever imports faster waits
+                # in the 1st for longer than the threshold too, and the
+                # monitor rightly says so: that is not the hang under test
+                if report["hang"]["hung"] and \
+                        report["hang"]["waiting_seq"] == 3:
                     break
                 time.sleep(0.05)
             assert report is not None and report["hang"]["hung"]
+            assert report["hang"]["waiting_seq"] == 3
             assert report["hang"]["suspect_ranks"] == [1]
             assert report["hang"]["waiting_ranks"] == [0]
             bundle = agg.collect_postmortem(

@@ -29,6 +29,7 @@ import sys
 # span name -> waterfall phase; lifecycle spans win over live engine spans
 # for the summed phase view (they cover the whole window, ticks overlap)
 _PHASE_PRIMARY = {
+    "replica.inbox_wait": "queue",      # router send -> engine accepted
     "queued": "queue",
     "prefill": "prefill",
     "decode": "decode",
