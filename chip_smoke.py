@@ -58,10 +58,11 @@ LLAMA_7B_WIDTHS = dict(
 
 # 2 layers = 666,914,816 parameters; f32 weights + Adam moments are 8.0 GB
 TRAIN = dict(widths=LLAMA_7B_WIDTHS, layers=2, batch=4, seq=2048, steps=5)
-# 4 layers in bf16 = 2.1 GB of weights, 0.5 GB of KV pool
+# 4 layers in bf16 = 2.1 GB of weights, 0.5 GB of KV pool; the paged kernel
+# alone is also checked at the benchmark's 32 slots (a 1.1 GB pool of one layer)
 SERVE = dict(widths=LLAMA_7B_WIDTHS, layers=4, block_size=16, max_slots=4,
              max_model_len=2048, prompt_lens=(40, 300, 1500),
-             shared_prefix=1024, tail_len=200, new_tokens=32)
+             shared_prefix=1024, tail_len=200, new_tokens=32, paged_slots=32)
 
 FLASH_KERNELS = ("flash_attention_fwd", "flash_attention_bwd_dq",
                  "flash_attention_bwd_dkv")
@@ -183,13 +184,15 @@ def paged_vs_reference(slots, heads, kv_heads, head_dim, block_size,
                     jnp.bfloat16)
     pool = jnp.asarray(rng.standard_normal(
         (num_blocks, 2, kv_heads, block_size, head_dim)), jnp.bfloat16)
-    # every slot owns a shuffled run of blocks; contexts from one token to
-    # the full table, none a multiple of the block size but the last
+    # every slot owns a shuffled run of blocks; contexts from one token (an
+    # idle slot) to the full table, none a multiple of the block size but
+    # the last, with one block and a token, and a few hundred, among them
     tables = (1 + rng.permutation(slots * max_blocks)).reshape(
         slots, max_blocks).astype(np.int32)
     full = max_blocks * block_size
     ctx = np.linspace(1, full, slots).astype(np.int32)
     ctx[1:-1] += 3
+    ctx[1:3] = np.minimum((block_size + 1, 300), full)
     got = jax.jit(paged_attention_pallas)(q, pool, tables, ctx)
     with jax.default_matmul_precision("highest"):
         ref = jax.jit(paged_attention_ref)(
@@ -414,7 +417,7 @@ def serve_leg(size):
 
     with _leg_setup() as (ir_dir, cache_report):
         paged_vs_reference(
-            size["max_slots"], w["num_attention_heads"],
+            size["paged_slots"], w["num_attention_heads"],
             w["num_key_value_heads"],
             w["hidden_size"] // w["num_attention_heads"], size["block_size"],
             max_blocks=size["max_model_len"] // size["block_size"])

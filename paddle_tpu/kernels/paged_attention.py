@@ -9,16 +9,23 @@ computes, for one query token per slot,
     out[s] = softmax(q[s] @ K[s, :ctx[s]]^T) @ V[s, :ctx[s]]
 
 where K/V are *gathered through the block table* — the ragged part: slots
-have arbitrary context lengths but the kernel runs on one static grid
+have arbitrary context lengths but the kernel is one compiled program
 (Ragged Paged Attention, PAPERS.md).
 
-TPU shape: grid (slots, kv_heads, max_blocks); the block tables and context
-lengths ride in scalar-prefetch (``pltpu.PrefetchScalarGridSpec``) so the
-K/V BlockSpec index maps dereference ``block_tables[s, j]`` to pick which
-pool block to DMA next — the gather happens in the pipeline, not in the
-kernel body. Streaming softmax (m, l, acc) carries across the inner
-block-grid dimension in VMEM scratch, exactly like flash attention's inner
-loop; blocks past the context frontier are skipped via ``pl.when``.
+TPU shape: the grid is the slots; the block tables and context lengths ride
+in scalar prefetch (``pltpu.PrefetchScalarGridSpec``) and the pool stays in
+HBM. Inside a slot the kernel walks only the slot's live pages,
+``cdiv(context_lens[s], block_size)`` of them, read at run time: a loop of
+compute steps, each over ``_pages_per_step`` pages (at least a lane width of
+tokens). A page is fetched by one async copy as the pool keeps it, the
+contiguous ``[2, kv_heads, block_size, head_dim]`` that holds K and V of all
+KV heads, into one of two VMEM landing buffers; while one buffer is attended
+over, the next step's pages (the next slot's first, at a slot's end) are
+already in flight into the other. The dots take K, V and the probabilities
+in the pool's dtype (bf16 into the MXU) for all KV heads at once; the
+streaming softmax (m, l, acc) is float32 and carries through the loop. So
+the work follows the live K/V, not ``slots x kv_heads x max_blocks``: a slot
+with one token costs one page.
 
 Selection policy (the flash_attention / rmsnorm idiom): the Pallas kernel
 runs on real TPU; under ``JAX_PLATFORMS=cpu`` (tests) and inside the
@@ -82,54 +89,181 @@ def paged_attention_ref(q, kv_pool, block_tables, context_lens, *,
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _paged_kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, block_size, sm_scale, max_blocks):
-    """Grid (slots, kv_heads, max_blocks); scalar-prefetch refs first.
+# The least KV length of one compute step: a lane width, so the score tile
+# [rep, T] fills whole vregs and the dots have MXU-sized operands.
+_MIN_STEP_TOKENS = 128
+# What the two K/V landing buffers may take of VMEM. Well under the 16 MB a
+# v5e core scopes to one kernel: the score and probability tiles, the
+# pipelined q/out blocks and Mosaic's own temporaries share the rest.
+_KV_BUFFER_BYTES = 4 * 1024 * 1024
 
-    q_ref: [1, 1, rep, D] — this kv head's query rows for slot s
-    k_ref/v_ref: [1, 1, 1, bs, D] — pool block bt[s, j] for this head
-    o_ref: [1, 1, rep, D]; m/l/acc: VMEM scratch carried across j.
+
+def _pages_per_step(block_size, kv_heads, head_dim, itemsize, max_blocks):
+    """How many pool blocks one compute step attends over, from the shapes
+    alone: enough for ``_MIN_STEP_TOKENS`` tokens, no more than the table
+    holds or than two buffers of them fit in ``_KV_BUFFER_BYTES``."""
+    page_bytes = 2 * kv_heads * block_size * head_dim * itemsize
+    fit = max(1, _KV_BUFFER_BYTES // (2 * page_bytes))
+    want = pl.cdiv(_MIN_STEP_TOKENS, block_size)
+    return min(want, fit, max_blocks)
+
+
+def _paged_kernel(bt_ref, ctx_ref, q_ref, pool_hbm, o_ref,
+                  kv_buf, sems, buf_ref, *, sm_scale):
+    """Grid (slots,); scalar-prefetch refs first.
+
+    bt_ref [S, M], ctx_ref [S]: SMEM. q_ref/o_ref: [1, Hkv, rep, D], this
+    slot's rows. pool_hbm: the whole pool, left in HBM. kv_buf:
+    [2, 2, Hkv, P, bs, D] — two landing buffers of P pages, K and V of
+    every KV head. sems: one DMA semaphore a buffer. buf_ref: SMEM [1], the
+    buffer the *next* compute step reads (carried across grid steps).
+
+    The work is the flat sequence of (slot, step) pairs with
+    ``step < cdiv(pages(slot), P)``; each pair waits for its own pages and
+    has already started the next pair's copies, across slot boundaries too,
+    so only the very first copy of a call is exposed.
     """
     s = pl.program_id(0)
-    j = pl.program_id(2)
+    num_slots = pl.num_programs(0)
+    _, _, Hkv, P, bs, D = kv_buf.shape
+    rep = q_ref.shape[2]
+    T = P * bs
+    max_blocks = bt_ref.shape[1]
+
+    def live_pages(slot):
+        # a slot always owns page 0 (ctx >= 1 by contract; ctx 0 would read
+        # page 0 fully masked); never past the table
+        return jnp.clip(pl.cdiv(ctx_ref[slot], bs), 1, max_blocks)
+
+    def page_copy(slot, page, buf):
+        """The copy of table entry ``page`` of ``slot`` into its place in
+        landing buffer ``buf``. The one place that addresses the pool: a
+        later pool of all layers adds its layer index to ``pool_hbm.at``
+        here."""
+        return pltpu.make_async_copy(
+            pool_hbm.at[bt_ref[slot, page]],
+            kv_buf.at[buf, :, :, page % P],
+            sems.at[buf])
+
+    def for_each_live_page(slot, step, do):
+        """``do(page)`` for each live page of compute step ``step``."""
+        first = step * P
+        jax.lax.fori_loop(
+            first, jnp.minimum(first + P, live_pages(slot)),
+            lambda page, _: do(page), None)
+
+    def start(slot, step, buf):
+        for_each_live_page(
+            slot, step, lambda page: page_copy(slot, page, buf).start())
+
+    def wait(slot, step, buf):
+        for_each_live_page(
+            slot, step, lambda page: page_copy(slot, page, buf).wait())
+
+    @pl.when(s == 0)
+    def _first():
+        buf_ref[0] = 0
+        start(0, 0, 0)
+
     ctx = ctx_ref[s]
+    n_pages = live_pages(s)
+    n_steps = pl.cdiv(n_pages, P)
+    buf0 = buf_ref[0]
+    # operands in the pool's dtype (bf16 stays bf16 into the MXU); the scale
+    # is applied in f32 first, the statistics below stay f32
+    q = (q_ref[0].astype(jnp.float32) * sm_scale).astype(kv_buf.dtype)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def step_body(i, carry):
+        m_prev, l_prev, acc = carry
+        buf = (buf0 + i) % 2
+        last = i + 1 == n_steps
+        nxt_slot = jnp.where(last, s + 1, s)
+        nxt_step = jnp.where(last, 0, i + 1)
 
-    # blocks entirely past the context frontier contribute nothing
-    @pl.when(j * block_size < ctx)
-    def _attend():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale       # [rep, D]
-        k = k_ref[0, 0, 0].astype(jnp.float32)               # [bs, D]
-        v = v_ref[0, 0, 0].astype(jnp.float32)
+        @pl.when(nxt_slot < num_slots)
+        def _prefetch():
+            start(nxt_slot, nxt_step, 1 - buf)
+
+        wait(s, i, buf)
+        # pages of this step past the live ones were not copied: whatever
+        # the buffer held there is masked out of the scores, but 0 * V must
+        # stay finite, so their V is zeroed (no copy in flight targets them)
+        def zero_v(p, _):
+            kv_buf[buf, 1, :, p] = jnp.zeros((Hkv, bs, D), kv_buf.dtype)
+
+        jax.lax.fori_loop(jnp.minimum(n_pages - i * P, P), P, zero_v, None)
+
+        k = kv_buf[buf, 0].reshape(Hkv, T, D)
+        v = kv_buf[buf, 1].reshape(Hkv, T, D)
         # explicit DEFAULT: the package-wide tensorfloat32 default would
         # ask Mosaic for Precision.HIGH, which it does not lower
-        s_blk = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+        sc = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.DEFAULT)             # [rep, bs]
-        pos = j * jnp.int32(block_size) + jax.lax.broadcasted_iota(
-            jnp.int32, s_blk.shape, 1)
-        s_blk = jnp.where(pos < ctx, s_blk, NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=1, keepdims=True))
-        p = jnp.exp(s_blk - m_new)
+            precision=jax.lax.Precision.DEFAULT)             # [Hkv, rep, T]
+        pos = i * T + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 2)
+        sc = jnp.where(pos < ctx, sc, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=2, keepdims=True))
+        p_blk = jnp.exp(sc - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+        l_new = alpha * l_prev + jnp.sum(p_blk, axis=2, keepdims=True)
+        acc = alpha * acc + jax.lax.dot_general(
+            p_blk.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.DEFAULT)
+            precision=jax.lax.Precision.DEFAULT)             # [Hkv, rep, D]
+        return m_new, l_new, acc
 
-    @pl.when(j == max_blocks - 1)
-    def _finalize():
-        l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+    m0 = jnp.full((Hkv, rep, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((Hkv, rep, 1), jnp.float32)
+    acc0 = jnp.zeros((Hkv, rep, D), jnp.float32)
+    _, l_fin, acc = jax.lax.fori_loop(0, n_steps, step_body, (m0, l0, acc0))
+    buf_ref[0] = (buf0 + n_steps) % 2
+    o_ref[0] = (acc / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+def _paged_call(q4, kv_pool, block_tables, context_lens, *, sm_scale,
+                interpret):
+    """The ``pallas_call`` on ``q4 [S, Hkv, rep, D]``. Jitted so that a step
+    that calls it once a layer traces and lowers the kernel once: the
+    layers' calls have the same shapes and share the one traced function
+    (XLA inlines it; the device op is still ``paged_attention``)."""
+    S, Hkv, rep, D = q4.shape
+    bs = kv_pool.shape[3]
+    P = _pages_per_step(bs, Hkv, D, kv_pool.dtype.itemsize,
+                        block_tables.shape[1])
+
+    def slot_rows(s, bt, ctx):
+        return (s, 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # block_tables, context_lens
+        grid=(S,),
+        in_specs=[
+            pl.BlockSpec((1, Hkv, rep, D), slot_rows),
+            # the pool stays in HBM; the kernel copies the pages it needs
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, Hkv, rep, D), slot_rows),
+        scratch_shapes=[
+            pltpu.VMEM((2, 2, Hkv, P, bs, D), kv_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    with x64_off():
+        return pl.pallas_call(
+            functools.partial(_paged_kernel, sm_scale=sm_scale),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
+            # slots run in order: the landing buffers and the buffer index
+            # carry from one slot to the next
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+            name="paged_attention",
+        )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
+          q4, kv_pool)
 
 
 def paged_attention_pallas(q, kv_pool, block_tables, context_lens, *,
@@ -137,9 +271,7 @@ def paged_attention_pallas(q, kv_pool, block_tables, context_lens, *,
     """Pallas ragged paged attention; see :func:`paged_attention_ref` for
     the argument contract. ``interpret`` defaults to the platform policy."""
     S, Hq, D = q.shape
-    N, _, Hkv, bs, _ = kv_pool.shape
-    M = block_tables.shape[1]
-    rep = Hq // Hkv
+    Hkv = kv_pool.shape[2]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
     # this body runs at TRACE time (the args are tracers inside the engine's
     # jitted step), so one record here is one Pallas kernel build — the
@@ -153,44 +285,11 @@ def paged_attention_pallas(q, kv_pool, block_tables, context_lens, *,
             ("q", "kv_pool", "block_tables", "context_lens")))
     if interpret is None:
         interpret = _interpret_mode()
-    bt = block_tables.astype(jnp.int32)
-    ctx = context_lens.astype(jnp.int32)
-    # [S, Hkv, rep, D]: a (rep, D) block is then the full extent of the
-    # last two dims, which Mosaic tiles for any rep (a (1, rep, D) block
-    # over [S, Hq, D] is neither a multiple of 8 rows nor the full dim)
-    q4 = q.reshape(S, Hkv, rep, D)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block_tables, context_lens
-        grid=(S, Hkv, M),
-        in_specs=[
-            # this slot's query rows for kv head h: rows [h*rep, (h+1)*rep)
-            pl.BlockSpec((1, 1, rep, D),
-                         lambda s, h, j, bt, ctx: (s, h, 0, 0)),
-            # K / V pool block bt[s, j] for head h (same pool array twice)
-            pl.BlockSpec((1, 1, 1, bs, D),
-                         lambda s, h, j, bt, ctx: (bt[s, j], 0, h, 0, 0)),
-            pl.BlockSpec((1, 1, 1, bs, D),
-                         lambda s, h, j, bt, ctx: (bt[s, j], 1, h, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rep, D),
-                               lambda s, h, j, bt, ctx: (s, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rep, 1), jnp.float32),   # m
-            pltpu.VMEM((rep, 1), jnp.float32),   # l
-            pltpu.VMEM((rep, D), jnp.float32),   # acc
-        ],
-    )
-    kern = functools.partial(_paged_kernel, block_size=bs, sm_scale=scale,
-                             max_blocks=M)
-    with x64_off():
-        out = pl.pallas_call(
-            kern,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((S, Hkv, rep, D), q.dtype),
-            interpret=interpret,
-            name="paged_attention",
-        )(bt, ctx, q4, kv_pool, kv_pool)
+    # [S, Hkv, rep, D]: a (Hkv, rep, D) block is then the full extent of
+    # the last two dims, which Mosaic tiles for any rep (a block of rep rows
+    # of [S, Hq, D] is neither a multiple of 8 rows nor the full dim)
+    out = _paged_call(q.reshape(S, Hkv, Hq // Hkv, D), kv_pool, block_tables,
+                      context_lens, sm_scale=scale, interpret=interpret)
     return out.reshape(S, Hq, D)
 
 

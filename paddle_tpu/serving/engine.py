@@ -1148,6 +1148,7 @@ class LLMEngine:
         marks.append(time.monotonic())
         new_trace = self._decode_fn is None
         cost_est = None
+        live_share = None
         done = False
         try:
             try:
@@ -1183,6 +1184,12 @@ class LLMEngine:
                 share = 1.0 / len(running)
                 for req in running.values():
                     self._charge_tenant(req.tenant, "decode", "decode", share)
+                # how much of the running slots' block tables the paged
+                # kernel walks this step (its context is ctx + 1: the
+                # token being written counts)
+                live = -(-(host[2][list(running)] + 1) // self.block_size)
+                live_share = float(live.sum()) / (
+                    len(running) * self.max_blocks)
                 if self.prefix_cache:
                     # a decode write that just filled its block completes
                     # another full token-block: index it so later
@@ -1210,23 +1217,25 @@ class LLMEngine:
                     decode_s=self.last_decode_s,
                     limit_s=self.watchdog_timeout_s)
             if not done:
-                self._account_decode(marks, len(running), new_trace,
-                                     cost_est, done=False)
+                self._account_decode(marks, len(running), live_share,
+                                     new_trace, cost_est, done=False)
         with telemetry.span("engine.emit"):
             for slot, req in running.items():
                 self._emit(slot, req, int(toks[slot]))
         marks.append(time.monotonic())
-        return marks, len(running), new_trace, cost_est
+        return marks, len(running), live_share, new_trace, cost_est
 
-    def _account_decode(self, marks, n_running, new_trace, cost_est,
-                        done=True):
-        """Book one decode step's time once it is known: its phases and
-        occupancy into the StepTimeline, the call into the compile watcher
-        and, for a step that ran to its end, its roofline fraction."""
+    def _account_decode(self, marks, n_running, live_share, new_trace,
+                        cost_est, done=True):
+        """Book one decode step's time once it is known: its phases,
+        occupancy and live share of the block tables into the StepTimeline,
+        the call into the compile watcher and, for a step that ran to its
+        end, its roofline fraction."""
         phases = {ph: t1 - t0 for ph, t0, t1 in
                   zip(self._DECODE_PHASES, marks, marks[1:])}
         self._decode_tl.record_step(marks[-1] - marks[0], phases,
-                                    occupancy=n_running / self.max_slots)
+                                    occupancy=n_running / self.max_slots,
+                                    live_block_share=live_share)
         self._watcher.record_call(
             "engine.decode",
             (("tokens", (self.max_slots,), "int32"),

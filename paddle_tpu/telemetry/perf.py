@@ -635,7 +635,11 @@ class StepTimeline:
         self._lock = locksan.Lock("perf.step_timeline")
         self._totals: deque = deque(maxlen=self.window)
         self._phases: dict[str, deque] = {}
-        self._occupancy: deque = deque(maxlen=self.window)
+        # per-step shares kept over the same window and reported beside
+        # the times, mean and median (see record_step)
+        self._shares: dict[str, deque] = {
+            name: deque(maxlen=self.window)
+            for name in ("occupancy", "live_block_share")}
         self.steps = 0
         self.regressions = 0
         self.last_regression: dict | None = None
@@ -652,10 +656,14 @@ class StepTimeline:
 
     # -- the core record (step() feeds it; tests can too) ---------------
     def record_step(self, total_s: float, phases: dict,
-                    occupancy: float | None = None):
+                    occupancy: float | None = None,
+                    live_block_share: float | None = None):
         """``occupancy`` is the share of the step's batch that did work
-        (the serving engine: running slots / ``max_slots``); it is kept
-        over the same window and reported beside the times."""
+        (the serving engine: running slots / ``max_slots``);
+        ``live_block_share`` the share of the running slots' block-table
+        entries that hold context (what the paged kernel walks, of what a
+        static grid over the table would). Both are kept over the same
+        window and reported beside the times."""
         if not ENABLED[0]:
             return    # telemetry.disable(): one flag check, like every
         total_s = float(total_s)  # other write path
@@ -669,8 +677,10 @@ class StepTimeline:
             for ph, v in phases.items():
                 self._phases.setdefault(
                     ph, deque(maxlen=self.window)).append(v)
-            if occupancy is not None:
-                self._occupancy.append(float(occupancy))
+            for name, v in (("occupancy", occupancy),
+                            ("live_block_share", live_block_share)):
+                if v is not None:
+                    self._shares[name].append(float(v))
             self.steps += 1
         pm = _perf_metrics()
         pm.step_s.labels(timeline=self.name).observe(total_s)
@@ -728,17 +738,19 @@ class StepTimeline:
                     "mean": s / len(vals),
                     "frac": s / total_sum if total_sum else 0.0,
                 }
-            if self._occupancy:
-                occ = sorted(self._occupancy)
-                out["occupancy"] = {"mean": sum(occ) / len(occ),
-                                    "p50": _pct(occ, 0.5)}
+            for name, d in self._shares.items():
+                if d:
+                    vals = sorted(d)
+                    out[name] = {"mean": sum(vals) / len(vals),
+                                 "p50": _pct(vals, 0.5)}
         return out
 
     def clear(self):
         with self._lock:
             self._totals.clear()
             self._phases.clear()
-            self._occupancy.clear()
+            for d in self._shares.values():
+                d.clear()
             self.steps = 0
             self.regressions = 0
             self.last_regression = None

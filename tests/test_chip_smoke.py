@@ -25,7 +25,7 @@ WIDTHS = dict(vocab_size=512, hidden_size=128, intermediate_size=256,
 TRAIN = dict(widths=WIDTHS, layers=2, batch=4, seq=128)
 SERVE = dict(widths=WIDTHS, layers=2, block_size=8, max_slots=4,
              max_model_len=256, prompt_lens=(10, 40, 150), shared_prefix=96,
-             tail_len=20, new_tokens=4)
+             tail_len=20, new_tokens=4, paged_slots=8)
 
 
 @pytest.fixture

@@ -191,6 +191,18 @@ class TestMemoryMonitor:
 # ---------------------------------------------------------------------------
 
 class TestStepTimeline:
+    @pytest.mark.parametrize("name", ["occupancy", "live_block_share"])
+    def test_shares_reported_beside_the_times(self, name):
+        tl = perf.StepTimeline("t_shares")
+        tl.record_step(0.010, {})                  # a step without shares
+        assert name not in tl.report()
+        for v in (0.25, 0.5, 1.0):
+            tl.record_step(0.010, {}, **{name: v})
+        assert tl.report()[name] == {"mean": pytest.approx(1.75 / 3),
+                                     "p50": 0.5}
+        tl.clear()
+        assert name not in tl.report()
+
     def test_phase_math_and_other(self):
         tl = perf.StepTimeline("t1")
         tl.record_step(0.010, {"data": 0.002, "compute": 0.006})
@@ -321,6 +333,24 @@ class TestEnginePerf:
         # process's: other suites' engines are in its window too)
         occ = p["decode_step"]["occupancy"]
         assert 0.0 < occ["mean"] <= 1.0 and 0.0 < occ["p50"] <= 1.0
+
+    def test_live_block_share_follows_the_contexts(self):
+        """The share of the running slots' table entries that hold context:
+        the kernel's context is the host's ctx + 1 (the token being written
+        counts), so a 7-token prompt decodes at 8, 9, 10 tokens: 1, 2, 2 of
+        48 / 8 = 6 blocks, whatever the idle slot holds."""
+        paddle_tpu.seed(0)
+        cfg = llama_tiny(vocab=61, hidden=32, layers=2, heads=4, kv_heads=2,
+                         inter=64, seq=64)
+        eng = LLMEngine(LlamaForCausalLM(cfg), block_size=8, max_slots=2,
+                        max_model_len=48)
+        eng._decode_tl.clear()          # the process's timeline: start clean
+        eng.generate([[1, 2, 3, 4, 5, 6, 7]],
+                     SamplingParams(max_new_tokens=4))
+        live = eng.stats()["perf"]["decode_step"]["live_block_share"]
+        assert live["mean"] == pytest.approx((1 + 2 + 2) / 3 / 6)
+        assert live["p50"] == pytest.approx(2 / 6)
+        eng.close()
 
     def test_memory_tags_registered(self, served):
         eng, _ = served

@@ -78,6 +78,38 @@ class TestBlockAllocator:
 # ragged paged attention kernel
 # ---------------------------------------------------------------------------
 
+def _published_decode_shapes(sharding=None):
+    """The benchmark's decode call (Mistral-7B-v0.3 widths, the engine's 32
+    slots of 128 blocks of 16, the default pool), as shapes."""
+    S, Hq, Hkv, D, bs, M, N = 32, 32, 8, 128, 16, 128, 4096
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return (sds((S, Hq, D), jnp.bfloat16),
+            sds((N, 2, Hkv, bs, D), jnp.bfloat16),
+            sds((S, M), jnp.int32), sds((S,), jnp.int32))
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a described (not attached) v5e host, for compiles that
+    need no chip. Only here and only in a fixture: the process that
+    describes a topology loads libtpu and keeps it until it exits."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else /tmp/tpu_logs
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
 class TestPagedAttentionKernel:
     def _case(self, seed, S=4, Hq=4, Hkv=2, D=16, bs=8, N=12, M=3):
         rng = np.random.RandomState(seed)
@@ -116,6 +148,71 @@ class TestPagedAttentionKernel:
             np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
                                        atol=1e-5)
 
+    # the walk's shapes on the CPU: 40 blocks of 8 a slot, so a compute step
+    # is _pages_per_step = 16 pages = 128 tokens and a full slot takes
+    # three steps, the last one half empty
+    WALK = dict(S=4, Hkv=2, D=16, bs=8, M=40)
+    _STEP = 16 * 8
+    _FULL = 40 * 8
+    WALK_CONTEXTS = {
+        "one_token": [1, 1, 1, 1],
+        "block_boundary": [8 - 1, 8, 8 + 1, 2 * 8 + 1],
+        "step_boundary": [_STEP - 1, _STEP, _STEP + 1, 2 * _STEP],
+        "inactive_beside_full": [1, _FULL, 1, _FULL],
+        "ragged": [3, _STEP + 8 + 5, 2 * _STEP - 1, _FULL - 1],
+    }
+    _walk_fns = {}
+
+    @pytest.mark.parametrize("dtype,rep,atol", [
+        ("float32", 1, 1e-5), ("float32", 4, 1e-5),
+        # bf16 pool and query: the kernel rounds q * scale and the
+        # probabilities to bf16 before its dots (2^-9 relative each, as the
+        # MXU's DEFAULT precision did to the old kernel's f32 tiles) and
+        # both sides round the output to bf16 (2^-9 of |out| <= 3.2 is
+        # 0.006): 0.002 observed, 0.02 allowed, against 1e4 for a page
+        # folded in by mistake
+        ("bfloat16", 4, 2e-2)])
+    @pytest.mark.parametrize("contexts", list(WALK_CONTEXTS))
+    def test_walk_matches_mirror(self, contexts, dtype, rep, atol):
+        """The Pallas body in interpret mode (the installed interpreter runs
+        the async copies, semaphores and SMEM scratch as they are, and hands
+        out uninitialised VMEM as NaN) against the mirror, over what a walk
+        of live pages can get wrong. Every pool position that holds no
+        context (unreferenced blocks, block 0, which dead table entries
+        point at, and the tail of each slot's last block) is 1e4: large,
+        finite, and visible in the output if it is ever folded in."""
+        from paddle_tpu.kernels.paged_attention import _pages_per_step
+
+        w = self.WALK
+        S, Hkv, D, bs, M = w["S"], w["Hkv"], w["D"], w["bs"], w["M"]
+        assert _pages_per_step(bs, Hkv, D, 4, M) * bs == self._STEP
+        ctx = np.asarray(self.WALK_CONTEXTS[contexts], np.int32)
+        rng = np.random.RandomState(len(contexts) + rep)
+        N = S * M + 1
+        pool = np.full((N, 2, Hkv, bs, D), 1e4, np.float32)
+        bt = np.zeros((S, M), np.int32)
+        blocks = iter(1 + rng.permutation(N - 1))
+        for s in range(S):
+            for j in range(-(-ctx[s] // bs)):
+                bt[s, j] = b = next(blocks)
+                n = min(bs, ctx[s] - j * bs)
+                pool[b, :, :, :n] = rng.randn(2, Hkv, n, D)
+        q = rng.randn(S, Hkv * rep, D)
+        args = (jnp.asarray(q, dtype), jnp.asarray(pool, dtype),
+                jnp.asarray(bt), jnp.asarray(ctx))
+        # one trace a (dtype, rep): the contexts are data, not shapes
+        fn = self._walk_fns.setdefault((dtype, rep), jax.jit(
+            lambda *a: paged_attention_pallas(*a, interpret=True)))
+        got = np.asarray(fn(*args)).astype(np.float32)
+        ref = np.asarray(paged_attention_ref(*args)).astype(np.float32)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, ref, atol=atol)
+
+    @staticmethod
+    def _mosaic_calls(lowered_text):
+        return [l for l in lowered_text.splitlines()
+                if "paged_attention" in l and "tpu_custom_call" in l]
+
     @pytest.mark.parametrize("Hq,Hkv", [(8, 8), (8, 2)])   # MHA: rep = 1
     def test_lowers_for_mosaic(self, Hq, Hkv):
         """What the interpreter never checks: Mosaic's block-shape rule (the
@@ -133,8 +230,30 @@ class TestPagedAttentionKernel:
             sds((N, 2, Hkv, bs, D), jnp.bfloat16),
             sds((S, M), jnp.int32), sds((S,), jnp.int32)).lower(
             lowering_platforms=("tpu",)).as_text()
-        assert any("paged_attention" in l and "tpu_custom_call" in l
-                   for l in text.splitlines())
+        assert self._mosaic_calls(text)
+
+    def test_lowers_at_published_shape(self):
+        """The benchmark's own decode call: one Mosaic call, named for the
+        roofline metric's reader, and nothing of the attention beside it."""
+        text = jax.jit(
+            lambda *a: paged_attention_pallas(*a, interpret=False)).trace(
+            *_published_decode_shapes()).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert len(self._mosaic_calls(text)) == 1
+        assert text.count("tpu_custom_call") == 1
+        assert "stablehlo.dot" not in text and "stablehlo.exp" not in text
+
+    def test_compiles_at_published_shape_for_v5e(self, v5e_chip):
+        """The TPU compiler, for a described v5e chip, refuses a kernel that
+        overruns VMEM or slices against the tiling ("RESOURCE_EXHAUSTED: Ran
+        out of memory in memory space vmem", PERF.md 7 a), as the chip
+        would; the same with an f32 pool, whose pages are twice the bytes."""
+        fn = jax.jit(lambda *a: paged_attention_pallas(*a, interpret=False))
+        shapes = _published_decode_shapes(v5e_chip)
+        fn.trace(*shapes).lower(lowering_platforms=("tpu",)).compile()
+        f32 = [jax.ShapeDtypeStruct(a.shape, jnp.float32, sharding=v5e_chip)
+               if a.dtype == jnp.bfloat16 else a for a in shapes]
+        fn.trace(*f32).lower(lowering_platforms=("tpu",)).compile()
 
     def test_single_token_context(self):
         q, pool, bt, _ = self._case(2)
