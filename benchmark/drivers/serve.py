@@ -1,6 +1,8 @@
 """Serve driver: Gateway -> FleetRouter -> LocalReplica -> LLMEngine under a
 traffic mix, as ``chip_smoke.serve_leg`` builds the stack, measured from the
-client's side of ``POST /v1/completions`` with ``"stream": true``.
+client's side of ``POST /v1/completions`` with ``"stream": true``. Which
+model the engine serves is the configuration's architecture's to say
+(``ctx.arch``: ``build_model``, ``shapes``).
 
 Set-up: weights from the seed (the benchmark's, put into the program's
 model), the cell's prefill buckets and the decode step warmed through the
@@ -20,7 +22,7 @@ import numpy as np
 
 from benchmark.lib import harness, loadgen, window
 from benchmark.lib import weights as weights_mod
-from benchmark.drivers_common import Tracing, llama_config
+from benchmark.drivers_common import Tracing
 
 
 @contextlib.contextmanager
@@ -52,16 +54,15 @@ def placeholder_parameters():
         layer_mod.Layer.create_parameter = orig
 
 
-def build_model(cfg, seed, max_positions):
+def build_model(ctx):
     """The program's model holding the benchmark's weights, in the dtype the
     configuration serves in."""
     import jax
 
-    from paddle_tpu.models import LlamaForCausalLM
-
+    cfg = ctx.cfg
     with placeholder_parameters():
-        model = LlamaForCausalLM(llama_config(cfg, max_positions))
-    w = weights_mod.make_weights(cfg, seed, cfg["dtype"])
+        model = ctx.arch.build_model(cfg, cfg["max_position_embeddings"])
+    w = weights_mod.make_weights(ctx.arch.shapes(cfg), ctx.seed, cfg["dtype"])
     for name, p in model.named_parameters():
         if name not in w or tuple(p._value.shape) != w[name].shape:
             raise ValueError(f"the model's {name} {tuple(p._value.shape)} has "
@@ -107,7 +108,7 @@ def run(ctx):
     cfg, mix = ctx.cfg, ctx.traffic
     eng = dict(cfg["engine"])
     vocab = cfg["vocab_size"]
-    model = build_model(cfg, ctx.seed, cfg["max_position_embeddings"])
+    model = build_model(ctx)
     ctx.log("model built with the benchmark's weights")
 
     lg = None
@@ -259,7 +260,7 @@ def reference_gaps(ctx, done, chk):
     if not sample:
         return {"max": None, "mean": None}, None, 0
     pad, rows = int(chk["pad_to"]), int(chk["rows"])
-    w = weights_mod.make_weights(cfg, ctx.seed, cfg["dtype"])
+    w = weights_mod.make_weights(ctx.arch.shapes(cfg), ctx.seed, cfg["dtype"])
     control = ctx.control
 
     @jax.jit

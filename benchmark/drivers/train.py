@@ -1,6 +1,9 @@
-"""Train driver: ``LlamaPipelineTrainer.step`` on a one-mesh job, as
-``chip_smoke.train_leg`` builds it, with steps enqueued back to back for the
-window (fresh device-staged batches, rotated, as ``bench.py::_make_bufs``).
+"""Train driver: the program's pipeline trainer's ``step`` on a one-mesh job,
+as ``chip_smoke.train_leg`` builds it, with steps enqueued back to back for
+the window (fresh device-staged batches, rotated, as ``bench.py::_make_bufs``).
+Which trainer, and how its state lays the weights' leaves out, is the
+configuration's architecture's to say (``ctx.arch``: ``build_trainer``,
+``shapes``, ``trainer_layout``, ``trainer_leaf_norms``).
 
 Set-up builds ONE trainer, puts the benchmark's weights (from the seed) into
 its state, drives it through its first steps on batches whose rows all
@@ -24,48 +27,13 @@ from benchmark.lib import harness, traffic as traffic_mod
 from benchmark.lib import weights as weights_mod
 
 
-def _trainer_layout(flat, n_layers):
-    """The benchmark's flat leaves in the trainer's layout: decoder blocks
-    stacked [stages=1, layers, ...] under ``blocks.``, and embed/norm/head."""
-    import jax.numpy as jnp
-
-    out = {"embed.weight": flat["embed_tokens.weight"],
-           "norm.weight": flat["norm.weight"],
-           "head.weight": flat["lm_head.weight"]}
-    keys = [n[len("layers.0."):] for n in flat if n.startswith("layers.0.")]
-    for k in keys:
-        out["blocks." + k] = jnp.stack(
-            [flat[f"layers.{i}.{k}"] for i in range(n_layers)])[None]
-    return out
-
-
-def _leaf_norms(tree):
-    """Per-leaf L2 norms under the benchmark's flat names; a stacked block
-    leaf gives one norm per layer."""
-    import jax.numpy as jnp
-
-    out = {}
-    for n, a in tree.items():
-        a = a.astype(jnp.float32)
-        if n.startswith("blocks."):
-            per = jnp.sqrt(jnp.sum(jnp.square(a), axis=tuple(range(2, a.ndim))))
-            for i in range(a.shape[1]):
-                out[f"layers.{i}.{n[len('blocks.'):]}"] = per[0, i]
-        else:
-            flat = {"embed.weight": "embed_tokens.weight",
-                    "head.weight": "lm_head.weight"}.get(n, n)
-            out[flat] = jnp.sqrt(jnp.sum(jnp.square(a)))
-    return out
-
-
 def build_trainer(ctx):
     import jax
 
-    from benchmark.drivers_common import llama_config
     from paddle_tpu.distributed.mesh import build_mesh
-    from paddle_tpu.models.llama_pipeline import LlamaPipelineTrainer
     from paddle_tpu.optimizer import AdamW
 
+    arch = ctx.arch
     cfg, tr, oc = ctx.cfg, ctx.cfg["trainer"], ctx.cfg["optimizer"]
     if tr.get("remat_policy"):
         os.environ["PADDLE_TPU_REMAT_POLICY"] = tr["remat_policy"]
@@ -73,20 +41,18 @@ def build_trainer(ctx):
     opt = AdamW(learning_rate=oc["learning_rate"], beta1=oc["beta1"],
                 beta2=oc["beta2"], epsilon=oc["epsilon"],
                 weight_decay=oc["weight_decay"])
-    trainer = LlamaPipelineTrainer(
-        llama_config(cfg, cfg["max_position_embeddings"]), mesh, opt,
-        n_micro=tr["n_micro"], zero_stage=tr["zero_stage"], seed=0)
+    trainer = arch.build_trainer(cfg, mesh, opt)
     trainer._init_state()
     params, opt_state = trainer._state
     shardings = {n: v.sharding for n, v in params.items()}
     shapes = {n: v.shape for n, v in params.items()}
     for v in params.values():
         v.delete()
-    n_layers = cfg["num_hidden_layers"]
+    leaves = arch.shapes(cfg)
 
     def build(key):
-        return _trainer_layout(
-            weights_mod.build_flat(key, cfg, cfg["dtype"]), n_layers)
+        return arch.trainer_layout(
+            weights_mod.build_flat(key, leaves, cfg["dtype"]), cfg)
 
     new = jax.jit(build, out_shardings=shardings)(
         weights_mod.seed_key(ctx.seed))
@@ -123,12 +89,13 @@ def first_steps(ctx, trainer, bufs, build):
         if i == 0:
             m1 = {n: st["moment1"] for n, st in trainer._state[1].items()}
             grad_norms = {n: float(v) / (1.0 - beta1) for n, v in
-                          jax.jit(_leaf_norms)(m1).items()}
+                          jax.jit(ctx.arch.trainer_leaf_norms)(m1).items()}
 
     def change(params, key):
         init = build(key)
-        return _leaf_norms({n: params[n].astype(jnp.float32)
-                            - init[n].astype(jnp.float32) for n in params})
+        return ctx.arch.trainer_leaf_norms(
+            {n: params[n].astype(jnp.float32) - init[n].astype(jnp.float32)
+             for n in params})
 
     upd = jax.jit(change)(trainer._state[0], weights_mod.seed_key(ctx.seed))
     return {"losses": losses, "grad_norms": grad_norms,
@@ -152,7 +119,8 @@ def reference_steps(ctx, host_batches, linear=None, keep_rows=None):
     chk = ctx.traffic["check"]
     rows = int(chk.get("rows", 1))
     n_steps = int(chk["steps"])
-    w = weights_mod.make_weights(cfg, ctx.seed, "float32")
+    leaves = ctx.arch.shapes(cfg)
+    w = weights_mod.make_weights(leaves, ctx.seed, "float32")
     moments = {}                             # leaf -> (m, v) as numpy
 
     def total(w, xs, ys):                    # xs, ys: [blocks, rows, seq]
@@ -197,7 +165,7 @@ def reference_steps(ctx, host_batches, linear=None, keep_rows=None):
     upd = {}
     for n in w:
         leaf_change = jax.jit(lambda a, key, n=n: jnp.sqrt(jnp.sum(jnp.square(
-            a - weights_mod.build_flat(key, cfg, "float32", (n,))[n]))))
+            a - weights_mod.build_flat(key, leaves, "float32", (n,))[n]))))
         upd[n] = float(leaf_change(w[n], key))
     return {"losses": losses, "grad_norms": grad_norms, "update_norms": upd}
 
@@ -258,19 +226,23 @@ def run(ctx):
     inflight = collections.deque()
     steps = 0
     loss = None
+    done_at, longest_s = t_open, 0.0   # a stall shows as one long interval
     while time.monotonic() - t_open < seconds:
         loss = trainer.step(*bufs[(n_check + steps) % len(bufs)])
         steps += 1
         inflight.append(loss)
         if len(inflight) > 2:          # run two steps ahead of the device
             jax.block_until_ready(inflight.popleft())
+            now = time.monotonic()
+            done_at, longest_s = now, max(longest_s, now - done_at)
     last = float(np.asarray(jax.block_until_ready(loss)))
     t_close = time.monotonic()
     compiles_in_window = ctx.compiles.n - compiles0
     trace = tracer.stop() if tracer is not None else None
     mem_peak = harness.memory_peak_bytes()
     window_s = t_close - t_open
-    ctx.log(f"window: {steps} steps in {window_s:.3f} s, last loss {last}, "
+    ctx.log(f"window: {steps} steps in {window_s:.3f} s, the longest between "
+            f"two results {longest_s * 1e3:.0f} ms, last loss {last}, "
             f"compilations in the window {compiles_in_window}")
 
     # free the program's state before the reference takes the chip

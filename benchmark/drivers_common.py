@@ -1,23 +1,8 @@
-"""What the drivers share: the program's config object from a configuration
-file, and the profiler around a window."""
+"""What the drivers share: the profiler around a window."""
 from __future__ import annotations
 
 import os
 import shutil
-
-
-def llama_config(cfg, max_positions):
-    from paddle_tpu.models import LlamaConfig
-
-    return LlamaConfig(
-        vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
-        intermediate_size=cfg["intermediate_size"],
-        num_hidden_layers=cfg["num_hidden_layers"],
-        num_attention_heads=cfg["num_attention_heads"],
-        num_key_value_heads=cfg["num_key_value_heads"],
-        max_position_embeddings=max_positions,
-        rms_norm_eps=cfg["rms_norm_eps"], rope_theta=cfg["rope_theta"],
-        tie_word_embeddings=cfg.get("tie_word_embeddings", False))
 
 
 class Tracing:
@@ -59,5 +44,3 @@ class Tracing:
             trace_mod.dump_summary(path, os.environ["BENCH_KEEP_TRACE"])
         shutil.rmtree(self.ctx.trace_dir, ignore_errors=True)
         return tr
-
-

@@ -1,9 +1,11 @@
 """One run of one cell: find what the cell names, hand it to its driver,
 reduce what comes back to the result line.
 
-The harness knows no cell, configuration, mix or metric by name: the cell's
-configuration names its driver (``drivers/<driver>.py``) and its reference
-(``reference/<reference>.py``); each per-layer metric's file
+The harness knows no cell, configuration, mix, metric or model by name: the
+cell's configuration names its driver (``drivers/<driver>.py``), its
+architecture (``arch/<arch>.py``: the program's model, the weights' leaves,
+the work counts) and its reference (``reference/<reference>.py``); each
+per-layer metric's file
 (``metrics/<name>.json``) names its reader (``readers/<reader>.py``) and the
 reader's arguments.
 """
@@ -32,6 +34,7 @@ class RunContext:
         self.trace, self.control = bool(trace), bool(control)
         self.t0 = t0
         self.require_chip = require_chip
+        self.arch = spec.module("arch", self.cfg["arch"])
         self.reference = spec.module("reference", self.cfg["reference"])
         self.trace_dir = os.path.join(spec.root, ".bench_trace")
         self.compiles = CompileCounter()
@@ -106,7 +109,8 @@ def read_per_layer(ctx, run, device):
     out = {}
     facts = dict(run["facts"])
     # no chip, no peak: every share of one then finds nothing to read
-    facts.update(cfg=ctx.cfg, traffic=ctx.traffic, trace=run["trace"],
+    facts.update(cfg=ctx.cfg, arch=ctx.arch, traffic=ctx.traffic,
+                 trace=run["trace"],
                  peaks=peaks_mod.peaks(device["kind"]) if ctx.require_chip
                  else None, chips=ctx.cell["chips"])
     for m in ctx.spec.per_layer(ctx.cell["name"]):

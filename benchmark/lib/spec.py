@@ -1,10 +1,11 @@
 """Finds everything a cell names, by name, from BENCHMARK.json.
 
 A cell names a configuration and a traffic mix; the configuration names its
-driver and its plain reference; a per-layer metric names its reader. Each is
-a file of its own under one of the directories in ``paths``, so a later PR
-adds a cell, a configuration, a mix, a metric, a reader or a driver by adding
-files and entries and edits none.
+driver, its architecture and its plain reference; a per-layer metric names
+its reader. Each is a file of its own under one of the directories in
+``paths``, so a later PR adds a cell, a configuration, a mix, a metric, a
+reader, a driver or an architecture by adding files and entries and edits
+none.
 """
 from __future__ import annotations
 

@@ -1,14 +1,13 @@
-"""A tiny copy of the benchmark for CPU rehearsals: the same files, with the
-widths, the engine and the traffic cut to what a test run can hold."""
+"""A tiny copy of the benchmark for CPU rehearsals: the same files, with each
+configuration cut by its own architecture's ``tiny`` and the traffic cut to
+what a test run can hold."""
 import json
 import os
 import shutil
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from benchmark.lib import spec as spec_mod
 
-TINY_WIDTHS = dict(vocab_size=512, hidden_size=128, intermediate_size=256,
-                   num_attention_heads=8, num_key_value_heads=2, head_dim=16,
-                   max_position_embeddings=256, num_hidden_layers=2)
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def _edit(path, fn):
@@ -19,24 +18,24 @@ def _edit(path, fn):
         json.dump(doc, f)
 
 
-def make_root(dst):
-    """Copy BENCHMARK.json and benchmark/ to ``dst`` and cut them down."""
+def copy_root(dst, src=ROOT):
+    """Copy ``src``'s BENCHMARK.json and benchmark/ to ``dst`` as they are."""
     dst = str(dst)
-    shutil.copytree(os.path.join(ROOT, "benchmark"),
+    shutil.copytree(os.path.join(src, "benchmark"),
                     os.path.join(dst, "benchmark"),
                     ignore=shutil.ignore_patterns("__pycache__"))
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), dst)
-    cfgs = os.path.join(dst, "benchmark", "configs")
-    for name in os.listdir(cfgs):
+    shutil.copy(os.path.join(src, "BENCHMARK.json"), dst)
+    return dst
+
+
+def make_root(dst, src=ROOT):
+    """``copy_root``, then cut down."""
+    dst = copy_root(dst, src)
+    spec = spec_mod.Spec(dst)
+    for entry in spec.doc["configs"]:
         def cut(doc):
-            doc.update(TINY_WIDTHS)
-            if "engine" in doc:
-                doc["engine"] = {"block_size": 16, "max_slots": 4,
-                                 "max_model_len": 128}
-                # float32 here, so that the program sits far inside the
-                # limit that the int8 control has to break
-                doc["dtype"] = "float32"
-        _edit(os.path.join(cfgs, name), cut)
+            doc.update(spec.module("arch", doc["arch"]).tiny(doc))
+        _edit(os.path.join(dst, entry["file"]), cut)
     mixes = os.path.join(dst, "benchmark", "traffic")
 
     def closed(doc):

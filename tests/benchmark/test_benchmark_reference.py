@@ -1,42 +1,70 @@
-"""The plain reference against the program at a tiny width on the CPU, and
-its lower-precision control."""
+"""The plain reference against the program at a tiny width on the CPU, its
+lower-precision control, and the weights both are given."""
+import json
+import os
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+from benchmark.arch import llama
 from benchmark.lib import weights
 from benchmark.reference import mistral
 
 import _tiny
 
-CFG = dict(_tiny.TINY_WIDTHS, rope_theta=1e6, rms_norm_eps=1e-5)
+CFG = dict(llama.tiny({}), rope_theta=1e6, rms_norm_eps=1e-5)
+SHAPES = llama.shapes(CFG)
+
+with open(os.path.join(os.path.dirname(__file__),
+                       "recorded_weights_tiny.json")) as f:
+    RECORDED = json.load(f)
 
 
 @pytest.fixture(scope="module")
 def w():
-    return weights.make_weights(CFG, 2**31 + 7, "float32")
+    return weights.make_weights(SHAPES, 2**31 + 7, "float32")
 
 
 def test_weights_depend_on_the_seed_alone(w):
-    again = weights.make_weights(CFG, 2**31 + 7, "float32")
-    other = weights.make_weights(CFG, 2**31 + 8, "float32")
-    one = weights.make_weights(CFG, 2**31 + 7, "float32", ["norm.weight"])
+    again = weights.make_weights(SHAPES, 2**31 + 7, "float32")
+    other = weights.make_weights(SHAPES, 2**31 + 8, "float32")
+    one = weights.make_weights(SHAPES, 2**31 + 7, "float32", ["norm.weight"])
     assert all((w[n] == again[n]).all() for n in w)
     assert all((w[n] != other[n]).any() for n in w)
     assert (one["norm.weight"] == w["norm.weight"]).all()
-    assert set(w) == set(weights.shapes(CFG))
+    assert set(w) == set(SHAPES)
     assert abs(float(jnp.std(w["lm_head.weight"])) - 0.02) < 1e-3
     assert abs(float(jnp.mean(w["norm.weight"])) - 1.0) < 0.05
 
 
+@pytest.mark.parametrize("name", sorted(RECORDED["leaves"]))
+def test_weights_are_what_they_were_before_an_architecture_gave_the_leaves(name):
+    """Each leaf of both configurations (tiny widths, their own depth and
+    dtype) against sums recorded from the parent of PR 27: the same leaves
+    in the same order, the same shapes, the same values."""
+    with open(os.path.join(_tiny.ROOT, "benchmark", "configs",
+                           name + ".json")) as f:
+        cfg = json.load(f)
+    own = {k: cfg[k] for k in ("num_hidden_layers", "dtype")}
+    cfg.update(llama.tiny({}), **own)
+    shapes = llama.shapes(cfg)
+    w = weights.make_weights(shapes, RECORDED["seed"], cfg["dtype"])
+    want = RECORDED["leaves"][name]
+    assert list(shapes) == [leaf[0] for leaf in want]
+    for leaf, shape, dtype, total, squares in want:
+        a = np.asarray(w[leaf].astype(jnp.float32)).astype(np.float64)
+        assert (list(a.shape), str(w[leaf].dtype)) == (shape, dtype), leaf
+        assert a.sum() == pytest.approx(total, rel=1e-9, abs=1e-9), leaf
+        assert (a * a).sum() == pytest.approx(squares, rel=1e-9), leaf
+
+
 def test_reference_agrees_with_llama_for_causal_lm(w):
     import paddle_tpu
-    from benchmark.drivers_common import llama_config
-    from paddle_tpu.models import LlamaForCausalLM
 
-    model = LlamaForCausalLM(llama_config(CFG, 64))
+    model = llama.build_model(CFG, 64)
     for n, p in model.named_parameters():
         p._value = w[n]
     tok = np.random.RandomState(0).randint(0, CFG["vocab_size"], (2, 48))

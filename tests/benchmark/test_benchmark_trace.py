@@ -80,21 +80,22 @@ def test_breakdown_names_program_and_kernel(hand):
 
 
 def test_roofline_and_mfu_from_the_benchmarks_own_work(hand):
-    from benchmark.lib import peaks, work
+    from benchmark.arch import llama
+    from benchmark.lib import peaks
     from benchmark.readers import mfu, roofline
 
     with open(os.path.join(os.path.dirname(__file__), "..", "..", "benchmark",
                            "configs", "mistral-7b-v0.3-serve-l8.json")) as f:
         cfg = json.load(f)
     peak = peaks.peaks("TPU v5 lite")
-    facts = {"trace": hand, "cfg": cfg, "peaks": peak, "chips": 1,
+    facts = {"trace": hand, "cfg": cfg, "arch": llama, "peaks": peak, "chips": 1,
              "decode_contexts": [300] * 64, "prefill_lens": [200],
              "window_s": 2.0}
-    need = work.paged_attention_decode(cfg, [300] * 64)
+    need = llama.paged_attention_decode(cfg, [300] * 64)
     want = 100 * (need["bytes"] / 819e9) / 1200e-9
     assert roofline.read(facts, "paged_attention", "paged_attention_decode") \
         == pytest.approx(want)
-    flops = work.prefill_flops(cfg, 200) + 64 * work.decode_flops(cfg, 300)
+    flops = llama.prefill_flops(cfg, 200) + 64 * llama.decode_flops(cfg, 300)
     assert mfu.read(facts, "serve") == pytest.approx(
         100 * flops / 2.0 / 197e12)
     # nothing to read is nothing reported, never 0
