@@ -8,6 +8,10 @@ global_*). TPU-native: GShard-style einsum dispatch/combine over a
 mesh axis — GSPMD lowers the dispatch einsums to the all-to-all the reference
 hand-writes. Gates: naive(top-1)/switch(top-1 + load-balance loss)/
 gshard(top-2 + aux loss).
+
+:class:`SparseMoELayer` is the no-drop form that serving uses: top-k of all
+experts per token, no capacity, rows sorted by expert and multiplied by the
+experts this chip holds through ``kernels.moe_grouped_matmul``.
 """
 from __future__ import annotations
 
@@ -22,7 +26,7 @@ from ..core.dispatch import apply
 from ..nn import initializer as I
 from .mp_layers import mark_sharding
 
-__all__ = ["MoELayer", "top2_gating", "top1_gating"]
+__all__ = ["MoELayer", "SparseMoELayer", "top2_gating", "top1_gating"]
 
 
 def _one_hot(x, n):
@@ -138,3 +142,96 @@ class MoELayer(nn.Layer):
         out = mark_sharding(out, *([None] * out.ndim))
         self.aux_loss = aux
         return out
+
+
+class SparseMoELayer(nn.Layer):
+    """Sparse experts without dropped rows: every token goes to its
+    ``top_k`` experts, whatever their load.
+
+    The router scores all ``num_experts`` (sigmoid), keeps the ``top_k``
+    largest, and weighs expert ``e``'s output by ``routed_scaling * s_e /
+    sum(kept s)``. Experts are gated MLPs without bias,
+    ``down(silu(gate(x)) * up(x))``, stored stacked: ``gate_up_proj
+    [held, d_model, 2 * d_expert]`` and ``down_proj [held, d_expert,
+    d_model]``.
+
+    ``experts_held = (first, count)`` says which experts live here (all by
+    default). The layer routes over all of them and computes the part of the
+    result that its own experts give: the (token, expert) rows of the held
+    experts are sorted by expert, multiplied group by group
+    (``kernels.moe_grouped_matmul``: no weight of an expert without rows is
+    read) and added back into their tokens by weight; rows of experts held
+    elsewhere add nothing. With every expert held that is the whole layer.
+    What the shares of several chips add up to is the sum of their results;
+    the exchange that would form it is not this layer's.
+
+    ``shared_expert`` is an optional layer ``[N, d_model] -> [N, d_model]``
+    that every token passes through; its output is added once, so of the
+    shares of one layer exactly one is given it.
+
+    ``forward(x, row_mask=None)`` returns ``(y, load)``: ``load`` is int32
+    ``[count]``, the rows each held expert received (of the rows
+    ``row_mask`` keeps, where given: a serving step's padding rows are
+    computed like any other but are nobody's load).
+    """
+
+    def __init__(self, d_model, d_expert, num_experts, top_k, *,
+                 experts_held=None, shared_expert=None, routed_scaling=1.0):
+        super().__init__()
+        first, count = experts_held or (0, num_experts)
+        if not 0 <= first <= first + count <= num_experts:
+            raise ValueError(f"experts_held {(first, count)} is not a range "
+                             f"of {num_experts} experts")
+        self.num_experts, self.top_k = int(num_experts), int(top_k)
+        self.experts_held = (int(first), int(count))
+        self.routed_scaling = float(routed_scaling)
+        self.router = nn.Linear(d_model, num_experts, bias_attr=False)
+        self.gate_up_proj = self.create_parameter(
+            [count, d_model, 2 * d_expert],
+            default_initializer=I.XavierUniform())
+        self.down_proj = self.create_parameter(
+            [count, d_expert, d_model], default_initializer=I.XavierUniform())
+        if shared_expert is not None:
+            self.shared_expert = shared_expert
+
+    def forward(self, x, row_mask=None):
+        from ..kernels.moe_grouped_matmul import moe_grouped_matmul
+
+        k, scaling = self.top_k, self.routed_scaling
+        first, count = self.experts_held
+
+        def body(xv, wr, wgu, wd, mask=None):
+            d = xv.shape[-1]
+            xf = xv.reshape(-1, d)
+            n = xf.shape[0]
+            s = jax.nn.sigmoid(jnp.dot(xf, wr,
+                                       preferred_element_type=jnp.float32))
+            top_s, top_e = jax.lax.top_k(s, k)                   # [n, k]
+            w = scaling * top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+            # experts held elsewhere sort last and belong to no group
+            local = top_e.astype(jnp.int32) - first
+            local = jnp.where((local >= 0) & (local < count), local, count)
+            flat = local.reshape(-1)
+            order = jnp.argsort(flat, stable=True)
+            sizes = jnp.bincount(flat, length=count + 1)[:count]
+            rows = xf[order // k]                                # [n k, d]
+            gate, up = jnp.split(
+                moe_grouped_matmul(rows, wgu, sizes), 2, axis=-1)
+            y = moe_grouped_matmul(jax.nn.silu(gate) * up, wd, sizes)
+            y = y[jnp.argsort(order)].reshape(n, k, d)           # by token
+            out = jnp.sum(w[..., None] * y.astype(jnp.float32), axis=1)
+            if mask is None:
+                load = sizes
+            else:
+                kept = jnp.where(mask.reshape(-1, 1), local, count)
+                load = jnp.bincount(kept.reshape(-1), length=count + 1)[:count]
+            return (out.astype(xv.dtype).reshape(xv.shape),
+                    load.astype(jnp.int32))
+
+        args = (x, self.router.weight, self.gate_up_proj, self.down_proj)
+        if row_mask is not None:
+            args += (row_mask,)
+        out, load = apply(body, *args, op_name="sparse_moe")
+        if hasattr(self, "shared_expert"):
+            out = out + self.shared_expert(x)
+        return out, load

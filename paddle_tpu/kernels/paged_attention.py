@@ -54,7 +54,7 @@ NEG_INF = -1e30
 # ---------------------------------------------------------------------------
 
 def paged_attention_ref(q, kv_pool, block_tables, context_lens, *,
-                        sm_scale=None):
+                        sm_scale=None, window=None):
     """Pure-jnp ragged paged attention.
 
     q:            [slots, num_q_heads, head_dim] — one query token per slot
@@ -62,6 +62,9 @@ def paged_attention_ref(q, kv_pool, block_tables, context_lens, *,
     block_tables: int32 [slots, max_blocks] pool indices per slot
     context_lens: int32 [slots] valid tokens per slot (including the token
                   whose K/V was just written); positions >= ctx are masked
+    window:       None, or the static number of latest positions a query
+                  sees (itself included): positions < ctx - window are
+                  masked too
     returns       [slots, num_q_heads, head_dim]
     """
     S, Hq, D = q.shape
@@ -78,7 +81,10 @@ def paged_attention_ref(q, kv_pool, block_tables, context_lens, *,
     qg = (q.astype(jnp.float32) * scale).reshape(S, Hkv, rep, D)
     logits = jnp.einsum("shrd,shtd->shrt", qg, k.astype(jnp.float32))
     pos = jnp.arange(M * bs, dtype=jnp.int32)
-    valid = pos[None, :] < context_lens[:, None].astype(jnp.int32)
+    ctx = context_lens[:, None].astype(jnp.int32)
+    valid = pos[None, :] < ctx
+    if window is not None:
+        valid &= pos[None, :] >= ctx - window
     logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("shrt,shtd->shrd", probs, v.astype(jnp.float32))
@@ -109,7 +115,7 @@ def _pages_per_step(block_size, kv_heads, head_dim, itemsize, max_blocks):
 
 
 def _paged_kernel(bt_ref, ctx_ref, q_ref, pool_hbm, o_ref,
-                  kv_buf, sems, buf_ref, *, sm_scale):
+                  kv_buf, sems, buf_ref, *, sm_scale, window):
     """Grid (slots,); scalar-prefetch refs first.
 
     bt_ref [S, M], ctx_ref [S]: SMEM. q_ref/o_ref: [1, Hkv, rep, D], this
@@ -122,6 +128,12 @@ def _paged_kernel(bt_ref, ctx_ref, q_ref, pool_hbm, o_ref,
     ``step < cdiv(pages(slot), P)``; each pair waits for its own pages and
     has already started the next pair's copies, across slot boundaries too,
     so only the very first copy of a call is exposed.
+
+    With a ``window`` a slot's walk starts at the page that holds position
+    ``ctx - window`` (``first_page``), in the compute step that page falls
+    in; that step's older pages are not copied and the first page's older
+    positions are masked. ``window`` is static: without one, none of this
+    is traced.
     """
     s = pl.program_id(0)
     num_slots = pl.num_programs(0)
@@ -134,6 +146,12 @@ def _paged_kernel(bt_ref, ctx_ref, q_ref, pool_hbm, o_ref,
         # a slot always owns page 0 (ctx >= 1 by contract; ctx 0 would read
         # page 0 fully masked); never past the table
         return jnp.clip(pl.cdiv(ctx_ref[slot], bs), 1, max_blocks)
+
+    def first_page(slot):
+        return jnp.maximum(ctx_ref[slot] - window, 0) // bs
+
+    def first_step(slot):
+        return 0 if window is None else first_page(slot) // P
 
     def page_copy(slot, page, buf):
         """The copy of table entry ``page`` of ``slot`` into its place in
@@ -149,7 +167,9 @@ def _paged_kernel(bt_ref, ctx_ref, q_ref, pool_hbm, o_ref,
         """``do(page)`` for each live page of compute step ``step``."""
         first = step * P
         jax.lax.fori_loop(
-            first, jnp.minimum(first + P, live_pages(slot)),
+            first if window is None
+            else jnp.maximum(first, first_page(slot)),
+            jnp.minimum(first + P, live_pages(slot)),
             lambda page, _: do(page), None)
 
     def start(slot, step, buf):
@@ -163,22 +183,30 @@ def _paged_kernel(bt_ref, ctx_ref, q_ref, pool_hbm, o_ref,
     @pl.when(s == 0)
     def _first():
         buf_ref[0] = 0
-        start(0, 0, 0)
+        start(0, first_step(0), 0)
 
     ctx = ctx_ref[s]
     n_pages = live_pages(s)
+    step0 = first_step(s)
     n_steps = pl.cdiv(n_pages, P)
+    if window is not None:
+        n_steps -= step0
     buf0 = buf_ref[0]
     # operands in the pool's dtype (bf16 stays bf16 into the MXU); the scale
     # is applied in f32 first, the statistics below stay f32
     q = (q_ref[0].astype(jnp.float32) * sm_scale).astype(kv_buf.dtype)
 
-    def step_body(i, carry):
+    def step_body(n, carry):
         m_prev, l_prev, acc = carry
-        buf = (buf0 + i) % 2
-        last = i + 1 == n_steps
+        # the step's place in the table
+        i = n if window is None else step0 + n
+        buf = (buf0 + n) % 2
+        last = n + 1 == n_steps
         nxt_slot = jnp.where(last, s + 1, s)
-        nxt_step = jnp.where(last, 0, i + 1)
+        # (past the last slot nothing is started: any slot's answer does)
+        nxt_step = jnp.where(
+            last, 0 if window is None
+            else first_step(jnp.minimum(nxt_slot, num_slots - 1)), i + 1)
 
         @pl.when(nxt_slot < num_slots)
         def _prefetch():
@@ -192,6 +220,10 @@ def _paged_kernel(bt_ref, ctx_ref, q_ref, pool_hbm, o_ref,
             kv_buf[buf, 1, :, p] = jnp.zeros((Hkv, bs, D), kv_buf.dtype)
 
         jax.lax.fori_loop(jnp.minimum(n_pages - i * P, P), P, zero_v, None)
+        if window is not None:
+            # nor were the first step's pages before the window's first
+            jax.lax.fori_loop(0, jnp.clip(first_page(s) - i * P, 0, P),
+                              zero_v, None)
 
         k = kv_buf[buf, 0].reshape(Hkv, T, D)
         v = kv_buf[buf, 1].reshape(Hkv, T, D)
@@ -202,7 +234,10 @@ def _paged_kernel(bt_ref, ctx_ref, q_ref, pool_hbm, o_ref,
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.DEFAULT)             # [Hkv, rep, T]
         pos = i * T + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 2)
-        sc = jnp.where(pos < ctx, sc, NEG_INF)
+        seen = pos < ctx
+        if window is not None:
+            seen &= pos >= ctx - window
+        sc = jnp.where(seen, sc, NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=2, keepdims=True))
         p_blk = jnp.exp(sc - m_new)
         alpha = jnp.exp(m_prev - m_new)
@@ -221,9 +256,10 @@ def _paged_kernel(bt_ref, ctx_ref, q_ref, pool_hbm, o_ref,
     o_ref[0] = (acc / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "window", "interpret"))
 def _paged_call(q4, kv_pool, block_tables, context_lens, *, sm_scale,
-                interpret):
+                window, interpret):
     """The ``pallas_call`` on ``q4 [S, Hkv, rep, D]``. Jitted so that a step
     that calls it once a layer traces and lowers the kernel once: the
     layers' calls have the same shapes and share the one traced function
@@ -253,7 +289,8 @@ def _paged_call(q4, kv_pool, block_tables, context_lens, *, sm_scale,
     )
     with x64_off():
         return pl.pallas_call(
-            functools.partial(_paged_kernel, sm_scale=sm_scale),
+            functools.partial(_paged_kernel, sm_scale=sm_scale,
+                              window=window),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
             # slots run in order: the landing buffers and the buffer index
@@ -267,7 +304,7 @@ def _paged_call(q4, kv_pool, block_tables, context_lens, *, sm_scale,
 
 
 def paged_attention_pallas(q, kv_pool, block_tables, context_lens, *,
-                           sm_scale=None, interpret=None):
+                           sm_scale=None, window=None, interpret=None):
     """Pallas ragged paged attention; see :func:`paged_attention_ref` for
     the argument contract. ``interpret`` defaults to the platform policy."""
     S, Hq, D = q.shape
@@ -289,15 +326,18 @@ def paged_attention_pallas(q, kv_pool, block_tables, context_lens, *,
     # the last two dims, which Mosaic tiles for any rep (a block of rep rows
     # of [S, Hq, D] is neither a multiple of 8 rows nor the full dim)
     out = _paged_call(q.reshape(S, Hkv, Hq // Hkv, D), kv_pool, block_tables,
-                      context_lens, sm_scale=scale, interpret=interpret)
+                      context_lens, sm_scale=scale, window=window,
+                      interpret=interpret)
     return out.reshape(S, Hq, D)
 
 
-def paged_attention(q, kv_pool, block_tables, context_lens, *, sm_scale=None):
+def paged_attention(q, kv_pool, block_tables, context_lens, *, sm_scale=None,
+                    window=None):
     """Policy entry: Pallas on TPU, jnp mirror elsewhere (the jnp path is
     also what runs inside the check_vma interpreter, where interpret-mode
     pallas cannot trace — same policy as kernels/flash_attention.py)."""
     from . import paged_attention_impl
 
     impl = paged_attention_impl()
-    return impl(q, kv_pool, block_tables, context_lens, sm_scale=sm_scale)
+    return impl(q, kv_pool, block_tables, context_lens, sm_scale=sm_scale,
+                window=window)

@@ -29,6 +29,7 @@ from ..distributed.mp_layers import (
     mark_sharding,
 )
 from ..nn import functional as F
+from ..nn.functional.attention import CacheLayer
 from ..ops import manipulation as M
 
 __all__ = ["LlamaConfig", "LlamaForCausalLM", "LlamaDecoderLayer",
@@ -225,6 +226,13 @@ class LlamaForCausalLM(nn.Layer):
                       positions=positions)
         h = self.norm(h)
         return self.lm_head(h)
+
+    def cache_layers(self):
+        """What each attention layer keeps in a KV cache (the serving
+        engine sizes its pool from this): every layer alike, no window."""
+        c = self.config
+        return [CacheLayer(c.num_key_value_heads, c.head_dim)
+                ] * c.num_hidden_layers
 
     def num_params(self):
         return sum(p.size for p in self.parameters())

@@ -10,6 +10,7 @@ point for long sequences (selected by ``paddle_tpu.kernels.use_pallas``).
 from __future__ import annotations
 
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +18,31 @@ import jax.numpy as jnp
 from ...core.dispatch import apply
 
 __all__ = ["scaled_dot_product_attention", "flash_attention",
-           "flash_attn_unpadded", "sdpa_ref"]
+           "flash_attn_unpadded", "sdpa_ref", "CacheLayer",
+           "causal_window_mask"]
+
+
+class CacheLayer(NamedTuple):
+    """What one attention layer keeps in a KV cache: a model's
+    ``cache_layers()`` gives one a layer, and whoever holds the cache (the
+    serving engine) sizes it and tells it each layer's window from them,
+    whatever the model."""
+
+    kv_heads: int
+    head_dim: int
+    window: int | None = None     # latest positions a query sees, or all
+
+
+def causal_window_mask(sq, sk, window=None):
+    """bool [sq, sk]: the last ``sq`` of ``sk`` positions as queries over all
+    ``sk`` as keys; query ``s`` sees key ``t`` iff ``s - window < t <= s``
+    (``t <= s`` without a window)."""
+    qi = jnp.arange(sk - sq, sk, dtype=jnp.int32)[:, None]
+    kj = jnp.arange(sk, dtype=jnp.int32)[None, :]
+    mask = kj <= qi
+    if window is not None:
+        mask &= kj > qi - window
+    return mask
 
 
 def sdpa_ref(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=False,

@@ -1,7 +1,10 @@
 """Continuous-batching LLM serving engine.
 
-``LLMEngine`` drives a ``models.llama.LlamaForCausalLM`` through two jitted
-step functions over the paged KV cache:
+``LLMEngine`` drives a cache-aware causal LM (``models.llama``,
+``models.laguna``) through two jitted step functions over the paged KV
+cache. What the model keeps there it says itself (``model.cache_layers()``:
+KV heads, head size and window of each layer); the engine reads no other
+field of the model's shape:
 
 - **prefill** (per admitted request, batch 1): the prompt — padded to a
   power-of-two number of KV blocks so trace count stays logarithmic — runs
@@ -59,6 +62,7 @@ from __future__ import annotations
 
 import itertools
 import time
+from collections import deque
 from types import SimpleNamespace
 
 import jax
@@ -169,8 +173,10 @@ def _engine_metrics(label: str) -> SimpleNamespace:
 class LLMEngine:
     """Continuous-batching serving engine over a paged KV cache.
 
-    model:         a ``LlamaForCausalLM`` (any cache-aware causal LM whose
-                   forward accepts ``cache=`` / ``positions=`` works)
+    model:         a cache-aware causal LM: ``forward(ids, cache=,
+                   positions=)`` and ``cache_layers()``, one
+                   ``CacheLayer`` an attention layer (all layers
+                   share one pool, so one KV width; windows may differ)
     block_size:    tokens per KV block (pool granularity)
     num_blocks:    pool size incl. the reserved scratch block; default sizes
                    the pool so every slot can reach ``max_model_len``
@@ -245,9 +251,18 @@ class LLMEngine:
         if kv_dtype is None:
             kv_dtype = next(iter(self.params.values())).dtype
         self.prefix_cache = bool(prefix_cache)
+        layers = tuple(model.cache_layers())
+        widths = {(l.kv_heads, l.head_dim) for l in layers}
+        if len(widths) != 1:
+            raise ValueError(
+                f"one pool holds every layer's K/V, so the layers must "
+                f"agree on (kv_heads, head_dim); the model has {widths}")
+        (kv_heads, head_dim), = widths
+        # static per layer: None, or the latest positions a query sees
+        self._windows = tuple(l.window for l in layers)
         self.cache = PagedKVCache(
-            cfg.num_hidden_layers, num_blocks, cfg.num_key_value_heads,
-            self.block_size, cfg.head_dim, dtype=kv_dtype,
+            len(layers), num_blocks, kv_heads,
+            self.block_size, head_dim, dtype=kv_dtype,
             prefix_cache=self.prefix_cache,
             spill_blocks=kv_spill_blocks if self.prefix_cache else None)
         self.engine_label = str(next(_ENGINE_IDS))
@@ -276,6 +291,9 @@ class LLMEngine:
             tenancy=self.tenancy)
 
         self._next_rid = 0
+        self._counter_names: tuple = ()    # set when a step is traced
+        # the model's counters of the latest prefills (stats()["perf"])
+        self._prefill_counters: dict[str, deque] = {}
         self._decode_fn = None
         self._prefill_fns: dict[int, object] = {}
         self._py_fns: dict = {}            # trace key -> python callable
@@ -291,10 +309,10 @@ class LLMEngine:
         # The fingerprint keys the process-global cost registry so
         # identical engines (fleet replicas, tests) share one estimate.
         self._cost_fp = (
-            cfg.vocab_size, cfg.hidden_size, cfg.intermediate_size,
-            cfg.num_hidden_layers, cfg.num_attention_heads,
-            cfg.num_key_value_heads, self.block_size, self.max_slots,
-            self.max_blocks, str(kv_dtype))
+            type(model).__name__,
+            tuple(sorted((n, tuple(v.shape)) for n, v in self.params.items())),
+            layers, self.block_size, self.max_slots, self.max_blocks,
+            str(kv_dtype))
         self._suspend_trace_counts = False  # cost tracing must not count
         self._trace_costs: dict[tuple, dict] = {}   # (kind, bucket) -> est
         self._roofline_fracs: dict[str, list] = {"prefill": [], "decode": []}
@@ -592,7 +610,7 @@ class LLMEngine:
     def _perf_block(self) -> dict:
         storms = [s for s in self._watcher.storms()
                   if s["callable"].startswith(("engine.", "pallas."))]
-        return {
+        block = {
             "compiles": self._watcher.summary(prefix="engine."),
             "storms": storms,
             "explain_recompile": (
@@ -602,6 +620,13 @@ class LLMEngine:
             "memory": self._mm.snapshot(),
             "roofline": self._roofline_block(),
         }
+        if self._prefill_counters:
+            # the model's own counters over the latest prefills (the decode
+            # steps' are in the StepTimeline's report)
+            block["prefill"] = telemetry.perf.nest_dotted(
+                {n: {"mean": sum(v) / len(v)}
+                 for n, v in self._prefill_counters.items()})
+        return block
 
     # ------------------------------------------------------------------
     # roofline cost model (telemetry.cost)
@@ -878,6 +903,49 @@ class LLMEngine:
             telemetry.dump(reason="engine stall detector", error=req.error)
 
     # ------------------------------------------------------------------
+    # the model's own counters of a step
+    # ------------------------------------------------------------------
+    def _pack_counters(self, view):
+        """Trace side: what the model counted about itself in this step
+        (``PagedCacheView.count``: per-layer sums under dotted names, with
+        ``<group>.layers`` the layers that added to a group) as one float32
+        vector beside the step's tokens, or None, which adds nothing to the
+        program, for a model that counts nothing."""
+        names = tuple(sorted(view.counters))
+        if not names:
+            return None
+        # lint: allow-tracer-leak(names are static strings, set once a trace)
+        self._counter_names = names
+        return jnp.stack([jnp.asarray(view.counters[n], jnp.float32)
+                          for n in names])
+
+    def _read_counters(self, packed) -> dict:
+        """Host side, once the step's result is here: each counter's mean
+        over the layers that added to its group (none: empty)."""
+        if packed is None:
+            return {}
+        vals = dict(zip(self._counter_names, np.asarray(packed).tolist()))
+        out = {}
+        for name, v in vals.items():
+            group, _, leaf = name.rpartition(".")
+            if leaf != "layers":
+                out[name] = v / (vals.get(group + ".layers") or 1.0)
+        return out
+
+    def _window_block_share(self, ctx_lens) -> float | None:
+        """Of the live block-table entries of the layers that have a window,
+        the share those layers' attention walks this step (from the host's
+        context lengths); None for a model without windows."""
+        windows = [w for w in self._windows if w is not None]
+        if not windows:
+            return None
+        live = -(-ctx_lens // self.block_size)
+        walked = sum(
+            int((live - np.maximum(ctx_lens - w, 0) // self.block_size).sum())
+            for w in windows)
+        return walked / (len(windows) * float(live.sum()))
+
+    # ------------------------------------------------------------------
     # prefill
     # ------------------------------------------------------------------
     def _bucket(self, length: int) -> int:
@@ -910,7 +978,8 @@ class LLMEngine:
                     temp, top_k, top_p, seed, step_idx):
             if not self._suspend_trace_counts:   # cost walks retrace too
                 self.prefill_traces[P] = self.prefill_traces.get(P, 0) + 1
-            view = PagedCacheView(pool, bt[None, :], None, self.block_size)
+            view = PagedCacheView(pool, bt[None, :], None, self.block_size,
+                                  windows=self._windows, valid_len=length)
             positions = jnp.arange(P, dtype=jnp.int32)[None]
             logits, _ = functional_call(
                 model, params, buffers, tokens[None], cache=view,
@@ -918,7 +987,7 @@ class LLMEngine:
             last = logits[0, length - 1].astype(jnp.float32)
             key = jax.random.fold_in(jax.random.PRNGKey(seed), step_idx)
             tok = sample_logits(last, temp, top_k, top_p, key)
-            return tok, view.pool
+            return tok, view.pool, self._pack_counters(view)
 
         fn = jax.jit(prefill, donate_argnums=self._donate)
         self._prefill_fns[P] = fn
@@ -944,7 +1013,8 @@ class LLMEngine:
                 self.prefill_traces[key] = self.prefill_traces.get(key, 0) + 1
             view = PagedCacheView(
                 pool, bt[None, :], None, self.block_size,
-                prefix_block_tables=pbt[None, :], prefix_len=prefix_len)
+                prefix_block_tables=pbt[None, :], prefix_len=prefix_len,
+                windows=self._windows, valid_len=length)
             positions = (prefix_len
                          + jnp.arange(P, dtype=jnp.int32))[None]
             logits, _ = functional_call(
@@ -953,7 +1023,7 @@ class LLMEngine:
             last = logits[0, length - 1].astype(jnp.float32)
             k = jax.random.fold_in(jax.random.PRNGKey(seed), step_idx)
             tok = sample_logits(last, temp, top_k, top_p, k)
-            return tok, view.pool
+            return tok, view.pool, self._pack_counters(view)
 
         fn = jax.jit(tail_prefill, donate_argnums=self._donate)
         self._prefill_fns[key] = fn
@@ -988,12 +1058,12 @@ class LLMEngine:
                 jnp.int32(len(req.output_tokens)))
             cost_est = (self._trace_cost("prefill", f"P{P}", P, call_args)
                         if new_trace else None)
-            tok, self.cache.pool = fn(*call_args)
+            tok, self.cache.pool, counters = fn(*call_args)
         self._finish_prefill(
             slot, req, toks, tok, t0, f"P{P}",
             (("tokens", (P,), "int32"),
              ("block_table", (P // self.block_size,), "int32")),
-            new_trace, cost_est)
+            new_trace, cost_est, counters)
 
     def _run_tail_prefill(self, slot: int, req: Request, toks, cached: int):
         """Prefill only the tokens past the matched prefix: the cached
@@ -1035,17 +1105,17 @@ class LLMEngine:
             cost_est = (self._trace_cost("prefill", bucket, (P, NPB),
                                          call_args)
                         if new_trace else None)
-            tok, self.cache.pool = fn(*call_args)
+            tok, self.cache.pool, counters = fn(*call_args)
         self._finish_prefill(
             slot, req, toks, tok, t0, bucket,
             (("tokens", (P,), "int32"),
              ("block_table", (P // bs,), "int32"),
              ("prefix_table", (NPB,), "int32")),
-            new_trace, cost_est)
+            new_trace, cost_est, counters)
 
     def _finish_prefill(self, slot: int, req: Request, toks, tok,
                         t0: float, bucket: str, signature, new_trace: bool,
-                        cost_est):
+                        cost_est, counters):
         """What both prefills do once the step is dispatched: book what
         needs no result while the device runs, wait for the first token,
         hand it on, and book the step's time. ``wall`` ends at the result
@@ -1055,6 +1125,7 @@ class LLMEngine:
             self._charge_tenant(req.tenant, "prefill", bucket)
         with telemetry.span("engine.prefill_wait"):
             tok = int(tok)
+            counters = self._read_counters(counters)
         wall = time.monotonic() - t0
         with telemetry.span("engine.emit"):
             self._emit(slot, req, tok)
@@ -1064,6 +1135,9 @@ class LLMEngine:
                 wall_s=wall if new_trace else None, cost=cost_est)
             if not new_trace:
                 self._note_roofline("prefill", bucket, wall)
+            for name, v in counters.items():
+                self._prefill_counters.setdefault(
+                    name, deque(maxlen=128)).append(v)
 
     # ------------------------------------------------------------------
     # decode
@@ -1079,7 +1153,8 @@ class LLMEngine:
             if not self._suspend_trace_counts:
                 # lint: allow-tracer-leak(trace-time compile counter, runs once per trace)
                 self.decode_traces += 1
-            view = PagedCacheView(pool, bt, ctx, self.block_size)
+            view = PagedCacheView(pool, bt, ctx, self.block_size,
+                                  windows=self._windows)
             logits, _ = functional_call(
                 model, params, buffers, tokens[:, None], cache=view,
                 positions=ctx[:, None], training=False)
@@ -1088,7 +1163,7 @@ class LLMEngine:
                 lambda s, t: jax.random.fold_in(jax.random.PRNGKey(s), t)
             )(seeds, step_idx)
             toks = sample_logits(last, temps, top_ks, top_ps, keys)
-            return toks, view.pool
+            return toks, view.pool, self._pack_counters(view)
 
         self._decode_fn = jax.jit(decode, donate_argnums=self._donate)
         self._py_fns["decode"] = decode
@@ -1149,6 +1224,7 @@ class LLMEngine:
         new_trace = self._decode_fn is None
         cost_est = None
         live_share = None
+        counters = None
         done = False
         try:
             try:
@@ -1169,7 +1245,7 @@ class LLMEngine:
                 with telemetry.span("engine.decode", batch=len(running),
                                     engine=self.engine_label,
                                     **({"trace_ids": tids} if tids else {})):
-                    toks, self.cache.pool = fn(*call_args)
+                    toks, self.cache.pool, counters = fn(*call_args)
                 marks.append(time.monotonic())
             except Exception as e:
                 # the fused step died: every request in the batch fails,
@@ -1187,9 +1263,11 @@ class LLMEngine:
                 # how much of the running slots' block tables the paged
                 # kernel walks this step (its context is ctx + 1: the
                 # token being written counts)
-                live = -(-(host[2][list(running)] + 1) // self.block_size)
+                ctx_lens = host[2][list(running)] + 1
+                live = -(-ctx_lens // self.block_size)
                 live_share = float(live.sum()) / (
                     len(running) * self.max_blocks)
+                window_share = self._window_block_share(ctx_lens)
                 if self.prefix_cache:
                     # a decode write that just filled its block completes
                     # another full token-block: index it so later
@@ -1201,6 +1279,11 @@ class LLMEngine:
                                                      req.prefill_tokens)
             with telemetry.span("engine.decode_wait"):
                 toks = np.asarray(toks)
+                # the model's counters came with the tokens; read before
+                # that, they would make the overlap above wait for the step
+                counters = self._read_counters(counters)
+                if window_share is not None:
+                    counters["window_block_share"] = window_share
             done = True
         finally:
             # the step's clocks end at the result: at dispatch the device
@@ -1218,24 +1301,25 @@ class LLMEngine:
                     limit_s=self.watchdog_timeout_s)
             if not done:
                 self._account_decode(marks, len(running), live_share,
-                                     new_trace, cost_est, done=False)
+                                     new_trace, cost_est, None, done=False)
         with telemetry.span("engine.emit"):
             for slot, req in running.items():
                 self._emit(slot, req, int(toks[slot]))
         marks.append(time.monotonic())
-        return marks, len(running), live_share, new_trace, cost_est
+        return marks, len(running), live_share, new_trace, cost_est, counters
 
     def _account_decode(self, marks, n_running, live_share, new_trace,
-                        cost_est, done=True):
+                        cost_est, counters, done=True):
         """Book one decode step's time once it is known: its phases,
-        occupancy and live share of the block tables into the StepTimeline,
-        the call into the compile watcher and, for a step that ran to its
-        end, its roofline fraction."""
+        occupancy, live share of the block tables and the model's own
+        counters into the StepTimeline, the call into the compile watcher
+        and, for a step that ran to its end, its roofline fraction."""
         phases = {ph: t1 - t0 for ph, t0, t1 in
                   zip(self._DECODE_PHASES, marks, marks[1:])}
         self._decode_tl.record_step(marks[-1] - marks[0], phases,
                                     occupancy=n_running / self.max_slots,
-                                    live_block_share=live_share)
+                                    live_block_share=live_share,
+                                    counters=counters)
         self._watcher.record_call(
             "engine.decode",
             (("tokens", (self.max_slots,), "int32"),

@@ -63,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import telemetry
+from ..nn.functional.attention import causal_window_mask
 from ..utils import faults
 
 __all__ = ["BlockAllocator", "PagedKVCache", "PagedCacheView", "DenseKVCache",
@@ -922,6 +923,15 @@ class PagedCacheView:
     layer; K/V writes are functional (``pool.at[...]``) and the updated pool
     accumulates on ``self.pool`` — the jitted step returns it as an output.
 
+    ``windows`` is the layers' window (``CacheLayer.window``), static: a
+    layer with one sees only its latest ``window`` positions, in every mode
+    below. The pool still keeps every position of every layer (one block
+    table a sequence); a window shortens the walk, not the table.
+
+    A model may hand the step integers about itself with :meth:`count`
+    (what its sparse layers routed where); they accumulate on
+    ``self.counters`` and the jitted step returns them beside the pool.
+
     Three modes, keyed on the query's token count and the prefix args:
     - decode (S_new == 1): batched slots, one token each; writes the token's
       K/V at position ``ctx_lens[s]`` through the block table, then runs the
@@ -937,13 +947,38 @@ class PagedCacheView:
     """
 
     def __init__(self, pool, block_tables, ctx_lens, block_size,
-                 prefix_block_tables=None, prefix_len=None):
+                 prefix_block_tables=None, prefix_len=None, windows=None,
+                 valid_len=None):
         self.pool = pool                      # [L, N, 2, H, bs, D]
         self.block_tables = block_tables      # [S, M] int32
         self.ctx_lens = ctx_lens              # [S] int32 (None for prefill)
         self.block_size = int(block_size)
         self.prefix_block_tables = prefix_block_tables  # [1, NPB] or None
         self.prefix_len = prefix_len          # int32 scalar (valid tokens)
+        self.windows = windows                # per layer: None | int
+        self.valid_len = valid_len            # prefill: tokens before padding
+        self.counters: dict = {}
+
+    def _window(self, layer_idx):
+        return None if self.windows is None else self.windows[layer_idx]
+
+    def live_rows(self, shape):
+        """bool ``shape`` ([slots, 1] in decode, [1, P] in prefill): the
+        rows of this step that are some request's token. An inactive decode
+        slot carries the all-zero block table; a prompt's padding lies past
+        ``valid_len``."""
+        if self.ctx_lens is not None:
+            return jnp.broadcast_to(self.block_tables[:, :1] != 0, shape)
+        if self.valid_len is None:
+            return jnp.ones(shape, bool)
+        return jnp.broadcast_to(
+            jnp.arange(shape[1], dtype=jnp.int32)[None] < self.valid_len,
+            shape)
+
+    def count(self, **named):
+        """Add integers (traced scalars) to the step's named counters."""
+        for name, v in named.items():
+            self.counters[name] = self.counters.get(name, 0) + v
 
     # the duck-typed hook LlamaAttention calls (raw arrays in/out)
     def attend(self, layer_idx, q, k, v):
@@ -967,8 +1002,8 @@ class PagedCacheView:
         from ..kernels import paged_attention_impl
 
         impl = paged_attention_impl()
-        out = impl(q[:, 0], pool[layer_idx], self.block_tables,
-                   pos + 1)                              # [S, Hq, D]
+        out = impl(q[:, 0], pool[layer_idx], self.block_tables, pos + 1,
+                   window=self._window(layer_idx))       # [S, Hq, D]
         return out[:, None]                              # [S, 1, Hq, D]
 
     def _write_prompt_blocks(self, layer_idx, k, v):
@@ -994,11 +1029,15 @@ class PagedCacheView:
         self._write_prompt_blocks(layer_idx, k, v)
         from ..nn.functional.attention import sdpa_ref
 
+        window = self._window(layer_idx)
         if self.prefix_block_tables is None:
             # causal within the prompt; padded tail positions produce
             # garbage that never flows back (causality) and is never read
             # (the engine takes logits at the last *valid* position)
-            return sdpa_ref(q, k, v, is_causal=True)
+            if window is None:
+                return sdpa_ref(q, k, v, is_causal=True)
+            mask = causal_window_mask(P, P, window)
+            return sdpa_ref(q, k, v, attn_mask=mask[None, None])
 
         # tail prefill: gather the cached prefix K/V through its block
         # table (padding entries point at scratch and are masked off by
@@ -1016,6 +1055,10 @@ class PagedCacheView:
         kj = jnp.arange(spfx + P, dtype=jnp.int32)[None, :]
         mask = jnp.where(kj < spfx, kj < self.prefix_len,
                          (kj - spfx) <= qi)              # [P, Spfx + P]
+        if window is not None:
+            # key positions: the prefix's own, then prefix_len + tail index
+            kpos = jnp.where(kj < spfx, kj, self.prefix_len + kj - spfx)
+            mask &= kpos > self.prefix_len + qi - window
         return sdpa_ref(q, k_full, v_full, attn_mask=mask[None, None])
 
 
@@ -1023,10 +1066,13 @@ class DenseKVCache:
     """Concatenating KV cache (the classic ``past_kv``): layer i holds the
     full [B, S_past, kv_heads, head_dim] K/V. Quadratic in memory across a
     long decode — the paged cache replaces it in the engine — but it is the
-    simplest correct reference, used by the cached-decode parity tests."""
+    simplest correct reference, used by the cached-decode parity tests.
+    ``windows`` as :class:`PagedCacheView`'s (``CacheLayer.window`` of each
+    layer; the past is kept whole all the same)."""
 
-    def __init__(self, num_layers: int):
+    def __init__(self, num_layers: int, windows=None):
         self.layers: list = [None] * num_layers
+        self.windows = windows
 
     @property
     def seq_len(self) -> int:
@@ -1042,11 +1088,9 @@ class DenseKVCache:
         from ..nn.functional.attention import sdpa_ref
 
         Sq, Sk = q.shape[1], k.shape[1]
-        if Sq == Sk:
+        window = None if self.windows is None else self.windows[layer_idx]
+        if Sq == Sk and window is None:
             return sdpa_ref(q, k, v, is_causal=True)
         # q token i sits at global position (Sk - Sq + i): attends j <= that
-        offset = Sk - Sq
-        qi = jnp.arange(Sq)[:, None]
-        kj = jnp.arange(Sk)[None, :]
-        mask = (kj <= qi + offset)[None, None]          # [1, 1, Sq, Sk]
-        return sdpa_ref(q, k, v, attn_mask=mask)
+        mask = causal_window_mask(Sq, Sk, window)
+        return sdpa_ref(q, k, v, attn_mask=mask[None, None])

@@ -614,6 +614,18 @@ def _pct(sorted_vals: list, q: float):
     return sorted_vals[lo] * (1 - frac) + sorted_vals[hi] * frac
 
 
+def nest_dotted(flat: dict) -> dict:
+    """``{"a.b": v, "c": w}`` as ``{"a": {"b": v}, "c": w}``."""
+    out: dict = {}
+    for name, v in flat.items():
+        node = out
+        *groups, leaf = name.split(".")
+        for g in groups:
+            node = node.setdefault(g, {})
+        node[leaf] = v
+    return out
+
+
 class StepTimeline:
     """Rolling per-phase step-time accounting with regression attribution.
 
@@ -657,12 +669,16 @@ class StepTimeline:
     # -- the core record (step() feeds it; tests can too) ---------------
     def record_step(self, total_s: float, phases: dict,
                     occupancy: float | None = None,
-                    live_block_share: float | None = None):
+                    live_block_share: float | None = None,
+                    counters: dict | None = None):
         """``occupancy`` is the share of the step's batch that did work
         (the serving engine: running slots / ``max_slots``);
         ``live_block_share`` the share of the running slots' block-table
         entries that hold context (what the paged kernel walks, of what a
-        static grid over the table would). Both are kept over the same
+        static grid over the table would). ``counters`` are further numbers
+        of the step under names of the caller's (what a model counted about
+        itself; a dotted name nests in the report: ``moe.routed_pairs`` is
+        ``report()["moe"]["routed_pairs"]``). All are kept over the same
         window and reported beside the times."""
         if not ENABLED[0]:
             return    # telemetry.disable(): one flag check, like every
@@ -678,9 +694,11 @@ class StepTimeline:
                 self._phases.setdefault(
                     ph, deque(maxlen=self.window)).append(v)
             for name, v in (("occupancy", occupancy),
-                            ("live_block_share", live_block_share)):
+                            ("live_block_share", live_block_share),
+                            *(counters or {}).items()):
                 if v is not None:
-                    self._shares[name].append(float(v))
+                    self._shares.setdefault(
+                        name, deque(maxlen=self.window)).append(float(v))
             self.steps += 1
         pm = _perf_metrics()
         pm.step_s.labels(timeline=self.name).observe(total_s)
@@ -738,11 +756,13 @@ class StepTimeline:
                     "mean": s / len(vals),
                     "frac": s / total_sum if total_sum else 0.0,
                 }
+            shares = {}
             for name, d in self._shares.items():
                 if d:
                     vals = sorted(d)
-                    out[name] = {"mean": sum(vals) / len(vals),
-                                 "p50": _pct(vals, 0.5)}
+                    shares[name] = {"mean": sum(vals) / len(vals),
+                                    "p50": _pct(vals, 0.5)}
+        out.update(nest_dotted(shares))
         return out
 
     def clear(self):

@@ -255,6 +255,90 @@ class TestPagedAttentionKernel:
                if a.dtype == jnp.bfloat16 else a for a in shapes]
         fn.trace(*f32).lower(lowering_platforms=("tpu",)).compile()
 
+    # a window over the same walk: shorter than a step, a step and a half,
+    # longer than any context
+    @pytest.mark.parametrize("window", [8 - 3, _STEP + 8 * 8 + 3, 2 * _FULL])
+    @pytest.mark.parametrize("contexts", list(WALK_CONTEXTS))
+    @pytest.mark.parametrize("rep", [6, 8])
+    def test_window_walk_matches_mirror(self, contexts, window, rep):
+        """The walk that starts at the page holding position ctx - window:
+        the pages before it hold 1e4 here too (they are some request's K/V
+        on the chip, but not this query's to see), so one folded in shows."""
+        w = self.WALK
+        S, Hkv, D, bs, M = w["S"], w["Hkv"], w["D"], w["bs"], w["M"]
+        ctx = np.asarray(self.WALK_CONTEXTS[contexts], np.int32)
+        rng = np.random.RandomState(len(contexts) + window)
+        N = S * M + 1
+        pool = np.full((N, 2, Hkv, bs, D), 1e4, np.float32)
+        bt = np.zeros((S, M), np.int32)
+        blocks = iter(1 + rng.permutation(N - 1))
+        for s in range(S):
+            first = max(ctx[s] - window, 0)
+            for j in range(-(-ctx[s] // bs)):
+                bt[s, j] = b = next(blocks)
+                lo = min(max(first - j * bs, 0), bs)
+                hi = min(bs, ctx[s] - j * bs)
+                pool[b, :, :, lo:hi] = rng.randn(2, Hkv, hi - lo, D)
+        q = rng.randn(S, Hkv * rep, D)
+        args = (jnp.asarray(q, jnp.float32), jnp.asarray(pool),
+                jnp.asarray(bt), jnp.asarray(ctx))
+        fn = self._walk_fns.setdefault(("window", window, rep), jax.jit(
+            lambda *a: paged_attention_pallas(*a, window=window,
+                                              interpret=True)))
+        got = np.asarray(fn(*args))
+        ref = np.asarray(paged_attention_ref(*args, window=window))
+        assert np.isfinite(got).all() and np.abs(ref).max() < 10
+        np.testing.assert_allclose(got, ref, atol=1e-5)
+        if window >= self._FULL:     # a window no context fills masks nothing
+            np.testing.assert_allclose(
+                ref, np.asarray(paged_attention_ref(*args)), atol=1e-6)
+
+    def test_window_mirror_matches_bruteforce(self):
+        q, pool, bt, ctx = self._case(3)
+        window = 5
+        out = np.asarray(paged_attention_ref(q, pool, bt, ctx, window=window))
+        rep = q.shape[1] // pool.shape[2]
+        for s in range(q.shape[0]):
+            k = np.concatenate([np.asarray(pool[bt[s, j], 0])
+                                for j in range(bt.shape[1])], axis=1)
+            v = np.concatenate([np.asarray(pool[bt[s, j], 1])
+                                for j in range(bt.shape[1])], axis=1)
+            c = int(ctx[s])
+            for h in range(q.shape[1]):
+                kh = k[h // rep][max(c - window, 0):c]
+                vh = v[h // rep][max(c - window, 0):c]
+                lo = (np.asarray(q)[s, h] @ kh.T) / math.sqrt(q.shape[2])
+                p = np.exp(lo - lo.max())
+                np.testing.assert_allclose(p / p.sum() @ vh, out[s, h],
+                                           atol=1e-5)
+
+    def test_without_a_window_the_traced_kernel_is_the_one_before_windows(
+            self):
+        """``window=None`` traces nothing of the window's: no op reads
+        ``ctx - window``, and the jaxpr is that of a call that never heard
+        of the argument."""
+        plain = jax.make_jaxpr(lambda *a: paged_attention_pallas(
+            *a, interpret=False))(*_published_decode_shapes())
+        none = jax.make_jaxpr(lambda *a: paged_attention_pallas(
+            *a, window=None, interpret=False))(*_published_decode_shapes())
+        windowed = jax.make_jaxpr(lambda *a: paged_attention_pallas(
+            *a, window=512, interpret=False))(*_published_decode_shapes())
+        assert str(plain) == str(none)
+        assert len(str(windowed)) > len(str(plain))
+
+    @pytest.mark.parametrize("Hq,window", [(48, None), (64, 512)])
+    def test_compiles_with_groups_of_6_and_8_for_v5e(self, v5e_chip, Hq,
+                                                     window):
+        """Laguna-XS.2's decode calls: 6 query heads a KV head in the full
+        layers, 8 and a window of 512 in the sliding ones."""
+        fn = jax.jit(lambda *a: paged_attention_pallas(
+            *a, window=window, interpret=False))
+        q, pool, bt, ctx = _published_decode_shapes(v5e_chip)
+        q = jax.ShapeDtypeStruct((q.shape[0], Hq, q.shape[2]), q.dtype,
+                                 sharding=v5e_chip)
+        fn.trace(q, pool, bt, ctx).lower(
+            lowering_platforms=("tpu",)).compile()
+
     def test_single_token_context(self):
         q, pool, bt, _ = self._case(2)
         ctx = jnp.ones(q.shape[0], jnp.int32)
@@ -264,6 +348,34 @@ class TestPagedAttentionKernel:
         rep = q.shape[1] // pool.shape[2]
         np.testing.assert_allclose(out, np.repeat(first, rep, axis=1),
                                    atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# grouped matrix product of the sparse experts (compiled for the chip here,
+# beside the other topology compiles; its numerics are in
+# tests/test_moe_grouped_matmul.py)
+# ---------------------------------------------------------------------------
+
+class TestGroupedMatmulCompiles:
+    # Laguna-XS.2: 256 experts, hidden 2048, expert width 512 (gate|up 1024)
+    @pytest.mark.parametrize("rows", [256, 4096, 16384])   # decode, prefills
+    @pytest.mark.parametrize("k,n", [(2048, 1024), (512, 2048)])
+    def test_compiles_at_published_shapes_for_v5e(self, v5e_chip, rows, k, n):
+        from paddle_tpu.kernels.moe_grouped_matmul import (
+            moe_grouped_matmul_pallas)
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+        fn = jax.jit(lambda *a: moe_grouped_matmul_pallas(
+            *a, interpret=False))
+        lowered = fn.trace(
+            sds((rows, k), jnp.bfloat16), sds((256, k, n), jnp.bfloat16),
+            sds((256,), jnp.int32)).lower(lowering_platforms=("tpu",))
+        text = lowered.as_text()
+        assert sum("moe_grouped_matmul" in l and "tpu_custom_call" in l
+                   for l in text.splitlines()) == 1
+        lowered.compile()
 
 
 # ---------------------------------------------------------------------------
