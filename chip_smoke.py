@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import functools
 import glob
 import json
 import math
@@ -59,7 +60,7 @@ LLAMA_7B_WIDTHS = dict(
 # 2 layers = 666,914,816 parameters; f32 weights + Adam moments are 8.0 GB
 TRAIN = dict(widths=LLAMA_7B_WIDTHS, layers=2, batch=4, seq=2048, steps=5)
 # 4 layers in bf16 = 2.1 GB of weights, 0.5 GB of KV pool; the paged kernel
-# alone is also checked at the benchmark's 32 slots (a 1.1 GB pool of one layer)
+# alone is also checked at the benchmark's 32 slots (a 2.1 GB pool of two layers)
 SERVE = dict(widths=LLAMA_7B_WIDTHS, layers=4, block_size=16, max_slots=4,
              max_model_len=2048, prompt_lens=(40, 300, 1500),
              shared_prefix=1024, tail_len=200, new_tokens=32, paged_slots=32)
@@ -170,20 +171,25 @@ def flash_vs_reference(batch, seq, heads, head_dim):
 
 def paged_vs_reference(slots, heads, kv_heads, head_dim, block_size,
                        max_blocks):
-    """The paged decode kernel against its jnp mirror, ragged contexts."""
+    """The paged decode kernel (the new rows written in place into the
+    second layer of a two-layer pool, donated as the engine donates it)
+    against its jnp mirror, ragged contexts."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from paddle_tpu.kernels.paged_attention import (paged_attention_pallas,
-                                                    paged_attention_ref)
+    from paddle_tpu.kernels.paged_attention import (
+        paged_attention_ref, paged_decode_pallas, paged_decode_ref)
 
     rng = np.random.RandomState(1)
     num_blocks = slots * max_blocks + 1
     q = jnp.asarray(rng.standard_normal((slots, heads, head_dim)),
                     jnp.bfloat16)
-    pool = jnp.asarray(rng.standard_normal(
-        (num_blocks, 2, kv_heads, block_size, head_dim)), jnp.bfloat16)
+    k_new, v_new = jnp.asarray(rng.standard_normal(
+        (2, slots, kv_heads, head_dim)), jnp.bfloat16)
+    pool = jax.random.normal(
+        jax.random.PRNGKey(1),
+        (2, num_blocks, 2, kv_heads, block_size, head_dim), jnp.bfloat16)
     # every slot owns a shuffled run of blocks; contexts from one token (an
     # idle slot) to the full table, none a multiple of the block size but
     # the last, with one block and a token, and a few hundred, among them
@@ -193,14 +199,22 @@ def paged_vs_reference(slots, heads, kv_heads, head_dim, block_size,
     ctx = np.linspace(1, full, slots).astype(np.int32)
     ctx[1:-1] += 3
     ctx[1:3] = np.minimum((block_size + 1, 300), full)
-    got = jax.jit(paged_attention_pallas)(q, pool, tables, ctx)
+    _, ref_pool = jax.jit(functools.partial(paged_decode_ref, layer_idx=1))(
+        q, k_new, v_new, pool, tables, ctx)
+    got, got_pool = jax.jit(
+        functools.partial(paged_decode_pallas, layer_idx=1),
+        donate_argnums=(3,))(q, k_new, v_new, pool, tables, ctx)
+    same_pool = bool((got_pool == ref_pool).all())
+    del pool, got_pool
     with jax.default_matmul_precision("highest"):
         ref = jax.jit(paged_attention_ref)(
-            q.astype(jnp.float32), pool.astype(jnp.float32), tables, ctx)
-    print(f"paged attention vs paged_attention_ref at [{slots} slots, "
+            q.astype(jnp.float32), ref_pool[1].astype(jnp.float32), tables,
+            ctx)
+    print(f"paged decode vs its mirror at [{slots} slots, "
           f"{heads} heads, {head_dim}], block {block_size}, contexts "
           f"{ctx.tolist()}, bf16:", flush=True)
     _assert_close("out", got, ref)
+    check(same_pool, "the pool after the kernel's write is not the mirror's")
 
 
 def kernels_in_step(ir_dir, step_name, kernels, mosaic):

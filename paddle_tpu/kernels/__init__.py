@@ -16,7 +16,7 @@ import jax
 __all__ = [
     "use_pallas", "use_pallas_explicit", "set_use_pallas", "attention_impl",
     "interpret_mode", "layer_norm_impl",
-    "rmsnorm_impl", "softmax_ce_impl", "paged_attention_impl",
+    "rmsnorm_impl", "softmax_ce_impl", "paged_decode_impl",
 ]
 
 _FORCE = os.environ.get("PADDLE_TPU_USE_PALLAS")  # "1" | "0" | None
@@ -104,16 +104,17 @@ def x64_off():
     return jax.enable_x64(False)
 
 
-def paged_attention_impl():
-    """Selector for the serving engine's ragged paged-attention decode op
-    (mirrors attention_impl): the Pallas block-gather kernel when the policy
-    picks Pallas, else the jnp gather mirror — the mirror is also the path
-    taken on CPU test runs, where it is authoritative for semantics."""
-    from .paged_attention import paged_attention_pallas, paged_attention_ref
+def paged_decode_impl():
+    """Selector for the serving engine's decode op of one layer (write the
+    new K/V rows into the pool, ragged paged attention over it; mirrors
+    attention_impl): the Pallas kernel, in place, when the policy picks
+    Pallas, else the jnp mirror — the mirror is also the path taken on CPU
+    test runs, where it is authoritative for semantics."""
+    from .paged_attention import paged_decode_pallas, paged_decode_ref
 
     if use_pallas():
-        return paged_attention_pallas
-    return paged_attention_ref
+        return paged_decode_pallas
+    return paged_decode_ref
 
 
 def layer_norm_impl():

@@ -1,16 +1,20 @@
-"""Ragged paged attention (TPU): decode attention over a paged KV cache.
+"""Ragged paged attention (TPU): the decode step of one layer over a paged
+KV cache, the new token's K/V written in place.
 
 The serving engine (paddle_tpu.serving) keeps every sequence's K/V in
 fixed-size blocks of one preallocated pool
-``[num_blocks, 2, kv_heads, block_size, head_dim]`` and hands each decode
-slot a block table (pool indices) plus a context length. This kernel
-computes, for one query token per slot,
+``[layers, num_blocks, 2, kv_heads, block_size, head_dim]`` and hands each
+decode slot a block table (pool indices) plus a context length. For one
+query token per slot, :func:`paged_decode_pallas` first puts the token's own
+K/V at position ``ctx[s] - 1`` of layer ``layer_idx`` and then computes
 
     out[s] = softmax(q[s] @ K[s, :ctx[s]]^T) @ V[s, :ctx[s]]
 
 where K/V are *gathered through the block table* — the ragged part: slots
 have arbitrary context lengths but the kernel is one compiled program
-(Ragged Paged Attention, PAPERS.md).
+(Ragged Paged Attention, PAPERS.md). It returns the output and the pool.
+:func:`paged_attention_pallas` is the same kernel body without the write,
+over one layer's ``[num_blocks, 2, kv_heads, block_size, head_dim]``.
 
 TPU shape: the grid is the slots; the block tables and context lengths ride
 in scalar prefetch (``pltpu.PrefetchScalarGridSpec``) and the pool stays in
@@ -27,10 +31,26 @@ streaming softmax (m, l, acc) is float32 and carries through the loop. So
 the work follows the live K/V, not ``slots x kv_heads x max_blocks``: a slot
 with one token costs one page.
 
+The pool never moves. It is the call's operand whole, every layer of it, in
+the row-major layout the engine holds it in, addressed at
+``pool.at[layer_idx, block]``, and **aliased to the call's second output**
+(``input_output_aliases``). A step that calls the kernel once a layer, each
+call the only user of the pool the one before returned, and whose own pool
+argument is donated, compiles to a chain of custom calls on one buffer: no
+scatter, no slice of a layer, no relayout (a ``pool.at[...].set`` and a
+``pool[layer_idx]`` around a read-only kernel cost two copies of the whole
+pool and one of every layer a step, three quarters of the device's time;
+PERF.md Findings PR 30; ``tests/test_serving.py`` holds the compiled
+structure). The new row is written by the kernel itself: it lands in the
+slot's last page in VMEM after that page's fetch, is attended over from
+there, and the 64 KiB page goes back to the pool while the dots run (see
+:func:`_paged_kernel` for why a page and not a row, and for the order).
+
 Selection policy (the flash_attention / rmsnorm idiom): the Pallas kernel
 runs on real TPU; under ``JAX_PLATFORMS=cpu`` (tests) and inside the
 ``check_vma`` interpreter the pure-jnp mirror below runs instead — the same
-math unblocked, so CPU tests are authoritative for the semantics.
+math unblocked, the write a ``pool.at[...].set``, so CPU tests are
+authoritative for the semantics.
 """
 from __future__ import annotations
 
@@ -44,7 +64,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import interpret_mode as _interpret_mode, x64_off
 
-__all__ = ["paged_attention", "paged_attention_pallas", "paged_attention_ref"]
+__all__ = ["paged_attention_pallas", "paged_attention_ref",
+           "paged_decode_pallas", "paged_decode_ref"]
 
 NEG_INF = -1e30
 
@@ -91,6 +112,35 @@ def paged_attention_ref(q, kv_pool, block_tables, context_lens, *,
     return out.reshape(S, Hq, D).astype(q.dtype)
 
 
+def paged_decode_ref(q, k_new, v_new, kv_pool, block_tables, context_lens, *,
+                     layer_idx, sm_scale=None, window=None):
+    """Pure-jnp decode step of one layer: write the slots' new K/V, attend.
+
+    k_new, v_new: [slots, kv_heads, head_dim] — K/V of each slot's query
+                  token, position ``context_lens - 1``
+    kv_pool:      [layers, num_blocks, 2, kv_heads, block_size, head_dim]
+    layer_idx:    the layer of the pool this call writes and reads
+    (the rest as :func:`paged_attention_ref`)
+    returns       (out [slots, num_q_heads, head_dim], the pool with row
+                  ``(pos % block_size)`` of block ``block_tables[s, pos //
+                  block_size]`` of ``layer_idx`` replaced, nothing else)
+    """
+    bs = kv_pool.shape[4]
+    pos = context_lens.astype(jnp.int32) - 1
+    rows = jnp.arange(q.shape[0], dtype=jnp.int32)
+    bidx = block_tables[rows, pos // bs]                 # [S]
+    off = pos % bs
+    # mixed basic/advanced indexing: advanced dims (S) move to the front,
+    # so the target of the .set is [S, kv_heads, head_dim]
+    pool = kv_pool.at[layer_idx, bidx, 0, :, off, :].set(
+        k_new.astype(kv_pool.dtype))
+    pool = pool.at[layer_idx, bidx, 1, :, off, :].set(
+        v_new.astype(kv_pool.dtype))
+    out = paged_attention_ref(q, pool[layer_idx], block_tables, context_lens,
+                              sm_scale=sm_scale, window=window)
+    return out, pool
+
+
 # ---------------------------------------------------------------------------
 # Pallas kernel
 # ---------------------------------------------------------------------------
@@ -114,15 +164,34 @@ def _pages_per_step(block_size, kv_heads, head_dim, itemsize, max_blocks):
     return min(want, fit, max_blocks)
 
 
-def _paged_kernel(bt_ref, ctx_ref, q_ref, pool_hbm, o_ref,
-                  kv_buf, sems, buf_ref, *, sm_scale, window):
+def _paged_kernel(bt_ref, ctx_ref, *refs, sm_scale, window, write):
     """Grid (slots,); scalar-prefetch refs first.
 
     bt_ref [S, M], ctx_ref [S]: SMEM. q_ref/o_ref: [1, Hkv, rep, D], this
     slot's rows. pool_hbm: the whole pool, left in HBM. kv_buf:
     [2, 2, Hkv, P, bs, D] — two landing buffers of P pages, K and V of
-    every KV head. sems: one DMA semaphore a buffer. buf_ref: SMEM [1], the
-    buffer the *next* compute step reads (carried across grid steps).
+    every KV head. sems: one DMA semaphore a buffer and one for the
+    write-back. buf_ref: SMEM [1], the buffer the *next* compute step reads
+    (carried across grid steps).
+
+    With ``write`` the pool is ``[L, N, ...]`` and is this call's aliased
+    output: ``refs`` is (layer_ref, q_ref, new_ref, the pool as input,
+    o_ref, the pool as output, scratch). layer_ref, SMEM [1], is a third
+    prefetched scalar: the layer is data, so that the calls of a step's
+    layers are one kernel, traced, compiled and loaded once (a static index
+    made eight, and 3 s more set-up; PERF.md Findings PR 30). Every read
+    and the write go through the output ref, which is the same HBM on the
+    chip and the one coherent copy in the interpreter. new_ref
+    ``[1, 2, Hkv, 1, D]`` is this slot's new K/V row, position
+    ``ctx - 1``. Its page is the slot's last, fetched (a pair
+    ahead, so before any write) without the row: after the wait of the
+    slot's last step the row is put into the landing buffer, by a select
+    over the page's rows (one row at a run-time offset is half a packed
+    bf16 sublane, which neither a store nor a DMA addresses), the dots read
+    it from there, and the page goes back to the pool while they run. No
+    other slot reads that page (a shared block is never written:
+    ``ensure_writable``), except the inactive slots, which all carry the
+    zero table, write the reserved block 0 and give an output nobody reads.
 
     The work is the flat sequence of (slot, step) pairs with
     ``step < cdiv(pages(slot), P)``; each pair waits for its own pages and
@@ -135,6 +204,12 @@ def _paged_kernel(bt_ref, ctx_ref, q_ref, pool_hbm, o_ref,
     positions are masked. ``window`` is static: without one, none of this
     is traced.
     """
+    if write:
+        (layer_ref, q_ref, new_ref, _, o_ref, pool_hbm, kv_buf, sems,
+         buf_ref) = refs
+        pool_hbm = pool_hbm.at[layer_ref[0]]
+    else:
+        q_ref, pool_hbm, o_ref, kv_buf, sems, buf_ref = refs
     s = pl.program_id(0)
     num_slots = pl.num_programs(0)
     _, _, Hkv, P, bs, D = kv_buf.shape
@@ -155,9 +230,7 @@ def _paged_kernel(bt_ref, ctx_ref, q_ref, pool_hbm, o_ref,
 
     def page_copy(slot, page, buf):
         """The copy of table entry ``page`` of ``slot`` into its place in
-        landing buffer ``buf``. The one place that addresses the pool: a
-        later pool of all layers adds its layer index to ``pool_hbm.at``
-        here."""
+        landing buffer ``buf``."""
         return pltpu.make_async_copy(
             pool_hbm.at[bt_ref[slot, page]],
             kv_buf.at[buf, :, :, page % P],
@@ -187,7 +260,18 @@ def _paged_kernel(bt_ref, ctx_ref, q_ref, pool_hbm, o_ref,
 
     ctx = ctx_ref[s]
     n_pages = live_pages(s)
+    new_page = n_pages - 1          # holds position ctx - 1, the new row's
+    new_at = new_page % P           # its place in a landing buffer
     step0 = first_step(s)
+
+    def write_back(buf):
+        """The copy of the new row's page from landing buffer ``buf`` to
+        its block of the pool."""
+        return pltpu.make_async_copy(
+            kv_buf.at[buf, :, :, new_at],
+            pool_hbm.at[bt_ref[s, new_page]],
+            sems.at[2])
+
     n_steps = pl.cdiv(n_pages, P)
     if window is not None:
         n_steps -= step0
@@ -224,6 +308,14 @@ def _paged_kernel(bt_ref, ctx_ref, q_ref, pool_hbm, o_ref,
             # nor were the first step's pages before the window's first
             jax.lax.fori_loop(0, jnp.clip(first_page(s) - i * P, 0, P),
                               zero_v, None)
+        if write:
+            @pl.when(last)
+            def _write():
+                page = kv_buf[buf, :, :, new_at]             # [2, Hkv, bs, D]
+                row = jax.lax.broadcasted_iota(jnp.int32, page.shape, 2)
+                kv_buf[buf, :, :, new_at] = jnp.where(
+                    row == (ctx - 1) % bs, new_ref[0], page)
+                write_back(buf).start()
 
         k = kv_buf[buf, 0].reshape(Hkv, T, D)
         v = kv_buf[buf, 1].reshape(Hkv, T, D)
@@ -252,92 +344,130 @@ def _paged_kernel(bt_ref, ctx_ref, q_ref, pool_hbm, o_ref,
     l0 = jnp.zeros((Hkv, rep, 1), jnp.float32)
     acc0 = jnp.zeros((Hkv, rep, D), jnp.float32)
     _, l_fin, acc = jax.lax.fori_loop(0, n_steps, step_body, (m0, l0, acc0))
+    if write:
+        # the next compute step starts copies into this buffer
+        write_back((buf0 + n_steps - 1) % 2).wait()
     buf_ref[0] = (buf0 + n_steps) % 2
     o_ref[0] = (acc / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("sm_scale", "window", "interpret"))
-def _paged_call(q4, kv_pool, block_tables, context_lens, *, sm_scale,
-                window, interpret):
+def _paged_call(q4, kv_new, layer, kv_pool, block_tables, context_lens, *,
+                sm_scale, window, interpret):
     """The ``pallas_call`` on ``q4 [S, Hkv, rep, D]``. Jitted so that a step
     that calls it once a layer traces and lowers the kernel once: the
     layers' calls have the same shapes and share the one traced function
-    (XLA inlines it; the device op is still ``paged_attention``)."""
+    (XLA inlines it; the device op is still ``paged_attention``).
+
+    ``layer=None``: ``kv_pool`` is one layer's ``[N, 2, Hkv, bs, D]``, read
+    only (``kv_new`` is None); returns the output. Else ``layer`` is int32
+    ``[1]``, ``kv_pool`` ``[L, N, 2, Hkv, bs, D]``, ``kv_new
+    [S, 2, Hkv, 1, D]`` the slots' new rows; returns (output, pool), the
+    pool aliased to the operand."""
     S, Hkv, rep, D = q4.shape
-    bs = kv_pool.shape[3]
+    bs = kv_pool.shape[-2]
     P = _pages_per_step(bs, Hkv, D, kv_pool.dtype.itemsize,
                         block_tables.shape[1])
+    write = layer is not None
 
-    def slot_rows(s, bt, ctx):
-        return (s, 0, 0, 0)
-
+    # the pool stays in HBM; the kernel copies the pages it needs
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    q_spec = pl.BlockSpec((1, Hkv, rep, D), lambda s, *_: (s, 0, 0, 0))
+    scalars = [block_tables.astype(jnp.int32), context_lens.astype(jnp.int32)]
+    operands, in_specs = [q4, kv_pool], [q_spec, in_hbm]
+    out_specs = q_spec
+    out_shape = jax.ShapeDtypeStruct(q4.shape, q4.dtype)
+    if write:
+        scalars.append(layer)
+        # a row as [2, Hkv, 1, D]: the block's last two dims are whole, and
+        # the row broadcasts over a page's rows along the sublanes
+        operands.insert(1, kv_new)
+        in_specs.insert(1, pl.BlockSpec(
+            (1, 2, Hkv, 1, D), lambda s, *_: (s, 0, 0, 0, 0)))
+        out_specs = (q_spec, in_hbm)
+        out_shape = (out_shape,
+                     jax.ShapeDtypeStruct(kv_pool.shape, kv_pool.dtype))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block_tables, context_lens
+        num_scalar_prefetch=len(scalars),
         grid=(S,),
-        in_specs=[
-            pl.BlockSpec((1, Hkv, rep, D), slot_rows),
-            # the pool stays in HBM; the kernel copies the pages it needs
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, Hkv, rep, D), slot_rows),
+        in_specs=in_specs,
+        out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((2, 2, Hkv, P, bs, D), kv_pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((3,)),
             pltpu.SMEM((1,), jnp.int32),
         ],
     )
     with x64_off():
         return pl.pallas_call(
             functools.partial(_paged_kernel, sm_scale=sm_scale,
-                              window=window),
+                              window=window, write=write),
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
+            out_shape=out_shape,
+            # the last operand is the pool: written in place, handed on as
+            # output 1
+            input_output_aliases=(
+                {len(scalars) + len(operands) - 1: 1} if write else {}),
             # slots run in order: the landing buffers and the buffer index
             # carry from one slot to the next
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
             name="paged_attention",
-        )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-          q4, kv_pool)
+        )(*scalars, *operands)
 
 
-def paged_attention_pallas(q, kv_pool, block_tables, context_lens, *,
-                           sm_scale=None, window=None, interpret=None):
-    """Pallas ragged paged attention; see :func:`paged_attention_ref` for
-    the argument contract. ``interpret`` defaults to the platform policy."""
-    S, Hq, D = q.shape
-    Hkv = kv_pool.shape[2]
-    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
-    # this body runs at TRACE time (the args are tracers inside the engine's
-    # jitted step), so one record here is one Pallas kernel build — the
-    # CompileWatcher's "kernel build" jit entry point
+def _pallas(q, kv_new, kv_pool, block_tables, context_lens, *, layer_idx,
+            sm_scale, window, interpret, **recorded):
+    """What both entries do around :func:`_paged_call`. They run at TRACE
+    time (their args are tracers inside the engine's jitted step), so one
+    record here is one Pallas kernel build — the CompileWatcher's "kernel
+    build" jit entry point."""
     from ..telemetry import perf as _perf
 
+    recorded.update(q=q, kv_pool=kv_pool, block_tables=block_tables,
+                    context_lens=context_lens)
     _perf.compile_watcher().record_call(
         "pallas.paged_attention",
-        _perf.abstract_signature(
-            (q, kv_pool, block_tables, context_lens),
-            ("q", "kv_pool", "block_tables", "context_lens")))
+        _perf.abstract_signature(tuple(recorded.values()), tuple(recorded)))
+    S, Hq, D = q.shape
+    Hkv = kv_pool.shape[-3]
     if interpret is None:
         interpret = _interpret_mode()
     # [S, Hkv, rep, D]: a (Hkv, rep, D) block is then the full extent of
     # the last two dims, which Mosaic tiles for any rep (a block of rep rows
     # of [S, Hq, D] is neither a multiple of 8 rows nor the full dim)
-    out = _paged_call(q.reshape(S, Hkv, Hq // Hkv, D), kv_pool, block_tables,
-                      context_lens, sm_scale=scale, window=window,
-                      interpret=interpret)
-    return out.reshape(S, Hq, D)
+    got = _paged_call(
+        q.reshape(S, Hkv, Hq // Hkv, D), kv_new,
+        None if layer_idx is None else jnp.full((1,), layer_idx, jnp.int32),
+        kv_pool, block_tables, context_lens, window=window,
+        interpret=interpret,
+        sm_scale=sm_scale if sm_scale is not None else 1.0 / math.sqrt(D))
+    if layer_idx is None:
+        return got.reshape(S, Hq, D)
+    return got[0].reshape(S, Hq, D), got[1]
 
 
-def paged_attention(q, kv_pool, block_tables, context_lens, *, sm_scale=None,
-                    window=None):
-    """Policy entry: Pallas on TPU, jnp mirror elsewhere (the jnp path is
-    also what runs inside the check_vma interpreter, where interpret-mode
-    pallas cannot trace — same policy as kernels/flash_attention.py)."""
-    from . import paged_attention_impl
+def paged_attention_pallas(q, kv_pool, block_tables, context_lens, *,
+                           sm_scale=None, window=None, interpret=None):
+    """Pallas ragged paged attention over one layer's pool, read only; see
+    :func:`paged_attention_ref` for the argument contract. ``interpret``
+    defaults to the platform policy."""
+    return _pallas(q, None, kv_pool, block_tables, context_lens,
+                   layer_idx=None, sm_scale=sm_scale, window=window,
+                   interpret=interpret)
 
-    impl = paged_attention_impl()
-    return impl(q, kv_pool, block_tables, context_lens, sm_scale=sm_scale,
-                window=window)
+
+def paged_decode_pallas(q, k_new, v_new, kv_pool, block_tables, context_lens,
+                        *, layer_idx, sm_scale=None, window=None,
+                        interpret=None):
+    """Pallas decode step of one layer, in place; see
+    :func:`paged_decode_ref` for the argument contract. The returned pool
+    is the operand's buffer (``input_output_aliases``): a caller that owns
+    it (the engine donates it to the step) pays no copy."""
+    kv_new = jnp.stack([k_new, v_new], axis=1)[:, :, :, None, :]
+    return _pallas(q, kv_new.astype(kv_pool.dtype), kv_pool, block_tables,
+                   context_lens, layer_idx=layer_idx, sm_scale=sm_scale,
+                   window=window, interpret=interpret,
+                   k_new=k_new, v_new=v_new)

@@ -920,8 +920,12 @@ class PagedKVCache:
 class PagedCacheView:
     """Per-trace functional view of the pool, passed to the model as
     ``cache=``. The model's attention layers call :meth:`attend` once per
-    layer; K/V writes are functional (``pool.at[...]``) and the updated pool
-    accumulates on ``self.pool`` — the jitted step returns it as an output.
+    layer; each call takes ``self.pool`` and leaves the updated pool there —
+    the jitted step returns it as an output. In decode that is the whole
+    pool through the layer's kernel and out again, the new rows written by
+    the kernel in place (on the chip one buffer from the step's donated
+    argument to its output: nothing between the calls may read the pool);
+    the prefills' whole-block writes are functional (``pool.at[...]``).
 
     ``windows`` is the layers' window (``CacheLayer.window``), static: a
     layer with one sees only its latest ``window`` positions, in every mode
@@ -933,9 +937,10 @@ class PagedCacheView:
     ``self.counters`` and the jitted step returns them beside the pool.
 
     Three modes, keyed on the query's token count and the prefix args:
-    - decode (S_new == 1): batched slots, one token each; writes the token's
-      K/V at position ``ctx_lens[s]`` through the block table, then runs the
-      ragged paged-attention kernel over ``ctx_lens + 1`` tokens.
+    - decode (S_new == 1): batched slots, one token each; one call
+      (``kernels.paged_decode_impl``) writes the token's K/V at position
+      ``ctx_lens[s]`` through the block table and runs the ragged
+      paged-attention kernel over ``ctx_lens + 1`` tokens.
     - prefill (S_new > 1, batch 1): the padded prompt; scatters whole blocks
       into the pool and attends densely (causal) within the prompt — no pool
       reads, so concurrent sequences are untouched.
@@ -987,23 +992,15 @@ class PagedCacheView:
         return self._prefill(layer_idx, q, k, v)
 
     def _decode(self, layer_idx, q, k, v):
-        S = q.shape[0]
-        bs = self.block_size
-        pos = self.ctx_lens.astype(jnp.int32)           # new token's position
-        rows = jnp.arange(S, dtype=jnp.int32)
-        bidx = self.block_tables[rows, pos // bs]       # [S]
-        off = pos % bs
-        # mixed basic/advanced indexing: advanced dims (S) move to the front,
-        # so the target of the .set is [S, kv_heads, head_dim]
-        pool = self.pool.at[layer_idx, bidx, 0, :, off, :].set(k[:, 0])
-        pool = pool.at[layer_idx, bidx, 1, :, off, :].set(v[:, 0])
-        self.pool = pool
+        from ..kernels import paged_decode_impl
 
-        from ..kernels import paged_attention_impl
-
-        impl = paged_attention_impl()
-        out = impl(q[:, 0], pool[layer_idx], self.block_tables, pos + 1,
-                   window=self._window(layer_idx))       # [S, Hq, D]
+        # the whole pool in, the whole pool out: nothing here slices or
+        # scatters it, so that on the chip the layers' kernels hand one
+        # buffer down the step (see kernels/paged_attention.py)
+        out, self.pool = paged_decode_impl()(
+            q[:, 0], k[:, 0], v[:, 0], self.pool, self.block_tables,
+            self.ctx_lens.astype(jnp.int32) + 1, layer_idx=layer_idx,
+            window=self._window(layer_idx))              # [S, Hq, D]
         return out[:, None]                              # [S, 1, Hq, D]
 
     def _write_prompt_blocks(self, layer_idx, k, v):

@@ -16,7 +16,8 @@ import jax.numpy as jnp
 
 import paddle_tpu
 from paddle_tpu.kernels.paged_attention import (
-    paged_attention_pallas, paged_attention_ref)
+    paged_attention_pallas, paged_attention_ref, paged_decode_pallas,
+    paged_decode_ref)
 from paddle_tpu.models import LlamaForCausalLM, llama_tiny
 from paddle_tpu.nn import sample_logits
 from paddle_tpu.serving import (
@@ -339,6 +340,84 @@ class TestPagedAttentionKernel:
         fn.trace(q, pool, bt, ctx).lower(
             lowering_platforms=("tpu",)).compile()
 
+    # the in-place write over the same walk; a context counts the new
+    # token, whose row is position ctx - 1: row 0 of a page it opens (in a
+    # later compute step too), the last row, a middle one
+    WRITE_CONTEXTS = {
+        "one_token": [1, 1, 1, 1],
+        "row_first_last_mid": [2 * 8 + 1, 3 * 8, 8 + 4, _STEP + 1],
+        "inactive_beside_live": [2, _FULL, 2, 5],
+        "ragged": [3, _STEP + 8 + 5, 2 * _STEP - 1, _FULL],
+    }
+
+    @pytest.mark.parametrize("dtype,rep,window,atol", [
+        ("float32", 1, None, 1e-5), ("float32", 4, None, 1e-5),
+        ("bfloat16", 4, None, 2e-2),
+        ("float32", 6, _STEP + 8 * 8 + 3, 1e-5), ("bfloat16", 8, 8 - 3, 2e-2)])
+    @pytest.mark.parametrize("contexts", list(WRITE_CONTEXTS))
+    def test_write_walk_matches_mirror(self, contexts, dtype, rep, window,
+                                       atol):
+        """The decode call on the whole pool (interpret mode against the
+        mirror): the new row is attended over and is the only thing written.
+        The middle layer of three is the call's; where it holds no context
+        it is 1e4, the new row's own place too, so a row put in after the
+        dots, or not at all, shows in the output. A slot with the all-zero
+        table is inactive, as the engine has it (a context of 2, the
+        reserved block 0): it may write nothing but its row of block 0."""
+        w = self.WALK
+        S, Hkv, D, bs, M = w["S"], w["Hkv"], w["D"], w["bs"], w["M"]
+        L, layer = 3, 1
+        ctx = np.asarray(self.WRITE_CONTEXTS[contexts], np.int32)
+        inactive = (contexts == "inactive_beside_live") & (ctx == 2)
+        rng = np.random.RandomState(len(contexts) + rep)
+        N = S * M + 1
+        pool = rng.randn(L, N, 2, Hkv, bs, D).astype(np.float32)
+        pool[layer] = 1e4
+        bt = np.zeros((S, M), np.int32)
+        blocks = iter(1 + rng.permutation(N - 1))
+        for s in np.flatnonzero(~inactive):
+            first = 0 if window is None else max(ctx[s] - window, 0)
+            for j in range(-(-ctx[s] // bs)):
+                bt[s, j] = b = next(blocks)
+                lo = min(max(first - j * bs, 0), bs)
+                hi = min(bs, ctx[s] - 1 - j * bs)    # the new row stays 1e4
+                if hi > lo:
+                    pool[layer, b, :, :, lo:hi] = rng.randn(
+                        2, Hkv, hi - lo, D)
+        q = rng.randn(S, Hkv * rep, D)
+        k_new, v_new = rng.randn(2, S, Hkv, D)
+        args = (*(jnp.asarray(a, dtype) for a in (q, k_new, v_new, pool)),
+                jnp.asarray(bt), jnp.asarray(ctx))
+        fn = self._walk_fns.setdefault(("write", dtype, rep, window), jax.jit(
+            lambda *a: paged_decode_pallas(*a, layer_idx=layer,
+                                           window=window, interpret=True)))
+        got, got_pool = fn(*args)
+        ref, ref_pool = paged_decode_ref(*args, layer_idx=layer,
+                                         window=window)
+        got = np.asarray(got).astype(np.float32)[~inactive]
+        ref = np.asarray(ref).astype(np.float32)[~inactive]
+        assert np.isfinite(got).all() and np.abs(ref).max() < 10
+        np.testing.assert_allclose(got, ref, atol=atol)
+        # the pool: the mirror's, bit for bit (block 0 apart, where the
+        # inactive slots' rows race in both) ...
+        before = np.asarray(args[3]).astype(np.float32)
+        got_pool = np.asarray(got_pool).astype(np.float32)
+        ref_pool = np.asarray(ref_pool).astype(np.float32)
+        assert (got_pool[:, 1:] == ref_pool[:, 1:]).all()
+        # ... and the operand's but for one row a live slot, which holds
+        # the slot's new K/V
+        changed = got_pool != before
+        assert not changed[[0, 2]].any()
+        if inactive.any():
+            changed[layer, 0, :, :, 1] = False
+        assert not changed[layer, 0].any()
+        new = np.asarray(jnp.stack(args[1:3], 1)).astype(np.float32)
+        for s in np.flatnonzero(~inactive):
+            b, off = bt[s, (ctx[s] - 1) // bs], (ctx[s] - 1) % bs
+            assert (got_pool[layer, b, :, :, off] == new[s]).all()
+            changed[layer, b, :, :, off] = False
+        assert not changed[layer, 1:].any()
+
     def test_single_token_context(self):
         q, pool, bt, _ = self._case(2)
         ctx = jnp.ones(q.shape[0], jnp.int32)
@@ -348,6 +427,93 @@ class TestPagedAttentionKernel:
         rep = q.shape[1] // pool.shape[2]
         np.testing.assert_allclose(out, np.repeat(first, rep, axis=1),
                                    atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the decode step's cache path, compiled for the chip: the pool stays put
+# ---------------------------------------------------------------------------
+
+class TestDecodeKeepsThePoolInPlace:
+    # the serve cells' decode steps: layers' query heads and windows, pool
+    CELLS = {
+        "mistral": ([32] * 8, None, jnp.bfloat16),
+        "mistral_f32_pool": ([32] * 8, None, jnp.float32),
+        "laguna": ([48, 64, 64, 64, 48], [None, 512, 512, 512, None],
+                   jnp.bfloat16),
+    }
+
+    @pytest.fixture
+    def on_the_chip(self, monkeypatch):
+        """What a process on a TPU would pick: the Pallas path, compiled by
+        Mosaic (this process's backend is the CPU)."""
+        from paddle_tpu import kernels
+        from paddle_tpu.kernels import paged_attention
+
+        monkeypatch.setattr(paged_attention, "_interpret_mode", lambda: False)
+        kernels.set_use_pallas(True)
+        yield
+        kernels.set_use_pallas(None)
+
+    @pytest.mark.parametrize("cell", list(CELLS))
+    def test_no_pool_shaped_copy_in_the_compiled_step(self, v5e_chip,
+                                                      on_the_chip, cell):
+        """``PagedCacheView.attend`` once a layer, one after the other as a
+        model calls it, the pool donated as the engine donates it, at the
+        cell's shapes (4,097 blocks of 16, 32 slots, 8 KV heads of 128): in
+        the optimised HLO the pool is the entry parameter, one
+        ``paged_attention`` custom call a layer that takes it whole and
+        hands it on, and the output, which is the parameter's buffer.
+        Nothing else has the pool's shape or a layer's: no ``copy``,
+        ``scatter``, ``dynamic-update-slice`` or fusion. (Before the kernel
+        wrote the row itself this failed, in the Mistral cell with sixteen
+        ``scatter``, two ``copy`` of the whole pool, between XLA's layout
+        for the scatters and the custom call's, and eight fusions that each
+        materialised one layer's ``[N, 2, H, bs, D]`` as the call's
+        operand: 25 of the 33.5 ms of a step on the chip, PERF.md Findings
+        PR 30.)"""
+        import re
+        from collections import Counter
+
+        from paddle_tpu.serving.kv_cache import PagedCacheView
+
+        heads, windows, pool_dtype = self.CELLS[cell]
+        L, N, S, Hkv, D, bs, M = len(heads), 4097, 32, 8, 128, 16, 128
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+        def step(pool, bt, ctx, qs, k, v):
+            view = PagedCacheView(pool, bt, ctx, bs, windows=windows)
+            x = jnp.zeros((), k.dtype)
+            for layer, q in enumerate(qs):
+                x = view.attend(layer, q + x, k + x, v + x).sum().astype(
+                    k.dtype)
+            return view.pool, x
+
+        compiled = jax.jit(step, donate_argnums=(0,)).trace(
+            sds((L, N, 2, Hkv, bs, D), pool_dtype), sds((S, M), jnp.int32),
+            sds((S,), jnp.int32),
+            [sds((S, 1, h, D), jnp.bfloat16) for h in heads],
+            sds((S, 1, Hkv, D), jnp.bfloat16),
+            sds((S, 1, Hkv, D), jnp.bfloat16)).lower(
+            lowering_platforms=("tpu",)).compile()
+        text = compiled.as_text()
+        layer_shape = f"[{N},2,{Hkv},{bs},{D}]"
+        pool_shape = f"[{L},{layer_shape[1:]}"
+        # %name = <result type> opcode(operands), ...
+        instr = re.compile(r"^\s*(?:ROOT )?%\S+ = (.*?) ([a-z][a-z-]*)\(")
+        ops = [m.group(2) for m in map(instr.match, text.splitlines())
+               if m and (pool_shape in m.group(1)
+                         or layer_shape in m.group(1))]
+        calls = [l for l in text.splitlines()
+                 if "custom-call(" in l and "paged_attention" in l]
+        assert sorted(set(ops)) == ["custom-call", "get-tuple-element",
+                                    "parameter", "tuple"], Counter(ops)
+        assert len(calls) == L and all(pool_shape in c for c in calls)
+        assert ops.count("custom-call") == L and ops.count("parameter") == 1
+        assert re.search(r"input_output_alias=\{ \{0\}: \(0, \{\}", text)
+        layer_bytes = N * 2 * Hkv * bs * D * jnp.dtype(pool_dtype).itemsize
+        assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
 
 
 # ---------------------------------------------------------------------------
