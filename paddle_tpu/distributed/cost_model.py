@@ -36,10 +36,10 @@ class ModelSpec:
     flash_attention: bool = True
 
     def flops_per_token(self):
-        from ..profiler import transformer_flops_per_token
-
-        return transformer_flops_per_token(
-            self.n_params, self.n_layers, self.hidden, self.seq_len)
+        # 6N weight flops + 12·L·H·S attention flops per trained token: the
+        # planner's ranking estimate, not a measurement's work count
+        return (6.0 * self.n_params
+                + 12.0 * self.n_layers * self.hidden * self.seq_len)
 
 
 @dataclass
@@ -56,10 +56,10 @@ class ClusterSpec:
     def detect(cls):
         import jax
 
-        from ..profiler import peak_flops
+        from ..telemetry.cost import platform_peaks
 
         dev = jax.devices()[0]
-        spec = cls(peak_flops=peak_flops(dev.device_kind))
+        spec = cls(peak_flops=platform_peaks(dev.device_kind)["flops_per_s"])
         if dev.platform == "cpu":  # virtual test mesh: tiny budgets, same ranking
             spec.hbm_bytes = 4e9
             spec.ici_bandwidth = 10e9

@@ -76,10 +76,9 @@ def attention_impl():
 
 
 def rmsnorm_impl():
-    """Fused RMSNorm(+residual) kernel — OPT-IN (use_pallas_explicit): the
-    r5 on-chip measurement protocol (tools/op_bench_r5.py ->
-    OPBENCH_r05.json) decides the default; until a recorded win, the XLA
-    composition stays default (same honesty policy as the RNNT lattice)."""
+    """Fused RMSNorm(+residual) kernel — OPT-IN (use_pallas_explicit) and
+    never timed on the chip: the XLA composition is the default until a run
+    of ``mistral7b-train-2k`` says otherwise (ROADMAP Design 6)."""
     if use_pallas_explicit():
         from .rmsnorm import rmsnorm_residual_pallas
 
@@ -88,8 +87,8 @@ def rmsnorm_impl():
 
 
 def softmax_ce_impl():
-    """Streaming softmax-CE kernel — OPT-IN, same measured-default policy
-    as rmsnorm_impl."""
+    """Streaming softmax-CE kernel — OPT-IN and never timed on the chip,
+    like rmsnorm_impl (ROADMAP Design 6)."""
     if use_pallas_explicit():
         from .softmax_ce import softmax_ce_pallas
 

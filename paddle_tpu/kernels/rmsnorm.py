@@ -6,10 +6,9 @@ elementwise work. This kernel fuses the residual add, the rms reduction,
 and the normalize/scale into ONE VMEM pass per row block, with a fused
 backward (dx + per-block dw partials).
 
-Whether it actually beats XLA's fusion on chip is MEASURED, not assumed:
-tools/op_bench_r5.py times both paths in-jit and OPBENCH_r05.json records
-the decision; the kernel-policy default only selects this kernel where the
-measurement says it wins.
+Opt-in (``kernels.rmsnorm_impl``) and never timed on the chip: whether it
+beats XLA's fusion there is not known. One run of ``mistral7b-train-2k``
+decides between default and deletion (ROADMAP Design 6).
 """
 from __future__ import annotations
 

@@ -4,8 +4,8 @@ Candidate from the round-5 op-bench loop: XLA's log_softmax+gather keeps
 [N, V] residuals alive for the backward; this kernel saves only the per-row
 logsumexp ([N] floats) and recomputes the softmax block-wise in the fused
 backward (softmax - onehot), the FlashAttention trick applied to the LM
-loss. Selected by measurement (tools/op_bench_r5.py -> OPBENCH_r05.json),
-not by default.
+loss. Opt-in (``kernels.softmax_ce_impl``) and never timed on the chip
+(ROADMAP Design 6).
 """
 from __future__ import annotations
 

@@ -233,14 +233,3 @@ class LlamaForCausalLM(nn.Layer):
         c = self.config
         return [CacheLayer(c.num_key_value_heads, c.head_dim)
                 ] * c.num_hidden_layers
-
-    def num_params(self):
-        return sum(p.size for p in self.parameters())
-
-    def flops_per_token(self, seq_len=None):
-        """Model FLOPs per token (6N + attention term) for MFU accounting."""
-        c = self.config
-        n = self.num_params()
-        seq = seq_len or c.max_position_embeddings
-        attn = 12 * c.num_hidden_layers * c.hidden_size * seq
-        return 6 * n + attn

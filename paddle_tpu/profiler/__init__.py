@@ -9,10 +9,8 @@ TPU stance: device tracing is jax.profiler (XLA's TraceMe + TPU device
 traces, viewable in TensorBoard/Perfetto/xprof) — we wrap rather than rebuild
 the event collector; host annotations use jax.profiler.TraceAnnotation so
 they interleave with XLA's own events in the same trace. The Benchmark math
-(TimeAverager, ips) is host-side and implemented here directly, extended
-with the model-FLOPs/MFU counter BASELINE.md requires (the reference has no
-MFU notion; tokens/sec/chip × flops/token ÷ peak is the TPU north-star
-metric).
+(TimeAverager, ips) is host-side and implemented here directly. A share of
+the chip's peak is not taken here: ``benchmark/run.py`` takes it, on a chip.
 """
 from __future__ import annotations
 
@@ -26,8 +24,7 @@ from .. import telemetry
 __all__ = [
     "Profiler", "ProfilerTarget", "ProfilerState", "RecordEvent",
     "make_scheduler", "export_chrome_tracing", "Benchmark", "benchmark",
-    "TimeAverager", "transformer_flops_per_token", "peak_flops", "mfu",
-    "parse_trace_op_times", "format_op_table",
+    "TimeAverager", "parse_trace_op_times", "format_op_table",
 ]
 
 
@@ -471,40 +468,3 @@ _GLOBAL_BENCHMARK = Benchmark()
 def benchmark() -> Benchmark:
     """Global instance (reference timer.py benchmark())."""
     return _GLOBAL_BENCHMARK
-
-
-# ---------------------------------------------------------------------------
-# MFU accounting (beyond-reference; BASELINE.md north-star metric)
-# ---------------------------------------------------------------------------
-
-# peak dense bf16 FLOP/s per chip, keyed by ``jax.Device.device_kind``;
-# "cpu" is a placeholder so host-only runs keep the shape of the number
-_PEAK_FLOPS = {
-    "TPU v5 lite": 197e12,   # v5e, Google Cloud "TPU v5e" documentation
-    "cpu": 1e12,
-}
-
-
-def peak_flops(device_kind: str | None = None) -> float:
-    """Peak FLOP/s of one device of ``device_kind`` (default: this
-    process's first device). A device the table does not know is an error,
-    not a default."""
-    if device_kind is None:
-        device_kind = jax.devices()[0].device_kind
-    if device_kind not in _PEAK_FLOPS:
-        raise ValueError(
-            f"no peak FLOP/s recorded for device kind {device_kind!r}; add "
-            f"it to profiler._PEAK_FLOPS with its source")
-    return _PEAK_FLOPS[device_kind]
-
-
-def transformer_flops_per_token(n_params: int, n_layers: int, hidden: int,
-                                seq_len: int) -> float:
-    """6N weight flops + 12·L·H·S attention flops per trained token (the
-    standard PaLM-appendix accounting; matches bench.py round 1)."""
-    return 6.0 * n_params + 12.0 * n_layers * hidden * seq_len
-
-
-def mfu(tokens_per_sec: float, flops_per_token: float,
-        device_kind: str | None = None) -> float:
-    return tokens_per_sec * flops_per_token / peak_flops(device_kind)

@@ -56,7 +56,7 @@ output index) and cached K/V is exactly what a full prefill would
 recompute.
 
 ``naive_generate`` is the uncached baseline (full re-prefill every step)
-used by the parity tests and ``tools/serving_bench.py``.
+used by the parity tests.
 """
 from __future__ import annotations
 
@@ -148,11 +148,6 @@ def _engine_metrics(label: str) -> SimpleNamespace:
                      "peak live KV blocks this run"),
         utilization=G("serving_cache_utilization",
                       "live / usable KV block fraction"),
-        roofline=reg.gauge(
-            "serving_roofline_frac",
-            "achieved fraction of the roofline-model step time "
-            "(rolling mean per engine and step kind)",
-            ("engine", "kind")),
         ttft=H("serving_ttft_seconds",
                "request arrival to first emitted token"),
         tpot=H("serving_tpot_seconds",
@@ -304,8 +299,8 @@ class LLMEngine:
         self._donate = (2,)
 
         # roofline cost model (telemetry.cost): each new trace is walked
-        # for FLOPs/HBM bytes at creation (jaxpr only, no extra compile);
-        # per-step achieved-fraction-of-roofline feeds stats()["perf"].
+        # for FLOPs/HBM bytes at creation (jaxpr only, no extra compile):
+        # counts for stats()["perf"]["roofline"] and for charging tenants.
         # The fingerprint keys the process-global cost registry so
         # identical engines (fleet replicas, tests) share one estimate.
         self._cost_fp = (
@@ -315,7 +310,6 @@ class LLMEngine:
             str(kv_dtype))
         self._suspend_trace_counts = False  # cost tracing must not count
         self._trace_costs: dict[tuple, dict] = {}   # (kind, bucket) -> est
-        self._roofline_fracs: dict[str, list] = {"prefill": [], "decode": []}
 
         # performance observability (telemetry.perf): compile watching on
         # the bucketed prefill/decode traces, per-tag memory accounting,
@@ -657,22 +651,6 @@ class LLMEngine:
             self._trace_costs[(kind, bucket)] = est
         return est
 
-    def _note_roofline(self, kind: str, bucket: str, wall_s: float):
-        """One steady-state step's achieved fraction of the roofline-model
-        time (compile steps are excluded by the callers)."""
-        est = self._trace_costs.get((kind, bucket))
-        if est is None or not wall_s or not telemetry.enabled():
-            return
-        frac = telemetry.cost.achieved_fraction(est, wall_s)
-        if frac is None:
-            return
-        fracs = self._roofline_fracs[kind]
-        fracs.append(frac)
-        if len(fracs) > 256:
-            del fracs[:len(fracs) - 256]
-        self._m.roofline.labels(engine=self.engine_label, kind=kind).set(
-            sum(fracs) / len(fracs))
-
     def _charge_tenant(self, tenant: str, kind: str, bucket: str,
                        share: float = 1.0):
         """Attribute one executed step's roofline-modeled cost to a tenant:
@@ -686,29 +664,20 @@ class LLMEngine:
             tenant, est["flops"] * share, est["bytes"] * share)
 
     def _roofline_block(self) -> dict:
-        """stats()["perf"]["roofline"]: per-kind modeled cost + achieved
-        fraction — the serving analogue of the training MFU headline."""
-        out = {"peaks": telemetry.cost.platform_peaks()}
+        """stats()["perf"]["roofline"]: each trace's modeled FLOPs and HBM
+        bytes. Counts only: a share of the chip's peak is the benchmark's
+        to take, from the device trace."""
+        out = {}
         for kind in ("prefill", "decode"):
-            buckets = {b: e for (k, b), e in self._trace_costs.items()
-                       if k == kind}
-            fracs = self._roofline_fracs[kind]
-            entry = {
-                "buckets": {
-                    b: {"flops": e["flops"], "bytes": e["bytes"],
-                        "arithmetic_intensity":
-                            round(e["arithmetic_intensity"], 3)}
-                    for b, e in sorted(buckets.items())},
-                "achieved_frac_mean": (sum(fracs) / len(fracs)
-                                       if fracs else None),
-                "achieved_frac_last": fracs[-1] if fracs else None,
-                "samples": len(fracs),
-            }
-            out[kind] = entry
+            out[kind] = {"buckets": {
+                b: {"flops": e["flops"], "bytes": e["bytes"],
+                    "arithmetic_intensity":
+                        round(e["arithmetic_intensity"], 3)}
+                for (k, b), e in sorted(self._trace_costs.items())
+                if k == kind}}
         dec = self._trace_costs.get(("decode", "decode"))
         out["decode_ai"] = (round(dec["arithmetic_intensity"], 3)
                             if dec else None)
-        out["serving_roofline_frac"] = out["decode"]["achieved_frac_mean"]
         return out
 
     def _mean_ttft_direct(self):
@@ -1133,8 +1102,6 @@ class LLMEngine:
             self._watcher.record_call(
                 "engine.prefill", signature,
                 wall_s=wall if new_trace else None, cost=cost_est)
-            if not new_trace:
-                self._note_roofline("prefill", bucket, wall)
             for name, v in counters.items():
                 self._prefill_counters.setdefault(
                     name, deque(maxlen=128)).append(v)
@@ -1301,7 +1268,7 @@ class LLMEngine:
                     limit_s=self.watchdog_timeout_s)
             if not done:
                 self._account_decode(marks, len(running), live_share,
-                                     new_trace, cost_est, None, done=False)
+                                     new_trace, cost_est, None)
         with telemetry.span("engine.emit"):
             for slot, req in running.items():
                 self._emit(slot, req, int(toks[slot]))
@@ -1309,11 +1276,11 @@ class LLMEngine:
         return marks, len(running), live_share, new_trace, cost_est, counters
 
     def _account_decode(self, marks, n_running, live_share, new_trace,
-                        cost_est, counters, done=True):
+                        cost_est, counters):
         """Book one decode step's time once it is known: its phases,
         occupancy, live share of the block tables and the model's own
-        counters into the StepTimeline, the call into the compile watcher
-        and, for a step that ran to its end, its roofline fraction."""
+        counters into the StepTimeline, and the call into the compile
+        watcher."""
         phases = {ph: t1 - t0 for ph, t0, t1 in
                   zip(self._DECODE_PHASES, marks, marks[1:])}
         self._decode_tl.record_step(marks[-1] - marks[0], phases,
@@ -1325,8 +1292,6 @@ class LLMEngine:
             (("tokens", (self.max_slots,), "int32"),
              ("block_tables", (self.max_slots, self.max_blocks), "int32")),
             wall_s=self.last_decode_s if new_trace else None, cost=cost_est)
-        if done and not new_trace:
-            self._note_roofline("decode", "decode", self.last_decode_s)
 
     def _emit(self, slot: int, req: Request, token: int):
         req.emit(token)

@@ -63,7 +63,7 @@ attribution, and ``GET /stats`` gains ``tenancy`` (registry + admission
 counts) and, when an ``autoscaler=`` is attached, ``autoscaler`` blocks.
 
 The server runs on a daemon thread with its own event loop so synchronous
-tools (``tools/serving_bench.py --fleet``, the chaos suite, tests) can
+tools (``benchmark/run.py``, the chaos suite, tests) can
 ``start()``/``stop()`` it around plain-socket clients. Chaos sites:
 ``gateway.request`` fires per parsed request (an injected error answers
 500 — the connection layer survives); ``gateway.auth`` fires per tenant

@@ -2166,8 +2166,7 @@ class FleetRouter:
                     "slo": (rep.stats or {}).get("slo"),
                     # per-replica prefix-cache block straight off the
                     # heartbeat: the fleet-wide hit-rate / occupancy
-                    # view serving_bench --fleet and cluster_status
-                    # --kv aggregate
+                    # view cluster_status --kv aggregates
                     "prefix_cache": (rep.stats or {}).get("prefix_cache"),
                     "stats": {k: v for k, v in (rep.stats or {}).items()
                               if k not in ("slo", "prefix_cache")},

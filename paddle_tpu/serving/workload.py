@@ -36,8 +36,7 @@ callable that blocks until terminal and returns
 ``{"outcome": "ok"|"failed", "ttft": float|None, "tokens": int,
 "error": str|None}``. If ``submit`` itself raises, the runner records
 the request as shed (admission-control rejection — counted against
-goodput, never "lost"). ``tools/serving_bench.py --workload`` adapts
-this onto :meth:`FleetRouter.submit`; the soak harness
+goodput, never "lost"). The soak harness
 (:mod:`paddle_tpu.serving.soak`) adapts it onto gateway HTTP/SSE.
 
 docs/WORKLOADS.md documents the spec schema, the arrival-process math,

@@ -35,8 +35,7 @@ And the performance layer (PR 9):
   signature diffs), where did the memory go (``MemoryMonitor`` per-tag
   live/peak accounting, peak attribution, leak sentinel), and which phase
   got slower (``StepTimeline`` per-phase percentiles + regression
-  culprit naming); ``tools/perf_gate.py`` enforces the bench trajectory
-  against ``BASELINE.json``.
+  culprit naming).
 
 And the ops plane (PR 19) — the detect half of detect→page→diagnose:
 
@@ -56,8 +55,8 @@ And the ops plane (PR 19) — the detect half of detect→page→diagnose:
   view.
 
 :func:`disable` flips one shared flag that every write path checks first —
-the guaranteed-cheap escape hatch for benchmarking the instrumentation
-itself (``tools/serving_bench.py --telemetry off``).
+the guaranteed-cheap escape hatch for measuring what the instrumentation
+itself costs.
 """
 from .metrics import (  # noqa: F401
     Counter,

@@ -1,10 +1,7 @@
 """Roofline cost model: FLOPs + HBM bytes per compiled trace.
 
-The ROADMAP's north-star is "as fast as the hardware allows" — a claim that
-is only checkable against a cost model. Training has had an MFU number
-since round 1 (``profiler.mfu``); serving has never been attributed
-flop-by-flop. This module closes that gap with the same per-op cost
-discipline GSPMD uses to reason about partitioned programs:
+Counts of what a compiled serving trace asks of the chip, with the same
+per-op cost discipline GSPMD uses to reason about partitioned programs:
 
 - :func:`jaxpr_cost` walks a (closed) jaxpr and accumulates **FLOPs**
   (``dot_general`` exactly from its dimension numbers — every matmul and
@@ -26,19 +23,12 @@ discipline GSPMD uses to reason about partitioned programs:
   engines share one estimate) and publishes ``trace_flops`` /
   ``trace_bytes`` / ``trace_arithmetic_intensity`` gauges.
 - :func:`platform_peaks` + :func:`roofline_time_s` turn an estimate into
-  the roofline-model lower bound on step wall time,
-  ``max(flops / peak_flops, bytes / peak_bw)``; the engine divides it by
-  the measured step time into an achieved-fraction-of-roofline gauge
-  (``serving_roofline_frac``) — the serving analogue of MFU.
-
-Peaks default per platform (same public-spec numbers as ``profiler``'s MFU
-accounting; CPU values are placeholders for shape, not truth) and are
-overridable with ``$PADDLE_TPU_PEAK_FLOPS`` / ``$PADDLE_TPU_PEAK_BW``.
+  the roofline model's time for it, ``max(flops / peak_flops, bytes /
+  peak_bw)``: what ``serving/tenancy.py`` prices a tenant's FLOPs and bytes
+  by. No share of a peak is taken here: a time, a rate or a share comes
+  from ``benchmark/run.py`` on a chip.
 """
 from __future__ import annotations
-
-import os
-import threading
 
 import numpy as np
 
@@ -48,7 +38,7 @@ from ..analysis import locksan
 __all__ = [
     "jaxpr_cost", "estimate_fn_cost", "xla_cost_analysis",
     "register_trace", "lookup", "traces", "clear",
-    "platform_peaks", "roofline_time_s", "achieved_fraction",
+    "platform_peaks", "roofline_time_s",
 ]
 
 # primitives that move/reshape data but compute nothing (counted as zero
@@ -270,10 +260,11 @@ def clear():
 # roofline
 # ---------------------------------------------------------------------------
 
-# peak dense flop/s (same public-spec table as profiler.peak_flops) and
-# peak HBM bandwidth bytes/s per chip, keyed by ``jax.Device.device_kind``;
-# the CPU entry is a placeholder that gives the *shape* of the number on
-# dev hosts, not truth
+# peak dense flop/s and peak HBM bandwidth bytes/s per chip, keyed by
+# ``jax.Device.device_kind``: the package's one table. The CPU entry is a
+# placeholder that lets tenancy price requests on dev hosts, not truth.
+# ``benchmark/lib/peaks.py`` keeps its own table on purpose: a yardstick
+# does not import the constants of what it measures.
 _PEAKS = {
     # v5e, Google Cloud "TPU v5e" documentation: 197 bf16 TFLOP/s, 819 GB/s
     "TPU v5 lite": (197e12, 819e9),
@@ -283,9 +274,8 @@ _PEAKS = {
 
 def platform_peaks(device_kind: str | None = None) -> dict:
     """{device_kind, flops_per_s, bytes_per_s} of one device (default: this
-    process's first); ``$PADDLE_TPU_PEAK_FLOPS`` / ``$PADDLE_TPU_PEAK_BW``
-    override (bench hosts vary wildly). A device kind the table does not
-    know is an error, not a default."""
+    process's first). A device kind the table does not know is an error,
+    not a default."""
     if device_kind is None:
         import jax
 
@@ -295,11 +285,6 @@ def platform_peaks(device_kind: str | None = None) -> dict:
             f"no peaks recorded for device kind {device_kind!r}; add it to "
             f"telemetry.cost._PEAKS with its source")
     flops, bw = _PEAKS[device_kind]
-    try:
-        flops = float(os.environ.get("PADDLE_TPU_PEAK_FLOPS") or flops)
-        bw = float(os.environ.get("PADDLE_TPU_PEAK_BW") or bw)
-    except ValueError:
-        pass
     return {"device_kind": device_kind, "flops_per_s": flops,
             "bytes_per_s": bw}
 
@@ -310,12 +295,3 @@ def roofline_time_s(cost: dict, peaks: dict | None = None) -> float:
     peaks = peaks or platform_peaks()
     return max(cost.get("flops", 0) / peaks["flops_per_s"],
                cost.get("bytes", 0) / peaks["bytes_per_s"])
-
-
-def achieved_fraction(cost: dict, wall_s: float,
-                      peaks: dict | None = None) -> float | None:
-    """roofline_time / measured wall — 1.0 means the step ran as fast as
-    the roofline model says the hardware allows."""
-    if not wall_s or wall_s <= 0:
-        return None
-    return roofline_time_s(cost, peaks) / float(wall_s)

@@ -31,8 +31,7 @@ is that axis, kept deliberately small:
 
 Sampling overhead is self-measured and exported (``history_overhead_frac``:
 sampler busy-time over elapsed time) so the cost of observing is itself
-observable — and gated by ``tools/perf_gate.py``
-(``history_sampler_overhead_frac``).
+observable.
 """
 from __future__ import annotations
 

@@ -13,14 +13,13 @@ Two export formats, both side-effect free snapshots of live state:
 - :meth:`MetricsRegistry.prometheus_text` — the Prometheus text exposition
   format (``# HELP`` / ``# TYPE`` / ``name{label="v"} value``, histogram
   ``_bucket{le=...}`` / ``_sum`` / ``_count`` series), scrapeable as-is.
-- :meth:`MetricsRegistry.snapshot` — a JSON-able dict written next to bench
-  artifacts (``--metrics-out``) and pretty-printed by
+- :meth:`MetricsRegistry.snapshot` — a JSON-able dict
+  (``snapshot_json(path)`` writes it) pretty-printed by
   ``tools/metrics_dump.py``.
 
 ``telemetry.disable()`` flips the shared :data:`ENABLED` flag: every write
-method returns after one list-index check, which is what keeps a
-registry-disabled serving run within noise of an instrumented one
-(ISSUE 4 acceptance: <= 3% overhead with telemetry *enabled*).
+method returns after one list-index check, so a registry-disabled run
+pays next to nothing for the instrumentation.
 """
 from __future__ import annotations
 

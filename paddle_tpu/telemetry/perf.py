@@ -39,15 +39,10 @@ One process-global instance of each (:func:`compile_watcher`,
 :func:`memory_monitor`, :func:`step_timeline`), published through the
 metrics registry so the cluster aggregator and ``tools/cluster_status.py``
 show fleet-wide recompile storms and memory watermarks per rank.
-``tools/perf_gate.py`` turns bench JSONs stamped with :func:`run_meta`
-into an enforced perf trajectory against ``BASELINE.json``.
 """
 from __future__ import annotations
 
 import os
-import socket
-import subprocess
-import sys
 import threading
 import time
 from collections import OrderedDict, deque
@@ -61,7 +56,7 @@ __all__ = [
     "CompileWatcher", "MemoryMonitor", "StepTimeline",
     "compile_watcher", "memory_monitor", "step_timeline",
     "abstract_signature", "explain_recompile", "note_phase",
-    "watch_dispatch", "arm_jax_monitoring", "run_meta", "reset",
+    "watch_dispatch", "arm_jax_monitoring", "reset",
 ]
 
 # compile wall times: traces are 10ms..minutes, not sub-ms
@@ -852,31 +847,6 @@ def watch_dispatch(enable: bool = True):
         _dispatch._perf_watch = _hook
     else:
         _dispatch._perf_watch = None
-
-
-def run_meta() -> dict:
-    """The ``__meta__`` stamp bench artifacts carry so ``perf_gate`` can
-    refuse cross-platform comparisons: git sha, jax version, platform,
-    host, wall time."""
-    meta = {"wall_time": time.time(),
-            "python": sys.version.split()[0],
-            "host": socket.gethostname(),
-            "pid": os.getpid()}
-    try:
-        import jax
-        meta["jax_version"] = jax.__version__
-        meta["platform"] = jax.devices()[0].platform
-    except Exception:  # lint: allow-silent(absence is recorded as None in the report)
-        meta["jax_version"] = meta["platform"] = None
-    try:
-        repo = os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        meta["git_sha"] = subprocess.run(
-            ["git", "rev-parse", "--short", "HEAD"], cwd=repo, timeout=5,
-            capture_output=True, text=True).stdout.strip() or None
-    except Exception:  # lint: allow-silent(absence is recorded as None in the report)
-        meta["git_sha"] = None
-    return meta
 
 
 def reset():

@@ -16,8 +16,7 @@ Two export formats, both dependency-free: folded flamegraph lines
 and speedscope JSON (:meth:`SamplingProfiler.speedscope` — drag onto
 https://speedscope.app). The sampler's own cost is self-measured and
 exported (``pyprof_overhead_frac``: sampling busy-time over elapsed
-time) and gated end to end by ``tools/perf_gate.py``
-(``profiler_overhead_frac``: serving throughput profiler-off vs -on).
+time).
 
 Fleet view: when a profiler is :func:`install`-ed, the cluster
 ``RankPublisher`` ships its folded top-N with every heartbeat and

@@ -1,9 +1,9 @@
 """The one place that turns on JAX's persistent compilation cache.
 
-Every entry point that compiles (``chip_smoke.py``, ``bench.py``, the test
-session, the replica and gateway workers) calls :func:`enable` before its
-first compile, so processes that build the same traces share one cache and
-only the first of them pays XLA.
+Every entry point that compiles (``chip_smoke.py``, ``benchmark/run.py``,
+the test session, the replica and gateway workers) calls :func:`enable`
+before its first compile, so processes that build the same traces share one
+cache and only the first of them pays XLA.
 
 The directory is part of what a hit depends on, so it never moves: where
 ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads the variable itself and no

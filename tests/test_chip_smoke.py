@@ -96,14 +96,3 @@ def test_leg_result_is_the_marked_line():
     text = "noise\n" + chip_smoke.RESULT_MARK + json.dumps({"ok": True}) + "\n"
     assert chip_smoke._leg_result(text) == {"ok": True}
     assert chip_smoke._leg_result("no result here\n{}") is None
-
-
-def test_bench_is_one_process_and_refuses_the_cpu():
-    """bench.py: a TPU or an error, and nothing that starts a child."""
-    src = open(os.path.join(REPO, "bench.py")).read()
-    assert "subprocess" not in src and "--smoke" not in src
-    r = subprocess.run([sys.executable, "bench.py"], cwd=REPO,
-                       env=dict(os.environ, JAX_PLATFORMS="cpu"),
-                       capture_output=True, text=True, timeout=300)
-    assert r.returncode != 0 and "measures a TPU" in r.stderr, r.stderr[-2000:]
-    assert not [l for l in r.stdout.splitlines() if l.startswith("{")]

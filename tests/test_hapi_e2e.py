@@ -1,6 +1,7 @@
 """M1 end-to-end: MNIST via Model.fit (BASELINE config #1; call-stack parity
 with /root/reference SURVEY §3.3). Uses a small MLP to keep XLA:CPU compile
-time CI-friendly; the full LeNet config is exercised by bench.py/verify."""
+time CI-friendly; the full LeNet config is the verify skill's canonical
+drive (.claude/skills/verify/SKILL.md)."""
 import numpy as np
 
 import paddle_tpu as paddle

@@ -7,8 +7,8 @@ use_shared_memory transport.
 NOTE on scaling: this sandbox exposes ONE cpu core (os.sched_getaffinity),
 so a >2x wall-clock scaling assertion is physically impossible here; these
 tests prove process-ness, ordering, worker_info, error propagation and
-shared-memory transport instead. tools/io_bench.py measures the scaling
-curve on real multi-core hosts.
+shared-memory transport instead. The scaling curve on a multi-core host
+has not been measured.
 """
 import os
 import time

@@ -1,7 +1,7 @@
 """Round-5 op-bench kernels (VERDICT r4 next #5): fused RMSNorm(+residual)
 and streaming softmax-CE — interpret-mode parity vs the XLA compositions.
-On-chip win/loss measurements live in tools/op_bench_r5.py ->
-OPBENCH_r05.json; these tests gate correctness only."""
+Neither kernel has been timed on the chip (ROADMAP Design 6); these tests
+gate correctness only."""
 import numpy as np
 
 import jax

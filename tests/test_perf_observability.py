@@ -1,13 +1,12 @@
 """Performance-observability layer (ISSUE 9): recompilation watcher with
 signature-diff explanations, per-tag memory accounting + leak sentinel,
 step-time phase attribution with regression naming, the static-Executor
-cache counters, and the perf regression gate.
+cache counters.
 
 Everything here is deliberately cheap: the only jitted work is one tiny
 static program and one tiny engine fleet (the heavyweight end-to-end
 proof lives in ``tools/chaos_run.py --suite perf``).
 """
-import json
 import os
 import sys
 
@@ -25,8 +24,6 @@ pytestmark = pytest.mark.telemetry
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-
-from tools import perf_gate  # noqa: E402
 
 
 def _sig(shape, name="tokens", dtype="int32"):
@@ -385,124 +382,10 @@ class TestEnginePerf:
 
 
 # ---------------------------------------------------------------------------
-# perf_gate
+# tools/metrics_dump.py
 # ---------------------------------------------------------------------------
 
-def _serving_result(ttft=0.05, tok_s=120.0, platform="cpu"):
-    return {
-        "engine_tok_per_sec": tok_s, "speedup": 9.0, "mean_ttft": ttft,
-        "slo": {"ttft": {"p99": 2 * ttft}, "tpot": {"p99": 0.004}},
-        "__meta__": {"platform": platform, "git_sha": "cafe12",
-                     "jax_version": "0.0", "wall_time": 1.0},
-    }
-
-
-class TestPerfGate:
-    def _write(self, tmp_path, name, doc):
-        p = str(tmp_path / name)
-        with open(p, "w") as f:
-            json.dump(doc, f)
-        return p
-
-    def test_seed_then_pass_then_catch_regression(self, tmp_path, capsys):
-        base = str(tmp_path / "BASELINE.json")
-        good = self._write(tmp_path, "good.json", _serving_result())
-        # no baseline yet: refuses to vacuously pass
-        assert perf_gate.main([good, "--baseline", base]) == 3
-        assert perf_gate.main([good, "--baseline", base,
-                               "--update-baseline"]) == 0
-        # unchanged re-run passes
-        assert perf_gate.main([good, "--baseline", base]) == 0
-        # injected 20% TTFT regression: nonzero exit, metric named
-        bad = self._write(tmp_path, "bad.json",
-                          _serving_result(ttft=0.06))
-        capsys.readouterr()
-        assert perf_gate.main([bad, "--baseline", base]) == 1
-        out = capsys.readouterr().out
-        assert "mean_ttft_s" in out and "REGRESSED" in out
-
-    def test_spill_prefix_result_is_its_own_bench_kind(self):
-        # the --kv-spill-blocks variant measures eviction recovery, not
-        # the plain cache-warm path: it must not cross-gate with the
-        # serving_prefix baseline
-        plain = {"mode": "prefix",
-                 "prefix": {"ttft_warm_on_s": 0.01, "ttft_speedup": 2.5,
-                            "tok_per_sec_on": 900.0, "hit_rate": 1.0}}
-        kind, metrics = perf_gate.extract_metrics(plain)
-        assert kind == "serving_prefix"
-        spilled = {"mode": "prefix",
-                   "prefix": {"hit_rate": 1.0,
-                              "spill": {"ttft_warm_spill_s": 0.02,
-                                        "ttft_speedup_vs_off": 4.0,
-                                        "tok_per_sec_spill": 800.0}}}
-        kind, metrics = perf_gate.extract_metrics(spilled)
-        assert kind == "serving_prefix_spill"
-        assert metrics == {"prefix_spill_ttft_warm_s": 0.02,
-                           "prefix_spill_ttft_speedup": 4.0,
-                           "prefix_spill_tok_per_sec": 800.0}
-        for name in metrics:
-            assert name in perf_gate.DIRECTIONS
-
-    def test_within_tolerance_noise_accepted(self, tmp_path):
-        base = str(tmp_path / "BASELINE.json")
-        good = self._write(tmp_path, "good.json", _serving_result())
-        perf_gate.main([good, "--baseline", base, "--update-baseline"])
-        noisy = self._write(
-            tmp_path, "noisy.json",
-            _serving_result(ttft=0.055, tok_s=110.0))     # ±10%: noise
-        assert perf_gate.main([noisy, "--baseline", base]) == 0
-
-    def test_cross_platform_refused(self, tmp_path, capsys):
-        base = str(tmp_path / "BASELINE.json")
-        cpu = self._write(tmp_path, "cpu.json", _serving_result())
-        perf_gate.main([cpu, "--baseline", base, "--update-baseline"])
-        tpu = self._write(tmp_path, "tpu.json",
-                          _serving_result(platform="tpu"))
-        assert perf_gate.main([tpu, "--baseline", base]) == 2
-        assert perf_gate.main([tpu, "--baseline", base,
-                               "--allow-cross-platform"]) == 0
-        capsys.readouterr()
-
-    def test_update_preserves_existing_baseline_keys(self, tmp_path):
-        base = str(tmp_path / "BASELINE.json")
-        with open(base, "w") as f:
-            json.dump({"north_star": "keep me", "configs": [1, 2]}, f)
-        good = self._write(tmp_path, "good.json", _serving_result())
-        assert perf_gate.main([good, "--baseline", base,
-                               "--update-baseline"]) == 0
-        doc = json.load(open(base))
-        assert doc["north_star"] == "keep me" and doc["configs"] == [1, 2]
-        assert "serving" in doc["perf"]
-
-    def test_train_bench_kind(self, tmp_path):
-        base = str(tmp_path / "BASELINE.json")
-        train = self._write(tmp_path, "train.json", {
-            "metric": "llama_train_tokens_per_sec_per_chip",
-            "value": 33000.0, "extra": {"mfu": 0.58},
-            "__meta__": {"platform": "tpu"}})
-        perf_gate.main([train, "--baseline", base, "--update-baseline"])
-        slower = self._write(tmp_path, "slower.json", {
-            "metric": "llama_train_tokens_per_sec_per_chip",
-            "value": 24000.0, "extra": {"mfu": 0.42},
-            "__meta__": {"platform": "tpu"}})
-        assert perf_gate.main([slower, "--baseline", base]) == 1
-
-    def test_prefix_bench_kind(self, tmp_path):
-        base = str(tmp_path / "BASELINE.json")
-        doc = {"mode": "prefix",
-               "prefix": {"ttft_warm_on_s": 0.1, "ttft_speedup": 2.7,
-                          "tok_per_sec_on": 50.0, "hit_rate": 0.9},
-               "__meta__": {"platform": "cpu"}}
-        p = self._write(tmp_path, "prefix.json", doc)
-        assert perf_gate.main([p, "--baseline", base,
-                               "--update-baseline"]) == 0
-        slow = dict(doc, prefix=dict(doc["prefix"], ttft_warm_on_s=0.2,
-                                     ttft_speedup=1.3))
-        ps = self._write(tmp_path, "prefix_slow.json", slow)
-        assert perf_gate.main([ps, "--baseline", base]) == 1
-        b = json.load(open(base))
-        assert "serving_prefix" in b["perf"]
-
+class TestMetricsDump:
     def test_gauge_diff_shows_delta(self, tmp_path):
         from tools.metrics_dump import format_diff
         a = {"__meta__": {"wall_time": 0.0},

@@ -176,13 +176,21 @@ class TestClocksEndAtTheResult:
         assert tl["step_s"]["p99"] >= 0.05
 
     def test_prefill_wall_ends_at_the_first_token(self, monkeypatch):
+        """A bucket's first trace hands its wall time to the compile
+        watcher, and every prefill's wait is the ``engine.prefill_wait``
+        span: both hold the first token's readback, which a clock that
+        ended at dispatch would leave out."""
         eng = _engine()
-        eng.generate([[1, 2, 3]], SamplingParams(max_new_tokens=2))
         walls = []
-        monkeypatch.setattr(
-            eng, "_note_roofline",
-            lambda kind, bucket, wall_s: walls.append((kind, wall_s)))
-        real = eng._prefill_fns[8]
+        record = eng._watcher.record_call
+
+        def record_call(name, signature, wall_s=None, cost=None):
+            if name == "engine.prefill":
+                walls.append(wall_s)
+            return record(name, signature, wall_s=wall_s, cost=cost)
+
+        monkeypatch.setattr(eng._watcher, "record_call", record_call)
+        get_fn = eng._get_prefill_fn
 
         class LateTok:
             def __init__(self, tok):
@@ -192,14 +200,28 @@ class TestClocksEndAtTheResult:
                 time.sleep(0.04)
                 return int(self.tok)
 
-        def late(*args):
-            tok, pool, counters = real(*args)
-            return LateTok(tok), pool, counters
+        def get_late_fn(P):
+            real = get_fn(P)
 
-        eng._prefill_fns[8] = late
-        eng.generate([[4, 5, 6]], SamplingParams(max_new_tokens=1))
-        assert [k for k, _ in walls] == ["prefill"]
-        assert walls[0][1] >= 0.04
+            def late(*args):
+                tok, pool, counters = real(*args)
+                return LateTok(tok), pool, counters
+            return late
+
+        monkeypatch.setattr(eng, "_get_prefill_fn", get_late_fn)
+        tr = telemetry.tracer()
+        dispatches = []
+        for prompt in ([1, 2, 3], [4, 5, 6]):   # first trace, then steady
+            tr.clear()
+            eng.generate([prompt], SamplingParams(max_new_tokens=1))
+            (dispatch,), (wait,) = (tr.find("engine.prefill"),
+                                    tr.find("engine.prefill_wait"))
+            assert wait.duration >= 0.04
+            dispatches.append(dispatch.duration)
+        first, steady = walls
+        # the first trace's wall: its dispatch (the compile) and the wait
+        assert first >= dispatches[0] + 0.04
+        assert steady is None       # a warm bucket books no compile time
 
 
 class TestFrontDoor:

@@ -1,12 +1,10 @@
 """Trace-driven workload engine (paddle_tpu.serving.workload) + the
-capacity planner's pure math (tools/capacity_plan.py) + the perf gate's
-workload bench kind (tools/perf_gate.py).
+capacity planner's pure math (tools/capacity_plan.py).
 
 The acceptance contract under test: a (spec, seed) pair replays to a
 byte-identical schedule — same fingerprint, same request stream — so a
 soak or bench regression is reproducible from its JSON artifact alone.
 """
-import json
 import os
 import sys
 import threading
@@ -22,7 +20,7 @@ from paddle_tpu.serving.workload import (
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from tools import capacity_plan, perf_gate  # noqa: E402
+from tools import capacity_plan  # noqa: E402
 
 pytestmark = pytest.mark.soak
 
@@ -296,55 +294,3 @@ class TestCapacityPlanner:
             qps=0.001, mean_out=1.0, slo_ttft_s=None, slo_tpot_s=None,
             tok_per_sec=1e6)
         assert p["replicas"] == 1
-
-
-# ---------------------------------------------------------------------------
-# perf gate: workload bench kind + regression exit
-
-def _bench_doc(**workload):
-    w = dict(spec="burst", workload_tok_per_sec=100.0, ttft_p99_s=1.0,
-             p99_under_burst=1.2, goodput_under_overload=0.5,
-             time_to_healthy_under_burst_s=3.0)
-    w.update(workload)
-    return {"mode": "workload", "workload": w,
-            "__meta__": {"platform": "cpu", "git_sha": "test",
-                         "jax": "0"}}
-
-
-class TestPerfGateWorkloadKind:
-    def test_extract_metrics_workload(self):
-        kind, metrics = perf_gate.extract_metrics(_bench_doc())
-        assert kind == "serving_workload_burst"
-        assert metrics["p99_under_burst"] == pytest.approx(1.2)
-        assert metrics["goodput_under_overload"] == pytest.approx(0.5)
-        assert metrics["workload_tok_per_sec"] == pytest.approx(100.0)
-        assert metrics["time_to_healthy_under_burst_s"] == pytest.approx(3.0)
-
-    def test_gate_passes_then_fails_on_injected_regression(
-            self, tmp_path, capsys):
-        base = tmp_path / "BASELINE.json"
-        good = tmp_path / "good.json"
-        good.write_text(json.dumps(_bench_doc()))
-        assert perf_gate.main([str(good), "--baseline", str(base),
-                               "--update-baseline"]) == 0
-        assert perf_gate.main([str(good), "--baseline", str(base)]) == 0
-
-        bad = tmp_path / "bad.json"
-        bad.write_text(json.dumps(_bench_doc(p99_under_burst=2.4)))
-        rc = perf_gate.main([str(bad), "--baseline", str(base)])
-        out = capsys.readouterr()
-        assert rc == 1
-        assert "p99_under_burst" in out.out + out.err
-
-    def test_goodput_regression_names_metric(self, tmp_path, capsys):
-        base = tmp_path / "BASELINE.json"
-        good = tmp_path / "good.json"
-        good.write_text(json.dumps(_bench_doc()))
-        perf_gate.main([str(good), "--baseline", str(base),
-                        "--update-baseline"])
-        bad = tmp_path / "bad.json"
-        bad.write_text(json.dumps(_bench_doc(goodput_under_overload=0.2)))
-        rc = perf_gate.main([str(bad), "--baseline", str(base)])
-        out = capsys.readouterr()
-        assert rc == 1
-        assert "goodput_under_overload" in out.out + out.err

@@ -11,8 +11,8 @@ The model (docs/WORKLOADS.md "Capacity planner math"):
    tokens]`` (means taken from the generated workload itself, so
    truncation and heavy tails are priced in). A replica delivers
    ``T_rep`` tokens/s — measured by a short closed-loop calibration run
-   at full batch (or taken from a bench artifact) — derated by
-   ``--headroom``. ``N_tput = ceil(demand / (T_rep * headroom))``.
+   at full batch — derated by ``--headroom``.
+   ``N_tput = ceil(demand / (T_rep * headroom))``.
 2. **TPOT feasibility** — if calibrated TPOT exceeds the TPOT SLO at
    full batch, a replica must run smaller batches; ``T_rep`` is scaled
    by ``slo_tpot / tpot`` (decode on this engine is throughput-bound,
@@ -30,15 +30,12 @@ The model (docs/WORKLOADS.md "Capacity planner math"):
    traffic on hosts where throughput is shared (replicas add queue
    slots and failure domains, not FLOPs).
 
-``N = max`` of the four. Roofline peaks (``telemetry.cost``) bound the
-sanity check: calibrated ``T_rep`` is reported as a fraction of the
-roofline ceiling so an implausible calibration is visible.
+``N = max`` of the four.
 
 Usage:
 
     python tools/capacity_plan.py --spec burst --slo-ttft-ms 4000
     python tools/capacity_plan.py --spec steady --qps 12 --validate
-    python tools/capacity_plan.py --spec wl.json --measured BENCH.json
 
 ``--validate`` runs the harness at N = 1..``--max-replicas`` open-loop
 and reports the measured minimum fleet meeting the SLO (zero lost,
@@ -272,24 +269,6 @@ def calibrate(args, spec, slo) -> dict:
     }
 
 
-def measured_from_artifact(path: str) -> dict:
-    """Pull (tok_per_sec, ttft_base_s, tpot_s) out of a serving bench
-    JSON (single-engine or --workload artifact)."""
-    with open(path, "r", encoding="utf-8") as f:
-        doc = json.load(f)
-    if "engine_tok_per_sec" in doc:
-        slo = doc.get("slo") or {}
-        return {"tok_per_sec": doc["engine_tok_per_sec"],
-                "ttft_base_s": doc.get("mean_ttft") or 0.0,
-                "tpot_s": ((slo.get("tpot") or {}).get("p50"))}
-    if isinstance(doc.get("workload"), dict):
-        w = doc["workload"]
-        return {"tok_per_sec": w.get("workload_tok_per_sec"),
-                "ttft_base_s": w.get("ttft_p50_s") or 0.0,
-                "tpot_s": None}
-    raise SystemExit(f"{path}: not a recognizable bench artifact")
-
-
 def measure_requirement(args, spec, slo, time_scale) -> tuple:
     """Harness ground truth: smallest fleet (1..--max-replicas) whose
     open-loop replay meets the SLO — zero lost, zero shed, goodput >=
@@ -344,9 +323,6 @@ def main(argv=None) -> int:
                     help="override the spec's TTFT SLO")
     ap.add_argument("--slo-tpot-ms", type=float, default=None,
                     help="override the spec's TPOT SLO")
-    ap.add_argument("--measured", default=None, metavar="BENCH.json",
-                    help="take T_rep/TTFT/TPOT from this bench artifact "
-                         "instead of running a calibration fleet")
     ap.add_argument("--headroom", type=float, default=0.75,
                     help="derate measured per-replica throughput (burst "
                          "absorption + failure-domain slack)")
@@ -357,7 +333,7 @@ def main(argv=None) -> int:
     ap.add_argument("--max-replicas", type=int, default=4)
     ap.add_argument("--meet-goodput", type=float, default=0.85)
     ap.add_argument("--json", default=None)
-    # engine/model sizing (matches serving_bench --workload defaults)
+    # engine/model sizing of the --validate harness fleets (a CPU toy)
     ap.add_argument("--vocab", type=int, default=128)
     ap.add_argument("--hidden", type=int, default=64)
     ap.add_argument("--layers", type=int, default=2)
@@ -389,13 +365,8 @@ def main(argv=None) -> int:
     qps = (args.qps if args.qps is not None
            else wl.offered_qps / max(args.time_scale, 1e-9))
 
-    if args.measured:
-        measured = measured_from_artifact(args.measured)
-        measured["source"] = args.measured
-    else:
-        print("# calibrating (1-replica closed-loop)...", file=sys.stderr)
-        measured = calibrate(args, spec, slo)
-        measured["source"] = "calibration"
+    print("# calibrating (1-replica closed-loop)...", file=sys.stderr)
+    measured = calibrate(args, spec, slo)
 
     service_s = (measured["ttft_base_s"]
                  + (measured["tpot_s"] or 0.0) * max(mean_out - 1, 0))
@@ -409,13 +380,6 @@ def main(argv=None) -> int:
         admission_per_replica=args.slots + args.max_queue,
         peak_conc=peak, headroom=args.headroom,
         max_replicas=args.max_replicas * 4)
-    # roofline ceiling sanity: calibrated T_rep as a fraction of what
-    # the platform peaks say a decode step could ever deliver
-    try:
-        from paddle_tpu.telemetry.cost import platform_peaks
-        result["platform_peaks"] = platform_peaks()
-    except Exception as e:  # lint: allow-silent(peaks table has no entry for this host; error lands in the report)
-        result["platform_peaks"] = {"error": str(e)}
     doc = {
         "spec": spec.to_dict(),
         "qps": qps,

@@ -50,9 +50,10 @@ the churning ``tokens`` argument; the same churn under
 ``serving.compile:error`` + ``serving.kv.alloc:exhaust`` must degrade
 gracefully (targeted requests FAILED with errors attached, no block
 leak, storm still reported); the memory leak sentinel must flag a
-simulated block leak while a clean drain stays quiet; and an
-instrumentation-overhead ratio is measured (the precise instrument is
-``serving_bench --telemetry on|off``).
+simulated block leak while a clean drain stays quiet; and the same
+stable fleet is run with telemetry on and off (both must survive; the
+ratio of the two wall times is reported, a sanity bound and not a
+measurement).
 
 ``--suite serve-fleet`` — the production front door (docs/SERVING.md
 "Fleet serving"): a real gateway + FleetRouter over engine replica
@@ -126,9 +127,7 @@ seconds, (1) a ``serving.decode:delay`` fault on a live gateway fleet
 must trip the fast-burn SLO page within a bounded detection time, the
 page carrying an exemplar trace id and showing on ``/v1/alerts``, and
 recovery must resolve it; (2) a SIGKILL'd rank telemetry publisher must
-trip the publisher-absence page (the watchdog for the watchers); (3) the
-history sampler's and profiler's own overhead is measured A/B
-(``serving_bench --obs-overhead``) and held to the 3% bar by perf_gate.
+trip the publisher-absence page (the watchdog for the watchers).
 
 ``--suite heal`` — the self-healing control plane (docs/ROBUSTNESS.md
 "Self-healing & rollout"): the *act* half of detect→page→act on a real
@@ -162,8 +161,6 @@ Usage:
         [--requests 6] [--prompt-len 24] [--max-new 16]
         [--slots 3] [--block-size 8] [--plan NAME:SPEC ...] [--json OUT.json]
         [--list] [--scenario NAME]
-
-    python bench.py --chaos        # serving sweep, via bench's opt-in mode
 
 ``--list`` prints every suite's scenario names; ``--scenario NAME`` re-runs
 a single scenario of the chosen suite (the unit of re-run when one row of
@@ -773,8 +770,7 @@ def run_perf_suite(args):
         rows.append({
             "scenario": "overhead",
             # generous bound: jit compiles dominate this tiny fleet and a
-            # shared CI host is noisy; serving_bench --telemetry on|off is
-            # the precise overhead instrument
+            # shared CI host is noisy; a sanity check, not a measurement
             "survived": bool(crashed is None and crashed2 is None
                              and ratio is not None and ratio < 2.0),
             "enabled_sec": round(on_s, 4),
@@ -1332,9 +1328,9 @@ def _scenario_noisy_neighbor(args, workdir, spec, max_len):
         # per-tenant cost attribution vs the fleet total: every prompt in
         # phase 1 has the same length, so each engine ran exactly one
         # prefill bucket and the one decode bucket — bucket cost x execution
-        # count reconstructs the engine's whole roofline spend. samples
-        # counts steady-state steps only; the bucket's compile-step
-        # execution (real work, charged to its tenant) is the +1
+        # count reconstructs the engine's whole roofline spend. A request
+        # is prefilled once, and once more after each preemption; the
+        # engine's decode-step histogram counts every decode step
         attributed, modeled, single_bucket = 0.0, 0.0, True
         tenant_flops: dict[str, float] = {}
         for rep in reps:
@@ -1343,13 +1339,18 @@ def _scenario_noisy_neighbor(args, workdir, spec, max_len):
                 f = row["cost"]["flops"]
                 attributed += f
                 tenant_flops[name] = tenant_flops.get(name, 0.0) + f
+            executions = {
+                "prefill": st["num_preemptions"] + sum(
+                    row["requests"]
+                    for row in st["tenancy"]["tenants"].values()),
+                "decode": rep.engine._m.decode_step.count}
             for kind in ("prefill", "decode"):
                 entry = st["perf"]["roofline"][kind]
                 if len(entry["buckets"]) != 1:
                     single_bucket = False
                     continue
                 (est,) = entry["buckets"].values()
-                modeled += est["flops"] * (entry["samples"] + 1)
+                modeled += est["flops"] * executions[kind]
         cost_ok = (single_bucket and modeled > 0
                    and abs(attributed - modeled) / modeled <= 0.05)
 
@@ -2925,13 +2926,12 @@ def run_soak_suite(args, workdir=None, scenario=None):
 # ``--suite alerts`` (docs/OBSERVABILITY.md "Ops plane", ISSUE 19): prove
 # the detect half of detect→page→diagnose end to end, with the SRE burn
 # windows shrunk (``time_scale``) so real page timing runs in seconds.
-# Three scenarios: (1) a ``serving.decode:delay`` fault degrades TPOT past
+# Two scenarios: (1) a ``serving.decode:delay`` fault degrades TPOT past
 # the SLO on a live gateway fleet — the fast-burn window PAGES within a
 # bounded detection time, the page names an exemplar trace id, the
 # gateway's /v1/alerts shows it, and recovery resolves the alert; (2) a
 # SIGKILL'd rank publisher trips the publisher-absence rule (the watchdog
-# for the watchers); (3) the ops plane's own cost is measured A/B and
-# gated by perf_gate within the 3% acceptance bar.
+# for the watchers).
 
 def _alerts_exemplar_fn(router):
     """The page's exemplar: the trace id behind the worst replica's
@@ -3182,52 +3182,6 @@ def _scenario_publisher_absence(args, workdir, spec, max_len):
         store.close()
 
 
-def _scenario_overhead_gate(args, workdir, spec, max_len):
-    """The ops plane's own bill: A/B the history sampler and profiler
-    against a bare decode pass (``serving_bench --obs-overhead``) and
-    hold both overheads to the 3% acceptance bar via perf_gate. One
-    retry absorbs shared-host bench noise."""
-    import subprocess
-
-    artifact = os.path.join(workdir, "obs_overhead.json")
-    bench = [sys.executable, os.path.join(REPO_ROOT, "tools",
-                                          "serving_bench.py"),
-             "--obs-overhead", "--requests", "6", "--max-new", "48",
-             "--json", artifact]
-    gate = [sys.executable, os.path.join(REPO_ROOT, "tools",
-                                         "perf_gate.py"), artifact,
-            "--tolerance", "profiler_overhead_frac=0.03",
-            "--tolerance", "history_sampler_overhead_frac=0.03"]
-    attempts = []
-    for attempt in range(2):
-        b = subprocess.run(bench, capture_output=True, text=True,
-                           timeout=900, cwd=REPO_ROOT)
-        if b.returncode != 0:
-            attempts.append({"bench_rc": b.returncode,
-                             "tail": b.stderr[-500:]})
-            continue
-        with open(artifact) as f:
-            obs = json.load(f)["observability"]
-        g = subprocess.run(gate, capture_output=True, text=True,
-                           timeout=120, cwd=REPO_ROOT)
-        attempts.append({
-            "bench_rc": 0, "gate_rc": g.returncode,
-            "profiler_overhead_frac":
-                round(obs["profiler_overhead_frac"], 4),
-            "history_sampler_overhead_frac":
-                round(obs["history_sampler_overhead_frac"], 4),
-        })
-        if g.returncode == 0:
-            break
-    last = attempts[-1] if attempts else {}
-    return {
-        "scenario": "overhead_gate",
-        "survived": last.get("gate_rc") == 0,
-        "attempts": len(attempts),
-        **{k: v for k, v in last.items() if k != "bench_rc"},
-    }
-
-
 def run_alerts_suite(args, workdir=None, scenario=None):
     import tempfile
 
@@ -3236,8 +3190,8 @@ def run_alerts_suite(args, workdir=None, scenario=None):
     spec = _fleet_spec(args, workdir, max_len)
     rows = []
     fns = _filter_scenarios(
-        (_scenario_slo_burn_page, _scenario_publisher_absence,
-         _scenario_overhead_gate), "_scenario_", scenario)
+        (_scenario_slo_burn_page, _scenario_publisher_absence),
+        "_scenario_", scenario)
     for fn in fns:
         try:
             rows.append(fn(args, workdir, spec, max_len))
@@ -3742,8 +3696,7 @@ SUITE_SCENARIOS = {
     "locksan": lambda: ["fleet_under_load", "telemetry_threads",
                         "inversion_canary"],
     "soak": lambda: ["degrade", "rolling"],
-    "alerts": lambda: ["slo_burn_page", "publisher_absence",
-                       "overhead_gate"],
+    "alerts": lambda: ["slo_burn_page", "publisher_absence"],
     "heal": lambda: ["wedged_replica_heal", "flap_quarantine",
                      "canary_rollback"],
 }

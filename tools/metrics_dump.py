@@ -1,8 +1,7 @@
 """Pretty-print a telemetry registry snapshot JSON as tables, or diff two.
 
-The snapshot is what ``--metrics-out`` (bench.py / tools/serving_bench.py)
-and ``telemetry.registry().snapshot_json(path)`` write — this tool turns it
-into something eyeballable next to a BENCH_*.json artifact:
+The snapshot is what ``telemetry.registry().snapshot_json(path)`` writes —
+this tool turns it into something eyeballable:
 
     python tools/metrics_dump.py METRICS.json [--filter serving_]
     python tools/metrics_dump.py --diff A.json B.json [--filter store_]
@@ -373,7 +372,7 @@ def _load(path: str):
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("snapshot", nargs="?", default=None,
-                    help="registry snapshot JSON (--metrics-out)")
+                    help="registry snapshot JSON (snapshot_json)")
     ap.add_argument("--diff", nargs=2, metavar=("A", "B"), default=None,
                     help="print counter deltas and rates from snapshot A "
                          "to snapshot B instead of pretty-printing one")

@@ -2,10 +2,9 @@
 fleet under a rolling chaos plan, with pass criteria asserted
 continuously.
 
-Where ``chaos_run`` proves one failure mode per scenario and
-``serving_bench --workload`` measures one replay, this driver loops a
-seeded workload epoch after epoch against a ProcReplica fleet + gateway
-while the chaos plan *rotates* — fault-plan degradation, replica
+Where ``chaos_run`` proves one failure mode per scenario, this driver
+loops a seeded workload epoch after epoch against a ProcReplica fleet +
+gateway while the chaos plan *rotates* — fault-plan degradation, replica
 SIGKILL, drain/restart churn, explicit journal compaction — and after
 every epoch re-asserts the soak invariants (zero lost accepted
 requests, leak sentinel quiet, journal segment/byte/retention bounds,
