@@ -36,9 +36,22 @@ def sample_logits(logits, temperature=1.0, top_k=0, top_p=1.0, key=None):
                  omitted only for pure-greedy calls.
 
     Returns int32 ids, scalar for 1-D input. Same key -> same tokens.
+
+    With a key, what only a sampling row needs (both sorts of ``[B, V]``,
+    the softmax, the cumulative sum, the ``[B, V]`` uniform draw) is the
+    taken branch of one ``jax.lax.cond`` on ``any(temperature > 0)``, read
+    on the device in the same compiled program: an all-greedy batch runs
+    the argmax and nothing else; one sampling row pays for all ``B`` rows,
+    as every batch did before. Under ``jax.vmap`` a ``cond`` becomes a
+    select that runs both branches: batch through ``[B, V]`` logits.
     """
     squeeze = logits.ndim == 1
-    lg = (logits[None] if squeeze else logits).astype(jnp.float32)
+    # the cast reads the values the model gave in its own dtype: without the
+    # barrier XLA may fold it into the head's matmul and hand on the float32
+    # accumulator's bits, so bf16 logits that tie would no longer tie and
+    # the served token would depend on what the compiler fused
+    lg = jax.lax.optimization_barrier(
+        logits[None] if squeeze else logits).astype(jnp.float32)
     B, V = lg.shape
     temp = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32), (B,))
     tk = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (B,))
@@ -49,35 +62,43 @@ def sample_logits(logits, temperature=1.0, top_k=0, top_p=1.0, key=None):
         tok = greedy  # greedy-only call; sampling rows need a key
     else:
         key = jnp.asarray(key)
-        if key.ndim == 2:
-            keys = key
-        elif B == 1:
-            # a lone row consumes the key directly, so batched callers that
-            # fold a per-request key per row (the engine) and single-row
-            # callers (prefill / naive_generate) draw the SAME stream
-            keys = key[None]
-        else:
-            keys = jax.random.split(key, B)
-        desc = jnp.sort(lg, axis=-1)[:, ::-1]
-        # top-k: threshold at the k-th largest logit (k=0 -> keep all)
-        k_eff = jnp.clip(jnp.where(tk <= 0, V, tk), 1, V)
-        kth = jnp.take_along_axis(desc, (k_eff - 1)[:, None], axis=-1)
-        masked = jnp.where(lg >= kth, lg, -jnp.inf)
-        # top-p over the surviving distribution: keep sorted entries whose
-        # *exclusive* cumulative mass is < p (always keeps the top-1)
-        probs = jax.nn.softmax(masked, axis=-1)
-        sp = jnp.sort(probs, axis=-1)[:, ::-1]
-        csum = jnp.cumsum(sp, axis=-1)
-        first = jnp.arange(V, dtype=jnp.int32)[None] == 0
-        keep = ((csum - sp) < tp[:, None]) | first
-        thresh = jnp.min(jnp.where(keep, sp, jnp.inf), axis=-1, keepdims=True)
-        masked = jnp.where(probs >= thresh, masked, -jnp.inf)
-        # Gumbel-max with a per-row key: argmax(logits/T + g)
-        scaled = masked / jnp.maximum(temp, 1e-6)[:, None]
-        u = jax.vmap(lambda kk: jax.random.uniform(
-            kk, (V,), minval=1e-20, maxval=1.0))(keys)
-        sampled = jnp.argmax(scaled - jnp.log(-jnp.log(u)),
-                             axis=-1).astype(jnp.int32)
+
+        def sample_rows():
+            if key.ndim == 2:
+                keys = key
+            elif B == 1:
+                # a lone row consumes the key directly, so batched callers
+                # that fold a per-request key per row (the engine) and
+                # single-row callers (prefill / naive_generate) draw the
+                # SAME stream
+                keys = key[None]
+            else:
+                keys = jax.random.split(key, B)
+            desc = jnp.sort(lg, axis=-1)[:, ::-1]
+            # top-k: threshold at the k-th largest logit (k=0 -> keep all)
+            k_eff = jnp.clip(jnp.where(tk <= 0, V, tk), 1, V)
+            kth = jnp.take_along_axis(desc, (k_eff - 1)[:, None], axis=-1)
+            masked = jnp.where(lg >= kth, lg, -jnp.inf)
+            # top-p over the surviving distribution: keep sorted entries
+            # whose *exclusive* cumulative mass is < p (always keeps the
+            # top-1)
+            probs = jax.nn.softmax(masked, axis=-1)
+            sp = jnp.sort(probs, axis=-1)[:, ::-1]
+            csum = jnp.cumsum(sp, axis=-1)
+            first = jnp.arange(V, dtype=jnp.int32)[None] == 0
+            keep = ((csum - sp) < tp[:, None]) | first
+            thresh = jnp.min(jnp.where(keep, sp, jnp.inf), axis=-1,
+                             keepdims=True)
+            masked = jnp.where(probs >= thresh, masked, -jnp.inf)
+            # Gumbel-max with a per-row key: argmax(logits/T + g)
+            scaled = masked / jnp.maximum(temp, 1e-6)[:, None]
+            u = jax.vmap(lambda kk: jax.random.uniform(
+                kk, (V,), minval=1e-20, maxval=1.0))(keys)
+            return jnp.argmax(scaled - jnp.log(-jnp.log(u)),
+                              axis=-1).astype(jnp.int32)
+
+        sampled = jax.lax.cond(jnp.any(temp > 0), sample_rows,
+                               lambda: greedy)
         tok = jnp.where(temp > 0, sampled, greedy)
     return tok[0] if squeeze else tok
 

@@ -953,7 +953,7 @@ class LLMEngine:
             logits, _ = functional_call(
                 model, params, buffers, tokens[None], cache=view,
                 positions=positions, training=False)
-            last = logits[0, length - 1].astype(jnp.float32)
+            last = logits[0, length - 1]
             key = jax.random.fold_in(jax.random.PRNGKey(seed), step_idx)
             tok = sample_logits(last, temp, top_k, top_p, key)
             return tok, view.pool, self._pack_counters(view)
@@ -989,7 +989,7 @@ class LLMEngine:
             logits, _ = functional_call(
                 model, params, buffers, tokens[None], cache=view,
                 positions=positions, training=False)
-            last = logits[0, length - 1].astype(jnp.float32)
+            last = logits[0, length - 1]
             k = jax.random.fold_in(jax.random.PRNGKey(seed), step_idx)
             tok = sample_logits(last, temp, top_k, top_p, k)
             return tok, view.pool, self._pack_counters(view)
@@ -1125,7 +1125,8 @@ class LLMEngine:
             logits, _ = functional_call(
                 model, params, buffers, tokens[:, None], cache=view,
                 positions=ctx[:, None], training=False)
-            last = logits[:, -1].astype(jnp.float32)
+            # in the model's dtype: the sampler casts, behind a barrier
+            last = logits[:, -1]
             keys = jax.vmap(
                 lambda s, t: jax.random.fold_in(jax.random.PRNGKey(s), t)
             )(seeds, step_idx)
@@ -1191,6 +1192,7 @@ class LLMEngine:
         new_trace = self._decode_fn is None
         cost_est = None
         live_share = None
+        sampled = None
         counters = None
         done = False
         try:
@@ -1235,6 +1237,9 @@ class LLMEngine:
                 live_share = float(live.sum()) / (
                     len(running) * self.max_blocks)
                 window_share = self._window_block_share(ctx_lens)
+                # some row samples (idle slots upload temperature 0): the
+                # sampler's conditional takes its sort-and-draw branch
+                sampled = bool((host[3] > 0).any())
                 if self.prefix_cache:
                     # a decode write that just filled its block completes
                     # another full token-block: index it so later
@@ -1268,25 +1273,26 @@ class LLMEngine:
                     limit_s=self.watchdog_timeout_s)
             if not done:
                 self._account_decode(marks, len(running), live_share,
-                                     new_trace, cost_est, None)
+                                     sampled, new_trace, cost_est, None)
         with telemetry.span("engine.emit"):
             for slot, req in running.items():
                 self._emit(slot, req, int(toks[slot]))
         marks.append(time.monotonic())
-        return marks, len(running), live_share, new_trace, cost_est, counters
+        return (marks, len(running), live_share, sampled, new_trace, cost_est,
+                counters)
 
-    def _account_decode(self, marks, n_running, live_share, new_trace,
-                        cost_est, counters):
+    def _account_decode(self, marks, n_running, live_share, sampled,
+                        new_trace, cost_est, counters):
         """Book one decode step's time once it is known: its phases,
-        occupancy, live share of the block tables and the model's own
-        counters into the StepTimeline, and the call into the compile
-        watcher."""
+        occupancy, live share of the block tables, whether a row sampled
+        and the model's own counters into the StepTimeline, and the call
+        into the compile watcher."""
         phases = {ph: t1 - t0 for ph, t0, t1 in
                   zip(self._DECODE_PHASES, marks, marks[1:])}
         self._decode_tl.record_step(marks[-1] - marks[0], phases,
                                     occupancy=n_running / self.max_slots,
                                     live_block_share=live_share,
-                                    counters=counters)
+                                    sampled=sampled, counters=counters)
         self._watcher.record_call(
             "engine.decode",
             (("tokens", (self.max_slots,), "int32"),
@@ -1342,7 +1348,7 @@ def naive_generate(model, prompt, sampling: SamplingParams | None = None,
         logits, _ = functional_call(
             model, params, buffers, jnp.asarray([toks], jnp.int32),
             training=False)
-        last = logits[0, -1].astype(jnp.float32)
+        last = logits[0, -1]
         key = jax.random.fold_in(jax.random.PRNGKey(sp.seed), i)
         tok = int(sample_logits(last, sp.temperature, sp.top_k, sp.top_p,
                                 key))

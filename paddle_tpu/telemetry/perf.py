@@ -647,6 +647,8 @@ class StepTimeline:
         self._shares: dict[str, deque] = {
             name: deque(maxlen=self.window)
             for name in ("occupancy", "live_block_share")}
+        # per-step yes / no, reported as the share of the window's steps
+        self._sampled: deque = deque(maxlen=self.window)
         self.steps = 0
         self.regressions = 0
         self.last_regression: dict | None = None
@@ -665,14 +667,19 @@ class StepTimeline:
     def record_step(self, total_s: float, phases: dict,
                     occupancy: float | None = None,
                     live_block_share: float | None = None,
+                    sampled: bool | None = None,
                     counters: dict | None = None):
         """``occupancy`` is the share of the step's batch that did work
         (the serving engine: running slots / ``max_slots``);
         ``live_block_share`` the share of the running slots' block-table
         entries that hold context (what the paged kernel walks, of what a
-        static grid over the table would). ``counters`` are further numbers
-        of the step under names of the caller's (what a model counted about
-        itself; a dotted name nests in the report: ``moe.routed_pairs`` is
+        static grid over the table would); ``sampled`` whether some row of
+        the step's batch had ``temperature > 0`` (the sampler's conditional
+        then took its sort-and-draw branch), reported as one number,
+        ``sampled_step_share``: the share of the window's steps.
+        ``counters`` are further numbers of the step under names of the
+        caller's (what a model counted about itself; a dotted name nests in
+        the report: ``moe.routed_pairs`` is
         ``report()["moe"]["routed_pairs"]``). All are kept over the same
         window and reported beside the times."""
         if not ENABLED[0]:
@@ -694,6 +701,8 @@ class StepTimeline:
                 if v is not None:
                     self._shares.setdefault(
                         name, deque(maxlen=self.window)).append(float(v))
+            if sampled is not None:
+                self._sampled.append(bool(sampled))
             self.steps += 1
         pm = _perf_metrics()
         pm.step_s.labels(timeline=self.name).observe(total_s)
@@ -757,6 +766,9 @@ class StepTimeline:
                     vals = sorted(d)
                     shares[name] = {"mean": sum(vals) / len(vals),
                                     "p50": _pct(vals, 0.5)}
+            if self._sampled:
+                out["sampled_step_share"] = (sum(self._sampled)
+                                             / len(self._sampled))
         out.update(nest_dotted(shares))
         return out
 
@@ -766,6 +778,7 @@ class StepTimeline:
             self._phases.clear()
             for d in self._shares.values():
                 d.clear()
+            self._sampled.clear()
             self.steps = 0
             self.regressions = 0
             self.last_regression = None

@@ -200,6 +200,19 @@ class TestStepTimeline:
         tl.clear()
         assert name not in tl.report()
 
+    def test_sampled_step_share_is_one_number_over_the_window(self):
+        tl = perf.StepTimeline("t_sampled", window=4)
+        tl.record_step(0.010, {})                  # a step that did not say
+        assert "sampled_step_share" not in tl.report()
+        for flag in (False, False, True):
+            tl.record_step(0.010, {}, sampled=flag)
+        assert tl.report()["sampled_step_share"] == pytest.approx(1 / 3)
+        for _ in range(4):                         # the window moves on
+            tl.record_step(0.010, {}, sampled=False)
+        assert tl.report()["sampled_step_share"] == 0.0
+        tl.clear()
+        assert "sampled_step_share" not in tl.report()
+
     def test_phase_math_and_other(self):
         tl = perf.StepTimeline("t1")
         tl.record_step(0.010, {"data": 0.002, "compute": 0.006})

@@ -517,6 +517,126 @@ class TestDecodeKeepsThePoolInPlace:
 
 
 # ---------------------------------------------------------------------------
+# the decode step sorts only when a row samples
+# ---------------------------------------------------------------------------
+
+class TestDecodeSortsOnlyWhenARowSamples:
+    def test_every_sort_of_the_compiled_step_is_in_a_conditional(self):
+        """The optimised HLO of the engine's own ``jit(decode)`` on the tiny
+        model keeps a real ``conditional`` (a ``vmap`` over the sampler, or
+        a compiler that turned it into a ``select``, would run both
+        branches), and each ``sort`` is in a computation reached from its
+        branches alone: a greedy batch executes none."""
+        import re
+
+        eng = LLMEngine(_tiny_model(), block_size=8, max_slots=2,
+                        max_model_len=64)
+        eng.add_request([1, 2, 3], SamplingParams(max_new_tokens=8))
+        eng.step()                      # a prefill and one decode step
+        _, host = eng._assemble_decode()
+        eng._suspend_trace_counts = True
+        text = eng._get_decode_fn().lower(
+            eng.params, eng.buffers, eng.cache.pool,
+            *map(jnp.asarray, host)).compile().as_text()
+        eng.close()
+        # computation name -> its lines
+        comps, name = {}, None
+        for line in text.splitlines():
+            m = re.match(r"^(?:ENTRY )?%(\S+) \(.*\{$", line)
+            if m:
+                name = m.group(1)
+                comps[name] = []
+            elif name is not None:
+                comps[name].append(line)
+
+        def called(lines):
+            for line in lines:
+                for m in re.finditer(
+                        r"(?:calls|to_apply|body|condition|true_computation"
+                        r"|false_computation)=%([\w.\-]+)", line):
+                    yield m.group(1)
+                for m in re.finditer(r"branch_computations=\{([^}]*)\}",
+                                     line):
+                    yield from re.findall(r"%([\w.\-]+)", m.group(1))
+
+        conditionals = [line for lines in comps.values() for line in lines
+                        if " conditional(" in line]
+        assert len(conditionals) == 1, conditionals
+        under, todo = set(), list(called(conditionals))
+        while todo:
+            c = todo.pop()
+            if c not in under:
+                under.add(c)
+                todo.extend(called(comps[c]))
+        sorts = [c for c, lines in comps.items()
+                 for line in lines if " sort(" in line]
+        assert len(sorts) == 2 and set(sorts) <= under, sorts
+
+    def test_a_sampling_request_is_counted_and_moves_no_greedy_token(self):
+        """``sampled_step_share``: 0.0 while every request is greedy; above
+        0 once a ``temperature=0.8`` request runs among greedy ones, whose
+        tokens are those they get alone (the taken branch keeps the greedy
+        rows' argmax)."""
+        rng = np.random.RandomState(4)
+        prompts = [list(rng.randint(0, 61, n)) for n in (5, 9, 3)]
+        greedy = SamplingParams(max_new_tokens=6)
+        eng = LLMEngine(_tiny_model(), block_size=8, max_slots=4,
+                        max_model_len=64)
+        eng._decode_tl.clear()          # the process's timeline: start clean
+        alone = eng.generate(prompts, greedy)
+        step = eng.stats()["perf"]["decode_step"]
+        assert step["steps"] == 5 and step["sampled_step_share"] == 0.0
+        reqs = [eng.add_request(p, greedy) for p in prompts]
+        hot = eng.add_request(list(rng.randint(0, 61, 7)), SamplingParams(
+            max_new_tokens=3, temperature=0.8, top_k=20, top_p=0.95, seed=3))
+        eng.run()
+        assert [r.output_tokens for r in reqs] == alone
+        assert len(hot.output_tokens) == 3
+        step = eng.stats()["perf"]["decode_step"]
+        # two of the second run's five decode steps held the sampling row
+        assert step["steps"] == 10
+        assert step["sampled_step_share"] == pytest.approx(2 / 10)
+        assert eng.stats()["decode_traces"] == 1
+        eng.close()
+
+    @pytest.mark.parametrize("vocab", [32768, 100352])
+    def test_the_sampler_reads_logits_of_the_models_precision(self, v5e_chip,
+                                                              vocab):
+        """The decode step's last lines at the serve cells' widths (32 rows,
+        a bf16 head), compiled for a described v5e chip: the head's product
+        leaves its fusion in bf16 and the cast to float32 is an operation of
+        its own. Without the sampler's barrier XLA fuses the cast into the
+        product (its one consumer, now that a greedy batch sorts nothing)
+        and the product hands on its float32 accumulator: bf16 logits that
+        tie no longer tie, and greedy streams leave the parent's after some
+        tokens (PERF.md Findings, PR 33)."""
+        import re
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+        def tail_of_decode(x, head, temps, top_ks, top_ps, seeds, step_idx):
+            logits = (x @ head)[:, None, :]
+            keys = jax.vmap(
+                lambda s, t: jax.random.fold_in(jax.random.PRNGKey(s), t)
+            )(seeds, step_idx)
+            return sample_logits(logits[:, -1], temps, top_ks, top_ps, keys)
+
+        S, H = 32, 2048
+        text = jax.jit(tail_of_decode).trace(
+            sds((S, H), jnp.bfloat16), sds((H, vocab), jnp.bfloat16),
+            sds((S,), jnp.float32), sds((S,), jnp.int32),
+            sds((S,), jnp.float32), sds((S,), jnp.int32),
+            sds((S,), jnp.int32)).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+        # the fused computation that holds the head's product, by its root
+        products = [l for l in text.splitlines() if " convolution(" in l]
+        assert len(products) == 1 and "ROOT" in products[0], products
+        assert re.search(rf"= bf16\[{S},{vocab}\]", products[0]), products[0]
+        assert re.search(rf"= f32\[{S},{vocab}\][^=]* convert\(", text)
+
+
+# ---------------------------------------------------------------------------
 # grouped matrix product of the sparse experts (compiled for the chip here,
 # beside the other topology compiles; its numerics are in
 # tests/test_moe_grouped_matmul.py)
@@ -547,6 +667,23 @@ class TestGroupedMatmulCompiles:
 # ---------------------------------------------------------------------------
 # sampling
 # ---------------------------------------------------------------------------
+
+# name: (logits shape, temperature, top_k, top_p); scalars broadcast
+_SAMPLER_BATCHES = {
+    "all_greedy": ((4, 37), [0.0] * 4, 0, 1.0),
+    "all_greedy_with_filters": ((4, 37), 0.0, [3, 0, 7, 1], 0.9),
+    "all_sampling": ((4, 37), [0.7, 1.3, 0.2, 1.0], [0, 5, 0, 12],
+                     [1.0, 0.9, 0.5, 0.95]),
+    "all_sampling_scalars": ((3, 29), 0.8, 6, 0.9),
+    "mixed": ((4, 37), [0.0, 0.9, 0.0, 1.4], [0, 4, 9, 0],
+              [1.0, 1.0, 0.3, 0.8]),
+    "mixed_one_sampling_row": ((5, 23), [0.0, 0.0, 0.0, 1.1, 0.0], 0, 1.0),
+    "lone_row_greedy": ((1, 41), [0.0], 0, 1.0),
+    "lone_row_sampling": ((1, 41), [0.6], 8, 0.9),
+    "vector_greedy": ((41,), 0.0, 5, 0.9),
+    "vector_sampling": ((41,), 0.9, 5, 0.9),
+}
+
 
 class TestSampleLogits:
     def test_temperature_zero_is_argmax(self):
@@ -594,6 +731,112 @@ class TestSampleLogits:
         for i in range(3):
             single = int(sample_logits(lg[i], 0.8, 5, 0.95, keys[i]))
             assert batched[i] == single
+
+    # -- the conditional (PR 33): the tokens of the unconditional form --
+    @pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jit"])
+    @pytest.mark.parametrize("batch,keys", [
+        (b, k) for b, (shape, *_) in _SAMPLER_BATCHES.items()
+        for k in ("stacked", "single")
+        if len(shape) == 2 or k == "single"])  # a vector takes one key
+    def test_same_tokens_as_the_unconditional_form(self, batch, keys, jitted):
+        """The conditional decides whether the sorts and the draw run,
+        never a token: all-greedy, all-sampling or mixed, per-row or scalar
+        parameters, stacked keys or one, a vector or a matrix of logits,
+        the ids are those of the form that sorted and drew for every row
+        and chose with ``where`` at the end."""
+        shape, temps, top_k, top_p = _SAMPLER_BATCHES[batch]
+        rng = np.random.RandomState(sum(map(ord, batch)))
+        lg = jnp.asarray(rng.randn(*shape).astype(np.float32) * 3)
+        key = (jnp.stack([jax.random.PRNGKey(11 + i)
+                          for i in range(shape[0])])
+               if keys == "stacked" else jax.random.PRNGKey(5))
+        args = (lg, jnp.asarray(temps, jnp.float32),
+                jnp.asarray(top_k, jnp.int32),
+                jnp.asarray(top_p, jnp.float32), key)
+        new, old = sample_logits, _sample_logits_unconditional
+        if jitted:
+            new, old = jax.jit(new), jax.jit(old)
+        got, want = new(*args), old(*args)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("keys", ["stacked", "single"])
+    def test_sampling_work_only_inside_one_cond(self, keys):
+        """With a key the jaxpr holds one ``cond``; both sorts, the
+        cumulative sum and every random bit (a single key's split too) are
+        in its taken branch, the other hands back the argmax computed
+        outside, and nothing of the kind is left at top level, where it
+        ran for every greedy batch before."""
+        lg = jnp.zeros((4, 37), jnp.float32)
+        key = (jnp.stack([jax.random.PRNGKey(i) for i in range(4)])
+               if keys == "stacked" else jax.random.PRNGKey(0))
+        jaxpr = jax.make_jaxpr(sample_logits)(
+            lg, jnp.zeros(4), jnp.zeros(4, jnp.int32), jnp.ones(4), key).jaxpr
+
+        def names(eqns):
+            """Every primitive of ``eqns``, nested programs included."""
+            out = []
+            for eqn in eqns:
+                out.append(eqn.primitive.name)
+                for v in eqn.params.values():
+                    for j in (v if isinstance(v, (tuple, list)) else (v,)):
+                        j = getattr(j, "jaxpr", j)
+                        if hasattr(j, "eqns"):
+                            out.extend(names(j.eqns))
+            return out
+
+        def sampling_work(ns):
+            return {n for n in ns if n in ("sort", "cumsum")
+                    or n.startswith(("random_", "threefry"))}
+
+        conds = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+        assert len(conds) == 1
+        outside = names(e for e in jaxpr.eqns if e is not conds[0])
+        assert not sampling_work(outside) and "argmax" in outside
+        greedy_branch, sampling_branch = (
+            names(b.jaxpr.eqns) for b in conds[0].params["branches"])
+        assert greedy_branch == []                  # returns its operand
+        assert sampling_branch.count("sort") == 2
+        assert {"sort", "cumsum"} < sampling_work(sampling_branch)
+
+
+def _sample_logits_unconditional(logits, temperature=1.0, top_k=0, top_p=1.0,
+                                 key=None):
+    """``sample_logits`` with a key as it was before PR 33: sorts, softmax,
+    cumulative sum and draw for every row of every batch, and a ``where``
+    at the end. The function must return these tokens, bit for bit."""
+    squeeze = logits.ndim == 1
+    lg = (logits[None] if squeeze else logits).astype(jnp.float32)
+    B, V = lg.shape
+    temp = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32), (B,))
+    tk = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (B,))
+    tp = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), (B,))
+    greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+    key = jnp.asarray(key)
+    if key.ndim == 2:
+        keys = key
+    elif B == 1:
+        keys = key[None]
+    else:
+        keys = jax.random.split(key, B)
+    desc = jnp.sort(lg, axis=-1)[:, ::-1]
+    k_eff = jnp.clip(jnp.where(tk <= 0, V, tk), 1, V)
+    kth = jnp.take_along_axis(desc, (k_eff - 1)[:, None], axis=-1)
+    masked = jnp.where(lg >= kth, lg, -jnp.inf)
+    probs = jax.nn.softmax(masked, axis=-1)
+    sp = jnp.sort(probs, axis=-1)[:, ::-1]
+    csum = jnp.cumsum(sp, axis=-1)
+    first = jnp.arange(V, dtype=jnp.int32)[None] == 0
+    keep = ((csum - sp) < tp[:, None]) | first
+    thresh = jnp.min(jnp.where(keep, sp, jnp.inf), axis=-1, keepdims=True)
+    masked = jnp.where(probs >= thresh, masked, -jnp.inf)
+    scaled = masked / jnp.maximum(temp, 1e-6)[:, None]
+    u = jax.vmap(lambda kk: jax.random.uniform(
+        kk, (V,), minval=1e-20, maxval=1.0))(keys)
+    sampled = jnp.argmax(scaled - jnp.log(-jnp.log(u)),
+                         axis=-1).astype(jnp.int32)
+    tok = jnp.where(temp > 0, sampled, greedy)
+    return tok[0] if squeeze else tok
 
 
 # ---------------------------------------------------------------------------
