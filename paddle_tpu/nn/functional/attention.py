@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from ...core.dispatch import apply
 
 __all__ = ["scaled_dot_product_attention", "flash_attention",
-           "flash_attn_unpadded", "sdpa_ref", "CacheLayer",
+           "flash_attn_unpadded", "sdpa_ref", "CacheLayer", "StateLayer",
            "causal_window_mask"]
 
 
@@ -31,6 +31,18 @@ class CacheLayer(NamedTuple):
     kv_heads: int
     head_dim: int
     window: int | None = None     # latest positions a query sees, or all
+
+
+class StateLayer(NamedTuple):
+    """What one recurrent (state-space) layer keeps for each running
+    sequence, whatever its length: the recurrence's carry and the last
+    inputs of its causal conv, each as ``(shape, dtype)``, a dtype of None
+    being the cache's own (the model's). A model's ``cache_layers()`` gives
+    one beside its ``CacheLayer``s; its attention layers count themselves
+    among the ``CacheLayer``s and its recurrent layers among these."""
+
+    state: tuple                  # ((heads, d_state, head_dim), "float32")
+    conv: tuple                   # ((d_conv - 1, channels), None)
 
 
 def causal_window_mask(sq, sk, window=None):

@@ -52,7 +52,7 @@ Above the single engine sits the fleet plane (docs/SERVING.md
   runners that the bench, the soak harness (:mod:`.soak`), and the
   capacity planner all replay from one :class:`WorkloadSpec`.
 """
-from ..nn.functional.attention import CacheLayer  # noqa: F401
+from ..nn.functional.attention import CacheLayer, StateLayer  # noqa: F401
 from . import kv_fabric  # noqa: F401
 from .autoscaler import Autoscaler  # noqa: F401
 from .engine import LLMEngine, STATS_KEYS, naive_generate  # noqa: F401
@@ -102,7 +102,7 @@ from .tenancy import (  # noqa: F401
 
 __all__ = [
     "LLMEngine", "naive_generate", "STATS_KEYS", "BlockAllocator",
-    "CacheLayer", "PagedKVCache",
+    "CacheLayer", "StateLayer", "PagedKVCache",
     "PagedCacheView", "DenseKVCache", "Request", "RequestState",
     "SamplingParams", "Scheduler", "EngineClosed", "QueueFull",
     "DeadlineExceeded", "PreemptionStorm",

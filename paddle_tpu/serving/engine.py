@@ -71,6 +71,7 @@ import numpy as np
 
 from .. import telemetry
 from ..nn.decode import sample_logits
+from ..nn.functional.attention import StateLayer
 from ..nn.layer import functional_call, functional_state
 from ..utils import faults
 from .kv_cache import PagedCacheView, PagedKVCache
@@ -172,6 +173,10 @@ class LLMEngine:
                    positions=)`` and ``cache_layers()``, one
                    ``CacheLayer`` an attention layer (all layers
                    share one pool, so one KV width; windows may differ)
+                   and one ``StateLayer`` a recurrent layer (all alike:
+                   per-slot state arrays beside the pool; such a model
+                   runs without prefix reuse, whatever ``prefix_cache``
+                   says, because nothing snapshots a state)
     block_size:    tokens per KV block (pool granularity)
     num_blocks:    pool size incl. the reserved scratch block; default sizes
                    the pool so every slot can reach ``max_model_len``
@@ -245,8 +250,11 @@ class LLMEngine:
         self.params, self.buffers = functional_state(model)
         if kv_dtype is None:
             kv_dtype = next(iter(self.params.values())).dtype
-        self.prefix_cache = bool(prefix_cache)
-        layers = tuple(model.cache_layers())
+        declared = tuple(model.cache_layers())
+        state_layers = tuple(l for l in declared if isinstance(l, StateLayer))
+        layers = tuple(l for l in declared if not isinstance(l, StateLayer))
+        # a prefix hit skips the prefill that would build a state
+        self.prefix_cache = bool(prefix_cache) and not state_layers
         widths = {(l.kv_heads, l.head_dim) for l in layers}
         if len(widths) != 1:
             raise ValueError(
@@ -259,7 +267,8 @@ class LLMEngine:
             len(layers), num_blocks, kv_heads,
             self.block_size, head_dim, dtype=kv_dtype,
             prefix_cache=self.prefix_cache,
-            spill_blocks=kv_spill_blocks if self.prefix_cache else None)
+            spill_blocks=kv_spill_blocks if self.prefix_cache else None,
+            state_layers=state_layers, max_slots=self.max_slots)
         self.engine_label = str(next(_ENGINE_IDS))
         self._m = _engine_metrics(self.engine_label)
         self.slo = telemetry.SLOTracker(
@@ -295,8 +304,18 @@ class LLMEngine:
         self.decode_traces = 0
         self.prefill_traces: dict[int, int] = {}
         # the KV pool is donated to every step: the step's output pool
-        # reuses its buffer instead of holding two pools in device memory
+        # reuses its buffer instead of holding two pools in device memory;
+        # a model's state arrays ride at the end of a step's arguments and
+        # are donated with it
         self._donate = (2,)
+        self._has_state = self.cache.state is not None
+        self._state_bytes = self.cache.state_nbytes
+        self._state_slot_bytes = self._state_bytes // self.max_slots
+        # what the latest decode steps moved of the state arrays: this
+        # engine's own (the decode StepTimeline is the process's)
+        self._state_moved: dict[str, deque] = {
+            name: deque(maxlen=128)
+            for name in ("bytes_moved", "share_of_cache_bytes")}
 
         # roofline cost model (telemetry.cost): each new trace is walked
         # for FLOPs/HBM bytes at creation (jaxpr only, no extra compile):
@@ -324,6 +343,8 @@ class LLMEngine:
         self._block_bytes = self._pool_bytes // max(num_blocks, 1)
         self._mm.add("params", self._params_bytes)
         self._mm.add("kv_pool", self._pool_bytes)
+        if self._has_state:
+            self._mm.add("state_pool", self._state_bytes)
         if self.cache.spill_blocks:
             # the host spill pool legitimately grows monotonically under
             # sustained pressure up to its capacity — exempt it from the
@@ -414,6 +435,8 @@ class LLMEngine:
         self.closed = True
         self._mm.sub("params", self._params_bytes)
         self._mm.sub("kv_pool", self._pool_bytes)
+        if self._has_state:
+            self._mm.sub("state_pool", self._state_bytes)
         if self.cache.spill_blocks:
             self._mm.set("kv_spill_host", 0)
         dropped = self.scheduler.close(cancel_pending=True)
@@ -515,6 +538,7 @@ class LLMEngine:
         admitting side to local prefill — never wrong K/V."""
         from . import kv_fabric
 
+        self._no_frames_with_state_layers()
         act = faults.inject("serving.kv.fetch", hashes=len(list(hashes)),
                             engine=self.engine_label)
         if act == "stale":
@@ -536,7 +560,16 @@ class LLMEngine:
         land verified simply prefills locally on admission."""
         from . import kv_fabric
 
+        self._no_frames_with_state_layers()
         return kv_fabric.ingest_frames(self.cache, frames)
+
+    def _no_frames_with_state_layers(self):
+        if self._has_state:
+            raise ValueError(
+                "this engine's model has state layers: K/V frames of a "
+                "prefix are of no use without the recurrent state at its "
+                "end, which is neither exported nor ingested; the request "
+                "prefills here")
 
     def stats(self) -> dict:
         """Serving counters, read back from this engine's registry series
@@ -590,6 +623,12 @@ class LLMEngine:
             # prefix-cache effectiveness: hit rate, blocks/tokens saved,
             # CoW copies, evictions, and the evictable-pool size
             "prefix_cache": self.cache.prefix_stats(),
+            # a model with state layers: the per-slot arrays beside the pool
+            **({"state_cache": {
+                "slots": self.max_slots, "bytes": self._state_bytes,
+                "bytes_per_slot": self._state_slot_bytes,
+                "prefix_reuse": "off: state layers"}}
+               if self._has_state else {}),
             # performance observability (telemetry.perf): compile/retrace
             # counts per engine callable (+ any active storm with its
             # signature diff), the decode step's phase breakdown, and the
@@ -614,6 +653,11 @@ class LLMEngine:
             "memory": self._mm.snapshot(),
             "roofline": self._roofline_block(),
         }
+        if self._has_state and self._state_moved["bytes_moved"]:
+            block["decode_step"]["state"] = {
+                name: {"mean": sum(v) / len(v),
+                       "p50": float(np.median(v))}
+                for name, v in self._state_moved.items()}
         if self._prefill_counters:
             # the model's own counters over the latest prefills (the decode
             # steps' are in the StepTimeline's report)
@@ -914,6 +958,30 @@ class LLMEngine:
             for w in windows)
         return walked / (len(windows) * float(live.sum()))
 
+    def _donate_from(self, first: int) -> tuple:
+        """A step's donated arguments: the pool and, for a model with state
+        layers, the two state arrays, which start at argument ``first``."""
+        state = (first, first + 1) if self._has_state else ()
+        return self._donate + state
+
+    def _book_state_bytes_moved(self, ctx_lens):
+        """What a decode step moves of the state arrays (every live slot's
+        rows read and written once) and that as a share of the step's
+        cache bytes, itself plus the live K/V the paged kernel walks (from
+        the host's context lengths): which cache sets the step. Kept over
+        this engine's last 128 steps; nothing for a model without state
+        layers."""
+        if not self._has_state:
+            return
+        moved = len(ctx_lens) * 2 * self._state_slot_bytes
+        kv_token = self._block_bytes // self.block_size
+        walked = sum(
+            int(np.minimum(ctx_lens, w or ctx_lens).sum())
+            for w in self._windows) * kv_token // len(self._windows)
+        self._state_moved["bytes_moved"].append(float(moved))
+        self._state_moved["share_of_cache_bytes"].append(
+            moved / (moved + walked))
+
     # ------------------------------------------------------------------
     # prefill
     # ------------------------------------------------------------------
@@ -936,6 +1004,13 @@ class LLMEngine:
                                           4 * cfg.hidden_size)
         return int(tokens) * width * 4
 
+    @staticmethod
+    def _sampled_row(view, logits, length):
+        """The logits a batch-1 prefill samples from: the last valid
+        position's, which is the only one there is where the model kept it
+        alone (``PagedCacheView.last_rows``; the view says so)."""
+        return logits[0, 0] if view.kept_last_rows else logits[0, length - 1]
+
     def _get_prefill_fn(self, P: int):
         fn = self._prefill_fns.get(P)
         if fn is not None:
@@ -944,21 +1019,23 @@ class LLMEngine:
         model = self.model
 
         def prefill(params, buffers, pool, tokens, length, bt,
-                    temp, top_k, top_p, seed, step_idx):
+                    temp, top_k, top_p, seed, step_idx, slot=None, *state):
             if not self._suspend_trace_counts:   # cost walks retrace too
                 self.prefill_traces[P] = self.prefill_traces.get(P, 0) + 1
             view = PagedCacheView(pool, bt[None, :], None, self.block_size,
-                                  windows=self._windows, valid_len=length)
+                                  windows=self._windows, valid_len=length,
+                                  state=state or None, slot=slot)
             positions = jnp.arange(P, dtype=jnp.int32)[None]
             logits, _ = functional_call(
                 model, params, buffers, tokens[None], cache=view,
                 positions=positions, training=False)
-            last = logits[0, length - 1]
+            last = self._sampled_row(view, logits, length)
             key = jax.random.fold_in(jax.random.PRNGKey(seed), step_idx)
             tok = sample_logits(last, temp, top_k, top_p, key)
-            return tok, view.pool, self._pack_counters(view)
+            return (tok, view.pool, self._pack_counters(view),
+                    *(view.state or ()))
 
-        fn = jax.jit(prefill, donate_argnums=self._donate)
+        fn = jax.jit(prefill, donate_argnums=self._donate_from(12))
         self._prefill_fns[P] = fn
         self._py_fns[P] = prefill
         return fn
@@ -989,7 +1066,7 @@ class LLMEngine:
             logits, _ = functional_call(
                 model, params, buffers, tokens[None], cache=view,
                 positions=positions, training=False)
-            last = logits[0, length - 1]
+            last = self._sampled_row(view, logits, length)
             k = jax.random.fold_in(jax.random.PRNGKey(seed), step_idx)
             tok = sample_logits(last, temp, top_k, top_p, k)
             return tok, view.pool, self._pack_counters(view)
@@ -1025,9 +1102,13 @@ class LLMEngine:
                 jnp.float32(sp.temperature), jnp.int32(sp.top_k),
                 jnp.float32(sp.top_p), jnp.int32(sp.seed),
                 jnp.int32(len(req.output_tokens)))
+            if self._has_state:
+                call_args += (jnp.int32(slot), *self.cache.state)
             cost_est = (self._trace_cost("prefill", f"P{P}", P, call_args)
                         if new_trace else None)
-            tok, self.cache.pool, counters = fn(*call_args)
+            tok, self.cache.pool, counters, *state = fn(*call_args)
+            if state:
+                self.cache.state = tuple(state)
         self._finish_prefill(
             slot, req, toks, tok, t0, f"P{P}",
             (("tokens", (P,), "int32"),
@@ -1116,12 +1197,12 @@ class LLMEngine:
         model = self.model
 
         def decode(params, buffers, pool, tokens, bt, ctx,
-                   temps, top_ks, top_ps, seeds, step_idx):
+                   temps, top_ks, top_ps, seeds, step_idx, *state):
             if not self._suspend_trace_counts:
                 # lint: allow-tracer-leak(trace-time compile counter, runs once per trace)
                 self.decode_traces += 1
             view = PagedCacheView(pool, bt, ctx, self.block_size,
-                                  windows=self._windows)
+                                  windows=self._windows, state=state or None)
             logits, _ = functional_call(
                 model, params, buffers, tokens[:, None], cache=view,
                 positions=ctx[:, None], training=False)
@@ -1131,9 +1212,11 @@ class LLMEngine:
                 lambda s, t: jax.random.fold_in(jax.random.PRNGKey(s), t)
             )(seeds, step_idx)
             toks = sample_logits(last, temps, top_ks, top_ps, keys)
-            return toks, view.pool, self._pack_counters(view)
+            return (toks, view.pool, self._pack_counters(view),
+                    *(view.state or ()))
 
-        self._decode_fn = jax.jit(decode, donate_argnums=self._donate)
+        self._decode_fn = jax.jit(decode,
+                                  donate_argnums=self._donate_from(11))
         self._py_fns["decode"] = decode
         return self._decode_fn
 
@@ -1203,7 +1286,8 @@ class LLMEngine:
                     faults.inject("serving.decode", batch=len(running))
                     fn = self._get_decode_fn()
                     call_args = (self.params, self.buffers, self.cache.pool,
-                                 *(jnp.asarray(a) for a in host))
+                                 *(jnp.asarray(a) for a in host),
+                                 *(self.cache.state or ()))
                     if new_trace:
                         cost_est = self._trace_cost(
                             "decode", "decode", "decode", call_args)
@@ -1214,7 +1298,9 @@ class LLMEngine:
                 with telemetry.span("engine.decode", batch=len(running),
                                     engine=self.engine_label,
                                     **({"trace_ids": tids} if tids else {})):
-                    toks, self.cache.pool, counters = fn(*call_args)
+                    toks, self.cache.pool, counters, *state = fn(*call_args)
+                    if state:
+                        self.cache.state = tuple(state)
                 marks.append(time.monotonic())
             except Exception as e:
                 # the fused step died: every request in the batch fails,
@@ -1237,6 +1323,7 @@ class LLMEngine:
                 live_share = float(live.sum()) / (
                     len(running) * self.max_blocks)
                 window_share = self._window_block_share(ctx_lens)
+                self._book_state_bytes_moved(ctx_lens)
                 # some row samples (idle slots upload temperature 0): the
                 # sampler's conditional takes its sort-and-draw branch
                 sampled = bool((host[3] > 0).any())

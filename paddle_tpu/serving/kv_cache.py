@@ -36,6 +36,18 @@ evicted on demand — the scheduler admits against *effective* free blocks
 per-sequence block tables, so shared blocks are purely host-side
 bookkeeping: no kernel change.
 
+State layers (``PagedKVCache(state_layers=...)``, docs/SERVING.md §2a): a
+model whose layers carry a recurrence (a state-space mixer) keeps, beside
+the pool, two arrays indexed by *decode slot*, not by block,
+``[state_layers, max_slots, ...]``: the recurrent state and the causal
+conv's last inputs. Their size does not grow with the context; a decode
+step reads and rewrites a live slot's rows whole (the state in place,
+``kernels/ssm_state_update.py``), a prefill writes its slot's rows from a
+zero state, so a slot never inherits its predecessor's. They travel through
+the jitted steps donated, as the pool does. A prefix hit would need the
+state at the prefix's end, which nothing snapshots: such a model runs
+without prefix reuse.
+
 Tiered host-RAM spill (``spill_blocks=N``, docs/ROBUSTNESS.md "Degradation
 ladder"): with a spill tier armed, LRU eviction *demotes* instead of
 destroys — the evicted block's K/V is copied to a bounded host (numpy)
@@ -317,10 +329,27 @@ class PagedKVCache:
 
     def __init__(self, num_layers, num_blocks, kv_heads, block_size,
                  head_dim, dtype=jnp.float32, prefix_cache: bool = False,
-                 spill_blocks: int | None = None):
+                 spill_blocks: int | None = None, state_layers=(),
+                 max_slots: int = 0):
         self.pool = jnp.zeros(
             (num_layers, num_blocks, 2, kv_heads, block_size, head_dim),
             dtype)
+        # what the state layers (``StateLayer``, all alike) keep a decode
+        # slot: (recurrent state, conv inputs), or None without such layers
+        self.state = None
+        if state_layers:
+            if len(set(state_layers)) != 1:
+                raise ValueError(
+                    f"one array holds every state layer's rows, so the "
+                    f"layers must agree; the model has {set(state_layers)}")
+            if prefix_cache:
+                raise ValueError(
+                    "a prefix hit skips the prefill that builds a state "
+                    "layer's state: no prefix cache with state layers")
+            rows = (len(state_layers), int(max_slots))
+            self.state = tuple(
+                jnp.zeros(rows + tuple(shape), dt or dtype)
+                for shape, dt in state_layers[0])
         self.allocator = BlockAllocator(num_blocks)
         self.block_size = int(block_size)
         self.tables: dict[object, list[int]] = {}
@@ -905,6 +934,10 @@ class PagedKVCache:
             },
         }
 
+    @property
+    def state_nbytes(self) -> int:
+        return sum(int(a.nbytes) for a in self.state or ())
+
     def table_array(self, seq_ids, max_blocks: int) -> np.ndarray:
         """Fixed-shape [len(seq_ids), max_blocks] int32 table; absent ids
         and padding rows point at the scratch block."""
@@ -915,6 +948,15 @@ class PagedKVCache:
             t = self.tables[sid]
             out[i, :len(t)] = t
         return out
+
+
+def _put_row(rows, row, layer_idx, slot):
+    """``rows[layer_idx, slot] = row`` as one dynamic-update-slice (in
+    place on a donated buffer)."""
+    at = (jnp.int32(layer_idx), slot.astype(jnp.int32)) + (
+        jnp.int32(0),) * row.ndim
+    return jax.lax.dynamic_update_slice(
+        rows, row[None, None].astype(rows.dtype), at)
 
 
 class PagedCacheView:
@@ -931,6 +973,16 @@ class PagedCacheView:
     layer with one sees only its latest ``window`` positions, in every mode
     below. The pool still keeps every position of every layer (one block
     table a sequence); a window shortens the walk, not the table.
+
+    ``state`` is the cache's per-slot arrays for a model with state layers
+    (``PagedKVCache.state``: recurrent state ``[L, slots, H, N, P]`` and
+    conv inputs ``[L, slots, K - 1, C]``), threaded like the pool: a
+    recurrent layer calls :meth:`shift` (its conv's window) and
+    :meth:`recur` (the recurrence) where an attention layer calls
+    :meth:`attend`. In decode a batch row is its slot's row; a prefill
+    (batch 1) starts from zeros and writes row ``slot``: the state after the
+    last *valid* token (padding's ``dt`` is zeroed, which leaves a state as
+    it is) and the conv's last valid inputs. The view knows no model.
 
     A model may hand the step integers about itself with :meth:`count`
     (what its sparse layers routed where); they accumulate on
@@ -953,8 +1005,10 @@ class PagedCacheView:
 
     def __init__(self, pool, block_tables, ctx_lens, block_size,
                  prefix_block_tables=None, prefix_len=None, windows=None,
-                 valid_len=None):
+                 valid_len=None, state=None, slot=None):
         self.pool = pool                      # [L, N, 2, H, bs, D]
+        self.state = state                    # None | (recurrent, conv)
+        self.slot = slot                      # prefill: the state's row
         self.block_tables = block_tables      # [S, M] int32
         self.ctx_lens = ctx_lens              # [S] int32 (None for prefill)
         self.block_size = int(block_size)
@@ -962,6 +1016,7 @@ class PagedCacheView:
         self.prefix_len = prefix_len          # int32 scalar (valid tokens)
         self.windows = windows                # per layer: None | int
         self.valid_len = valid_len            # prefill: tokens before padding
+        self.kept_last_rows = False           # set by last_rows (prefill)
         self.counters: dict = {}
 
     def _window(self, layer_idx):
@@ -984,6 +1039,62 @@ class PagedCacheView:
         """Add integers (traced scalars) to the step's named counters."""
         for name, v in named.items():
             self.counters[name] = self.counters.get(name, 0) + v
+
+    def last_rows(self, h):
+        """Of a step's hidden states ``[B, S, H]``, the rows whose logits
+        the step samples: a prefill's last valid position (``[1, 1, H]``;
+        a model that calls this computes no ``[P, vocab]`` logits), every
+        row of a decode step."""
+        if self.ctx_lens is not None or self.valid_len is None:
+            return h
+        self.kept_last_rows = True
+        return jax.lax.dynamic_slice_in_dim(h, self.valid_len - 1, 1, axis=1)
+
+    # the hooks a recurrent layer calls (raw arrays in/out)
+    def shift(self, layer_idx, u):
+        """The causal conv's window of state layer ``layer_idx``: this
+        step's inputs ``u [B, S, C]`` with the ``K - 1`` before them in
+        front, ``[B, S + K - 1, C]``; the last ``K - 1`` valid ones are
+        kept for the next step."""
+        recurrent, conv = self.state
+        k1 = conv.shape[2]
+        if self.ctx_lens is not None:
+            window = jnp.concatenate([conv[layer_idx], u], axis=1)
+            kept = window[:, window.shape[1] - k1:]
+            self.state = (recurrent, conv.at[layer_idx].set(kept))
+            return window
+        window = jnp.pad(u, ((0, 0), (k1, 0), (0, 0)))
+        # inputs valid_len - (K - 1) .. valid_len - 1, zeros before the
+        # sequence, whatever the padding holds
+        kept = jax.lax.dynamic_slice_in_dim(window[0], self.valid_len, k1,
+                                            axis=0)
+        self.state = (recurrent, _put_row(conv, kept, layer_idx, self.slot))
+        return window
+
+    def recur(self, layer_idx, x, dt, a, b, c, *, chunk):
+        """The selective recurrence of state layer ``layer_idx`` (Mamba-2's
+        ``S <- exp(dt a) S + dt x (x) b``, ``y = S c``) over this step's
+        tokens, the skip term left to the caller. x ``[B, S, H, P]``; dt
+        ``[B, S, H]`` (after softplus); a ``[H]``; b, c ``[B, S, G, N]``.
+        Returns y ``[B, S, H, P]`` float32."""
+        from ..kernels.ssd_chunk_scan import ssd_chunk_scan
+        from ..kernels.ssm_state_update import ssm_state_update
+
+        recurrent, conv = self.state
+        if self.ctx_lens is not None:
+            # the whole state in, the whole state out, rows updated in
+            # place: nothing here slices or scatters it
+            y, recurrent = ssm_state_update(
+                recurrent, layer_idx, x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0])
+            self.state = (recurrent, conv)
+            return y[:, None]
+        if x.shape[0] != 1:
+            raise ValueError(f"prefill expects batch 1; got {x.shape[0]}")
+        # a padded position neither decays the state nor adds to it
+        dt = jnp.where(self.live_rows(dt.shape[:2])[..., None], dt, 0.0)
+        y, final = ssd_chunk_scan(x[0], dt[0], a, b[0], c[0], chunk=chunk)
+        self.state = (_put_row(recurrent, final, layer_idx, self.slot), conv)
+        return y[None]
 
     # the duck-typed hook LlamaAttention calls (raw arrays in/out)
     def attend(self, layer_idx, q, k, v):

@@ -516,6 +516,138 @@ class TestDecodeKeepsThePoolInPlace:
         assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
 
 
+class TestStateCacheStaysInPlace:
+    """The per-slot state arrays of a model with state layers, beside the
+    pool and held to the same structure: at Falcon-H1-34B's widths (6
+    layers, 64 slots, 32 heads of 128 by 256 float32: 1.61 GB) one copy a
+    layer would cost what PR 30 removed."""
+
+    @pytest.fixture
+    def on_the_chip(self, monkeypatch):
+        from paddle_tpu import kernels
+        from paddle_tpu.kernels import (paged_attention, ssd_chunk_scan,
+                                        ssm_state_update)
+
+        for mod in (paged_attention, ssd_chunk_scan, ssm_state_update):
+            monkeypatch.setattr(mod, "_interpret_mode", lambda: False)
+        kernels.set_use_pallas(True)
+        yield
+        kernels.set_use_pallas(None)
+
+    def test_no_state_shaped_copy_in_the_compiled_decode_step(
+            self, v5e_chip, on_the_chip):
+        """``PagedCacheView.shift`` and ``.recur`` once a layer, as a model
+        calls them, the state donated as the engine donates it: in the
+        optimised HLO the recurrent state is the entry parameter, one
+        ``ssm_state_update`` custom call a layer that takes it whole and
+        hands it on, and the output, which is the parameter's buffer.
+        Nothing else has its shape or a layer's: no ``copy``, ``scatter``,
+        ``dynamic-update-slice`` or fusion."""
+        import re
+        from collections import Counter
+
+        from paddle_tpu.serving.kv_cache import PagedCacheView
+
+        L, S, H, N, P, G, K1, C = 6, 64, 32, 256, 128, 2, 3, 5120
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+        def step(state, conv, bt, ctx, u, x, dt, a, b, c):
+            view = PagedCacheView(None, bt, ctx, 16, state=(state, conv))
+            acc = jnp.zeros((), x.dtype)
+            for layer in range(L):
+                window = view.shift(layer, u + acc)
+                y = view.recur(layer, x + acc, dt, a, b, c, chunk=128)
+                acc = (y.sum() + window.sum()).astype(x.dtype)
+            return view.state, acc
+
+        bf = jnp.bfloat16
+        compiled = jax.jit(step, donate_argnums=(0, 1)).trace(
+            sds((L, S, H, N, P), jnp.float32), sds((L, S, K1, C), bf),
+            sds((S, 96), jnp.int32), sds((S,), jnp.int32),
+            sds((S, 1, C), bf), sds((S, 1, H, P), bf),
+            sds((S, 1, H), jnp.float32), sds((H,), jnp.float32),
+            sds((S, 1, G, N), bf), sds((S, 1, G, N), bf)).lower(
+            lowering_platforms=("tpu",)).compile()
+        text = compiled.as_text()
+        layer_shape = f"[{S},{H},{N},{P}]"
+        state_shape = f"[{L},{layer_shape[1:]}"
+        instr = re.compile(r"^\s*(?:ROOT )?%\S+ = (.*?) ([a-z][a-z-]*)\(")
+        ops = [m.group(2) for m in map(instr.match, text.splitlines())
+               if m and (state_shape in m.group(1)
+                         or layer_shape in m.group(1))]
+        calls = [l for l in text.splitlines()
+                 if "custom-call(" in l and "ssm_state_update" in l]
+        assert sorted(set(ops)) == ["custom-call", "get-tuple-element",
+                                    "parameter", "tuple"], Counter(ops)
+        assert len(calls) == L and all(state_shape in c for c in calls)
+        assert ops.count("custom-call") == L and ops.count("parameter") == 1
+        assert re.search(r"input_output_alias=\{ \{0\}: \(0, \{\}", text)
+        layer_bytes = S * H * N * P * 4
+        assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+
+    def test_the_compiled_prefill_has_no_prompt_by_vocabulary_array(
+            self, v5e_chip, on_the_chip):
+        """The engine's own ``jit(prefill)`` of a Falcon-H1 (mixer heads of
+        128, vocabulary 8,192, a prompt bucket of 256) compiled for the
+        chip: the head is applied to the sampled position alone, so nothing
+        in the optimised HLO is ``[256, 8192]``; both kernels are there, and
+        the state is written by in-place slices. A Llama engine's prefill
+        at the same sizes has the array (it keeps every position's
+        logits), so the search can find one."""
+        import re
+
+        from paddle_tpu.models import (FalconH1Config, FalconH1ForCausalLM,
+                                       LlamaConfig, LlamaForCausalLM)
+
+        P, V = 256, 8192
+        falcon = FalconH1ForCausalLM(FalconH1Config(
+            vocab_size=V, hidden_size=384, intermediate_size=512,
+            num_hidden_layers=2, num_attention_heads=5,
+            num_key_value_heads=1, head_dim=128, max_position_embeddings=512,
+            mamba_d_ssm=1024, mamba_n_heads=8, mamba_d_head=128,
+            mamba_d_state=128, mamba_n_groups=1))
+        llama = LlamaForCausalLM(LlamaConfig(
+            vocab_size=V, hidden_size=384, intermediate_size=512,
+            num_hidden_layers=2, num_attention_heads=3,
+            num_key_value_heads=1, max_position_embeddings=512))
+        wide = re.compile(rf"\[(?:1,)?{P},{V}\]")
+
+        def compiled_prefill(model):
+            model.to(dtype="bfloat16")
+            eng = LLMEngine(model, block_size=16, max_slots=2,
+                            max_model_len=512)
+            eng._suspend_trace_counts = True
+
+            def sds(a):
+                return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                            sharding=v5e_chip)
+
+            i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e_chip)
+            f32 = jax.ShapeDtypeStruct((), jnp.float32, sharding=v5e_chip)
+            args = [jax.tree.map(sds, eng.params),
+                    jax.tree.map(sds, eng.buffers), sds(eng.cache.pool),
+                    jax.ShapeDtypeStruct((P,), jnp.int32, sharding=v5e_chip),
+                    i32, jax.ShapeDtypeStruct((P // 16,), jnp.int32,
+                                              sharding=v5e_chip),
+                    f32, i32, f32, i32, i32]
+            if eng.cache.state is not None:
+                args += [i32, *map(sds, eng.cache.state)]
+            text = eng._get_prefill_fn(P).trace(*args).lower(
+                lowering_platforms=("tpu",)).compile().as_text()
+            eng.close()
+            return text
+
+        text = compiled_prefill(falcon)
+        assert not wide.search(text)
+        for kernel in ("ssd_chunk_scan", "paged_attention"):
+            assert (kernel == "ssd_chunk_scan") == any(
+                "custom-call(" in l and kernel in l
+                for l in text.splitlines()), kernel
+        assert wide.search(compiled_prefill(llama))
+
+
 # ---------------------------------------------------------------------------
 # the decode step sorts only when a row samples
 # ---------------------------------------------------------------------------
