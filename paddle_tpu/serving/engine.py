@@ -16,6 +16,18 @@ field of the model's shape:
   compiles exactly once no matter how sequences grow, join, or finish.
   A trace counter asserts this (the ``static.Executor`` discipline).
 
+The decode loop is a pipeline of depth one (docs/SERVING.md "The pipelined
+loop"): step N+1 is dispatched before step N's tokens are read. The sampled
+``[slots]`` vector goes from step to step on the device (a prefill's first
+token is put into its slot's place there), the host counts the tokens in
+flight (``Request.in_flight``), and reading, emitting, accounting and the
+next step's preparation run while the device works. A request that ends on
+``eos_token_id`` is found one step late: its row of the step dispatched
+past it is thrown away. Anything but the common case *drains* first (reads
+what is in flight, then goes on in the serial order): a preemption, a
+cancel or a deadline that hits a running request, a fault, ``close()``, a
+K/V export.
+
 Sampling is seeded per (request, output index) — batch composition,
 preemption, and re-prefill cannot change a request's tokens, which is what
 makes continuous batching output-equivalent to one-at-a-time decoding.
@@ -157,13 +169,20 @@ def _engine_metrics(label: str) -> SimpleNamespace:
         queue_time=H("serving_queue_time_seconds",
                      "request arrival to slot admission"),
         decode_step=H("serving_decode_step_seconds",
-                      "one fused decode step, from the start of batch "
-                      "assembly until its tokens are on the host",
+                      "one fused decode step, from its dispatch until its "
+                      "tokens are on the host",
                       _TOKEN_BUCKETS),
         admit_delay=H("serving_admit_delay_seconds",
                       "gateway read of a request to the engine accepting "
                       "it (observed by the replica on add_request)"),
     )
+
+
+@jax.jit
+def _put_token(tokens, slot, tok):
+    """A prefill's first token into its slot's place of the vector the next
+    decode step takes as it is."""
+    return tokens.at[slot].set(tok)
 
 
 class LLMEngine:
@@ -187,8 +206,8 @@ class LLMEngine:
                    raises ``QueueFull`` (None = unbounded)
     max_preemptions_per_request: requeue cap before a thrashing request is
                    failed (preemption-storm protection)
-    watchdog_timeout_s: decode steps slower than this — batch assembly to
-                   the tokens' arrival on the host, so a slow device step
+    watchdog_timeout_s: decode steps slower than this — dispatch to the
+                   tokens' arrival on the host, so a slow device step
                    trips it — are counted as watchdog trips in ``stats()``
                    (None = off)
     stall_limit:   consecutive no-progress engine steps tolerated before
@@ -361,6 +380,20 @@ class LLMEngine:
         self._total_generated = 0
         self._serve_start: float | None = None
 
+        # the decode pipeline (depth one). _tokens: each slot's next input
+        # token, on the device: a decode step's result as it is, a
+        # prefill's first token put into its slot's place. _inflight: the
+        # decode step dispatched and not yet read; _unread_prefills: the
+        # prefills of this turn whose first token is not yet read.
+        self._tokens = jnp.zeros(self.max_slots, jnp.int32)
+        self._inflight: SimpleNamespace | None = None
+        self._unread_prefills: list[SimpleNamespace] = []
+        self._last_result_t: float | None = None
+        # of the last 128 decode steps, those dispatched while the step
+        # before was unread; drains by reason (stats()["perf"])
+        self._pipelined: deque = deque(maxlen=128)
+        self._drains: dict[str, int] = {}
+
         self.watchdog_timeout_s = watchdog_timeout_s
         self.watchdog_trips = 0
         self.last_decode_s = 0.0
@@ -416,22 +449,26 @@ class LLMEngine:
         unknown or already-terminal request (including one that just
         finished, failed, or was already cancelled) returns False instead
         of raising, so a fleet router can fan out cancels without racing
-        the engine's own terminal transitions."""
+        the engine's own terminal transitions. A running request's tokens
+        in flight are read first (a drain): it may finish there."""
+        req = self._requests.get(rid)
+        if req is not None and req.in_flight:
+            self._drain("cancel")
         ok = self.scheduler.cancel(rid, reason=reason)
-        if ok:
-            req = self._requests.get(rid)
-            if req is not None:
-                self.cancelled.append(req)
-                self._record_lifecycle(req)
+        if ok and req is not None:
+            self.cancelled.append(req)
+            self._record_lifecycle(req)
         return ok
 
     def close(self):
         """Shut down: still-queued (never-prefilled) requests end FAILED
         with ``EngineClosed`` attached, running ones end CANCELLED (reason
         "shutdown") — every handle reaches a terminal state a router can
-        act on; future add_request calls raise ``EngineClosed``."""
+        act on; future add_request calls raise ``EngineClosed``. Tokens
+        in flight are read and emitted first."""
         if self.closed:
             return
+        self._drain("close")
         self.closed = True
         self._mm.sub("params", self._params_bytes)
         self._mm.sub("kv_pool", self._pool_bytes)
@@ -448,23 +485,37 @@ class LLMEngine:
                 self.cancelled.append(req)
             self._record_lifecycle(req)
 
+    def has_work(self) -> bool:
+        """Requests waiting or running, or a step in flight to be read."""
+        return self.scheduler.has_work() or self._unread()
+
     def step(self) -> bool:
-        """One engine iteration: sweep deadlines, admit + prefill new
-        requests (each inside its own failure boundary), then one batched
-        decode step over the running slots. Returns True while there is
-        work left."""
+        """One engine iteration: sweep deadlines, admit and dispatch the
+        prefills of new requests (each inside its own failure boundary),
+        dispatch one batched decode step over the running slots, and only
+        then read the decode step dispatched an iteration ago and this
+        iteration's first tokens: the device has the next step queued
+        while the host emits and books this one. Returns True while there
+        is work left (a step in flight is work left)."""
         if self.closed:
             return False
         if self._serve_start is None and self.scheduler.has_work():
             self._serve_start = time.monotonic()
         had_work = self.scheduler.has_work()
         self._progressed = False
+        if not (self.scheduler.running or self._unread()):
+            self._last_result_t = None      # no step to follow on from
+        now = time.monotonic()
+        if self._unread() and any(
+                req.past_deadline(now)
+                for req in self.scheduler.running.values()):
+            self._drain("deadline")
         # the phases below are flat spans that tile the iteration (docs/
         # OBSERVABILITY.md "Phase spans"): no span encloses another, so a
         # device-idle gap in a profiler trace is named by the phase the
         # engine thread spent in it
         with telemetry.span("engine.schedule"):
-            self._sweep_deadlines()
+            self._sweep_deadlines(now)
             admitted = self.scheduler.admit()
         for slot, req in admitted:
             self._progressed = True
@@ -472,15 +523,26 @@ class LLMEngine:
                 faults.inject("serving.prefill", rid=req.rid)
                 self._run_prefill(slot, req)
             except Exception as e:          # isolate: fail ONE request
-                self._fail(slot, e)
-        with telemetry.span("engine.schedule"):
-            if self.scheduler.running:
-                self.scheduler.ensure_decode_capacity()
+                self._drain("fault")
+                if self.scheduler.running.get(slot) is req:
+                    self._fail(slot, e)
+        while self.scheduler.running:
+            with telemetry.span("engine.schedule"):
+                fits = self.scheduler.ensure_decode_capacity(
+                    may_preempt=not self._unread())
                 self._collect_scheduler_failures()
-        step_acct = self._run_decode() if self.scheduler.running else None
+            if fits is not None:
+                break
+            # a victim's tokens in flight belong in its re-prefill
+            self._drain("preemption")
+        step = self._dispatch_decode()
+        prev, self._inflight = self._inflight, step
+        if prev is not None:
+            self._read_decode(prev)
+        self._read_prefills()
+        if self._inflight is not None:
+            self._book_dispatched(self._inflight)
         with telemetry.span("engine.account"):
-            if step_acct is not None:
-                self._account_decode(*step_acct)
             self._check_stall(had_work)
             self._sync_gauges()
             # steady-state watermark: stamp only when no request is
@@ -489,7 +551,7 @@ class LLMEngine:
             # monotonic "kv_blocks" growth and trip the leak sentinel
             if not self.scheduler.running:
                 self._mm.note_step()
-        return self.scheduler.has_work()
+        return self.has_work()
 
     def run(self):
         """Drive until every queued request has reached a terminal state
@@ -539,6 +601,7 @@ class LLMEngine:
         from . import kv_fabric
 
         self._no_frames_with_state_layers()
+        self._drain("kv_export")          # the pool at rest
         act = faults.inject("serving.kv.fetch", hashes=len(list(hashes)),
                             engine=self.engine_label)
         if act == "stale":
@@ -653,6 +716,10 @@ class LLMEngine:
             "memory": self._mm.snapshot(),
             "roofline": self._roofline_block(),
         }
+        block["decode_step"]["pipelined_step_share"] = (
+            sum(tuple(self._pipelined)) / len(self._pipelined)
+            if self._pipelined else None)
+        block["decode_step"]["drains"] = dict(self._drains)
         if self._has_state and self._state_moved["bytes_moved"]:
             block["decode_step"]["state"] = {
                 name: {"mean": sum(v) / len(v),
@@ -869,8 +936,7 @@ class LLMEngine:
                 self._failed_rids.add(req.rid)
                 self._record_lifecycle(req)
 
-    def _sweep_deadlines(self):
-        now = time.monotonic()
+    def _sweep_deadlines(self, now: float):
         for req in list(self.scheduler.waiting) + list(
                 self.scheduler.running.values()):
             if req.past_deadline(now):
@@ -1109,6 +1175,7 @@ class LLMEngine:
             tok, self.cache.pool, counters, *state = fn(*call_args)
             if state:
                 self.cache.state = tuple(state)
+            self._tokens = _put_token(self._tokens, jnp.int32(slot), tok)
         self._finish_prefill(
             slot, req, toks, tok, t0, f"P{P}",
             (("tokens", (P,), "int32"),
@@ -1156,6 +1223,7 @@ class LLMEngine:
                                          call_args)
                         if new_trace else None)
             tok, self.cache.pool, counters = fn(*call_args)
+            self._tokens = _put_token(self._tokens, jnp.int32(slot), tok)
         self._finish_prefill(
             slot, req, toks, tok, t0, bucket,
             (("tokens", (P,), "int32"),
@@ -1166,26 +1234,47 @@ class LLMEngine:
     def _finish_prefill(self, slot: int, req: Request, toks, tok,
                         t0: float, bucket: str, signature, new_trace: bool,
                         cost_est, counters):
-        """What both prefills do once the step is dispatched: book what
-        needs no result while the device runs, wait for the first token,
-        hand it on, and book the step's time. ``wall`` ends at the result
-        — at dispatch the device has only been asked."""
+        """What both prefills do once the step is dispatched and its first
+        token is in its slot's place on the device: book what needs no
+        result, and leave the token to be read once the iteration's decode
+        step is dispatched behind it (:meth:`_read_prefills`)."""
+        req.in_flight += 1
+        self._send_home(tok, counters)
+        self._unread_prefills.append(SimpleNamespace(
+            slot=slot, req=req, tok=tok, counters=counters, t0=t0,
+            signature=signature, new_trace=new_trace, cost_est=cost_est))
         with telemetry.span("engine.overlap"):
             self.cache.commit_prefix(req.rid, toks)
             self._charge_tenant(req.tenant, "prefill", bucket)
-        with telemetry.span("engine.prefill_wait"):
-            tok = int(tok)
-            counters = self._read_counters(counters)
-        wall = time.monotonic() - t0
-        with telemetry.span("engine.emit"):
-            self._emit(slot, req, tok)
-        with telemetry.span("engine.account"):
-            self._watcher.record_call(
-                "engine.prefill", signature,
-                wall_s=wall if new_trace else None, cost=cost_est)
-            for name, v in counters.items():
-                self._prefill_counters.setdefault(
-                    name, deque(maxlen=128)).append(v)
+
+    def _read_prefills(self):
+        """Wait for each dispatched prefill's first token, hand it on and
+        book the step's time. ``wall`` ends at the result — at dispatch the
+        device has only been asked. A prefill that failed on the device is
+        found here and fails its one request."""
+        unread, self._unread_prefills = self._unread_prefills, []
+        for p in unread:
+            p.req.in_flight -= 1
+            live = self.scheduler.running.get(p.slot) is p.req
+            try:
+                with telemetry.span("engine.prefill_wait"):
+                    tok = int(self._fetch(p.tok))
+                    counters = self._read_counters(p.counters)
+            except Exception as e:
+                if live:
+                    self._fail(p.slot, e)
+                continue
+            wall = time.monotonic() - p.t0
+            if live:        # else it ended while its token was in flight
+                with telemetry.span("engine.emit"):
+                    self._emit(p.slot, p.req, tok)
+            with telemetry.span("engine.account"):
+                self._watcher.record_call(
+                    "engine.prefill", p.signature,
+                    wall_s=wall if p.new_trace else None, cost=p.cost_est)
+                for name, v in counters.items():
+                    self._prefill_counters.setdefault(
+                        name, deque(maxlen=128)).append(v)
 
     # ------------------------------------------------------------------
     # decode
@@ -1220,27 +1309,50 @@ class LLMEngine:
         self._py_fns["decode"] = decode
         return self._decode_fn
 
-    # the decode StepTimeline's phases, in the order _run_decode goes
-    # through them (a failed step is attributed as far as it got); "wait"
-    # runs from the end of the dispatch to the tokens' arrival on the host
-    _DECODE_PHASES = ("assemble", "upload", "dispatch", "wait", "emit")
+    # the decode StepTimeline's phases of one step, each with the instant
+    # that ends it: the first three on the step's way out, then, an
+    # iteration later, the wait for its tokens ("wait" ends at their arrival
+    # on the host) and their emit
+    _DECODE_PHASES = (("assemble", "upload"), ("upload", "dispatch"),
+                      ("dispatch", "in_flight"), ("wait", "result"),
+                      ("emit", "emitted"))
+
+    @staticmethod
+    def _send_home(*results):
+        """Ask for a dispatched step's results on the host as soon as the
+        device has them, not when the host comes to wait for them."""
+        for x in results:
+            if x is not None:
+                x.copy_to_host_async()
+
+    @staticmethod
+    def _fetch(x):
+        """Where the host waits for a step's result."""
+        return np.asarray(x)
+
+    def _unread(self) -> bool:
+        return self._inflight is not None or bool(self._unread_prefills)
 
     def _assemble_decode(self):
-        """The running slots and the step's host-side batch: one NumPy
-        array per traced input of ``decode``, inactive slots reading one
-        garbage scratch token."""
+        """The slots the step runs and its host-side batch: one NumPy array
+        per traced input of ``decode`` past the tokens (which are on the
+        device), inactive slots reading one garbage scratch token. Lengths
+        and sampler indices count the tokens in flight; a request whose
+        last token is in flight has no row."""
         # per-slot chaos boundary: a fault targeted at one request drops
         # only that request from the batch (FAILED, error attached)
         for slot, req in sorted(self.scheduler.running.items()):
             try:
                 faults.inject("serving.decode.slot", rid=req.rid)
             except Exception as e:
-                self._fail(slot, e)
-        running = dict(self.scheduler.running)  # slot -> req snapshot
-        if not running:
-            return running, None
+                self._drain("fault")
+                if self.scheduler.running.get(slot) is req:
+                    self._fail(slot, e)
+        rows = {slot: req for slot, req in self.scheduler.running.items()
+                if req.dispatched < req.sampling.max_new_tokens}
+        if not rows:
+            return rows, None
         S = self.max_slots
-        tokens = np.zeros(S, np.int32)
         ctx = np.ones(S, np.int32)       # inactive: 1 garbage scratch token
         temps = np.zeros(S, np.float32)
         top_ks = np.zeros(S, np.int32)
@@ -1248,143 +1360,194 @@ class LLMEngine:
         seeds = np.zeros(S, np.int32)
         steps = np.zeros(S, np.int32)
         sids = [None] * S
-        for slot, req in running.items():
+        for slot, req in rows.items():
             sids[slot] = req.rid
-            tokens[slot] = (req.output_tokens[-1] if req.output_tokens
-                            else req.prompt[-1])
-            ctx[slot] = req.total_len - 1
+            ctx[slot] = len(req.prompt) + req.dispatched - 1
             temps[slot] = req.sampling.temperature
             top_ks[slot] = req.sampling.top_k
             top_ps[slot] = req.sampling.top_p
             seeds[slot] = req.sampling.seed
-            steps[slot] = len(req.output_tokens)
+            steps[slot] = req.dispatched
         bt = self.cache.table_array(sids, self.max_blocks)
-        return running, (tokens, bt, ctx, temps, top_ks, top_ps, seeds, steps)
+        return rows, (bt, ctx, temps, top_ks, top_ps, seeds, steps)
 
-    def _run_decode(self):
-        """One fused decode step over the running slots. Returns what
-        ``step``'s ``engine.account`` phase books (:meth:`_account_decode`),
-        or None when the step did not run to its end."""
-        # marks[i + 1] - marks[i] is the time of _DECODE_PHASES[i]
-        marks = [time.monotonic()]
+    def _dispatch_decode(self):
+        """Assemble, upload and dispatch one fused decode step over the
+        running slots, its tokens operand the device's own vector. Returns
+        the step in flight, or None when there was no row to run or the
+        dispatch failed (then every request of the batch has failed)."""
+        t0 = time.monotonic()
         with telemetry.span("engine.assemble"):
-            running, host = self._assemble_decode()
-        if not running:
+            rows, host = self._assemble_decode()
+        if not rows:
             return None
-        marks.append(time.monotonic())
-        new_trace = self._decode_fn is None
-        cost_est = None
-        live_share = None
-        sampled = None
-        counters = None
-        done = False
+        _, ctx, temps, *_ = host
+        step = SimpleNamespace(
+            rows=rows, ctx=ctx, temps=temps, toks=None, counters=None,
+            new_trace=self._decode_fn is None, cost_est=None,
+            live_share=None, window_share=None, sampled=None,
+            pipelined=self._inflight is not None,
+            # when each of _DECODE_PHASES began and ended
+            t={"assemble": t0, "upload": time.monotonic()})
         try:
-            try:
-                with telemetry.span("engine.upload"):
-                    self._mm.set("activations_estimate",
-                                 self._act_estimate(self.max_slots))
-                    faults.inject("serving.decode", batch=len(running))
-                    fn = self._get_decode_fn()
-                    call_args = (self.params, self.buffers, self.cache.pool,
-                                 *(jnp.asarray(a) for a in host),
-                                 *(self.cache.state or ()))
-                    if new_trace:
-                        cost_est = self._trace_cost(
-                            "decode", "decode", "decode", call_args)
-                marks.append(time.monotonic())
-                # batch-level decode ticks carry every member request's
-                # trace context so per-request merged traces include them
-                tids = [r.trace_id for r in running.values() if r.trace_id]
-                with telemetry.span("engine.decode", batch=len(running),
-                                    engine=self.engine_label,
-                                    **({"trace_ids": tids} if tids else {})):
-                    toks, self.cache.pool, counters, *state = fn(*call_args)
-                    if state:
-                        self.cache.state = tuple(state)
-                marks.append(time.monotonic())
-            except Exception as e:
-                # the fused step died: every request in the batch fails,
-                # the engine itself (and the waiting queue) survives
-                for slot in list(running):
-                    if slot in self.scheduler.running:
-                        self._fail(slot, e)
-                return None
-            # while the device runs the step: the bookkeeping that needs
-            # no result, so that none of it delays the next dispatch
-            with telemetry.span("engine.overlap"):
-                share = 1.0 / len(running)
-                for req in running.values():
-                    self._charge_tenant(req.tenant, "decode", "decode", share)
-                # how much of the running slots' block tables the paged
-                # kernel walks this step (its context is ctx + 1: the
-                # token being written counts)
-                ctx_lens = host[2][list(running)] + 1
-                live = -(-ctx_lens // self.block_size)
-                live_share = float(live.sum()) / (
-                    len(running) * self.max_blocks)
-                window_share = self._window_block_share(ctx_lens)
-                self._book_state_bytes_moved(ctx_lens)
-                # some row samples (idle slots upload temperature 0): the
-                # sampler's conditional takes its sort-and-draw branch
-                sampled = bool((host[3] > 0).any())
-                if self.prefix_cache:
-                    # a decode write that just filled its block completes
-                    # another full token-block: index it so later
-                    # admissions can share it
-                    for slot, req in running.items():
-                        if (slot in self.scheduler.running
-                                and req.total_len % self.block_size == 0):
-                            self.cache.commit_prefix(req.rid,
-                                                     req.prefill_tokens)
-            with telemetry.span("engine.decode_wait"):
-                toks = np.asarray(toks)
-                # the model's counters came with the tokens; read before
-                # that, they would make the overlap above wait for the step
-                counters = self._read_counters(counters)
-                if window_share is not None:
-                    counters["window_block_share"] = window_share
-            done = True
-        finally:
-            # the step's clocks end at the result: at dispatch the device
-            # has only been asked (failed steps are clocked too)
-            marks.append(time.monotonic())
-            self.last_decode_s = marks[-1] - marks[0]
-            self._m.decode_step.observe(self.last_decode_s)
-            if (self.watchdog_timeout_s is not None
-                    and self.last_decode_s > self.watchdog_timeout_s):
-                self.watchdog_trips += 1
-                self._m.watchdog.inc()
-                telemetry.record_event(
-                    "engine.watchdog_trip", engine=self.engine_label,
-                    decode_s=self.last_decode_s,
-                    limit_s=self.watchdog_timeout_s)
-            if not done:
-                self._account_decode(marks, len(running), live_share,
-                                     sampled, new_trace, cost_est, None)
-        with telemetry.span("engine.emit"):
-            for slot, req in running.items():
-                self._emit(slot, req, int(toks[slot]))
-        marks.append(time.monotonic())
-        return (marks, len(running), live_share, sampled, new_trace, cost_est,
-                counters)
+            with telemetry.span("engine.upload"):
+                self._mm.set("activations_estimate",
+                             self._act_estimate(self.max_slots))
+                faults.inject("serving.decode", batch=len(rows))
+                fn = self._get_decode_fn()
+                call_args = (self.params, self.buffers, self.cache.pool,
+                             self._tokens, *(jnp.asarray(a) for a in host),
+                             *(self.cache.state or ()))
+                if step.new_trace:
+                    step.cost_est = self._trace_cost(
+                        "decode", "decode", "decode", call_args)
+            step.t["dispatch"] = time.monotonic()
+            # batch-level decode ticks carry every member request's
+            # trace context so per-request merged traces include them
+            tids = [r.trace_id for r in rows.values() if r.trace_id]
+            with telemetry.span("engine.decode", batch=len(rows),
+                                engine=self.engine_label,
+                                **({"trace_ids": tids} if tids else {})):
+                (step.toks, self.cache.pool, step.counters,
+                 *state) = fn(*call_args)
+                self._tokens = step.toks
+                if state:
+                    self.cache.state = tuple(state)
+                self._send_home(step.toks, step.counters)
+            step.t["in_flight"] = time.monotonic()
+        except Exception as e:  # lint: allow-silent(_fail_rows attaches the error to every request of the batch)
+            # the fused step died on its way out: what is in flight is
+            # read, then every request in the batch fails; the engine
+            # itself (and the waiting queue) survives
+            self._drain("fault")
+            self._fail_rows(step, e)
+            self._clock_decode(step, None)      # as far as it got
+            return None
+        for req in rows.values():
+            req.in_flight += 1
+        self._pipelined.append(step.pipelined)
+        return step
 
-    def _account_decode(self, marks, n_running, live_share, sampled,
-                        new_trace, cost_est, counters):
-        """Book one decode step's time once it is known: its phases,
-        occupancy, live share of the block tables, whether a row sampled
-        and the model's own counters into the StepTimeline, and the call
-        into the compile watcher."""
-        phases = {ph: t1 - t0 for ph, t0, t1 in
-                  zip(self._DECODE_PHASES, marks, marks[1:])}
-        self._decode_tl.record_step(marks[-1] - marks[0], phases,
-                                    occupancy=n_running / self.max_slots,
-                                    live_block_share=live_share,
-                                    sampled=sampled, counters=counters)
-        self._watcher.record_call(
-            "engine.decode",
-            (("tokens", (self.max_slots,), "int32"),
-             ("block_tables", (self.max_slots, self.max_blocks), "int32")),
-            wall_s=self.last_decode_s if new_trace else None, cost=cost_est)
+    def _book_dispatched(self, step):
+        """Bookkeeping of the step just dispatched that needs no result of
+        its own, under the device's time; the tokens the step before
+        brought are on the host by now."""
+        with telemetry.span("engine.overlap"):
+            rows = step.rows
+            share = 1.0 / len(rows)
+            for req in rows.values():
+                self._charge_tenant(req.tenant, "decode", "decode", share)
+            # how much of the running slots' block tables the paged kernel
+            # walks this step (its context is ctx + 1: the token being
+            # written counts)
+            ctx_lens = step.ctx[list(rows)] + 1
+            live = -(-ctx_lens // self.block_size)
+            step.live_share = float(live.sum()) / (
+                len(rows) * self.max_blocks)
+            step.window_share = self._window_block_share(ctx_lens)
+            self._book_state_bytes_moved(ctx_lens)
+            # some row samples (idle slots upload temperature 0): the
+            # sampler's conditional takes its sort-and-draw branch
+            step.sampled = bool((step.temps > 0).any())
+            if self.prefix_cache:
+                # a decode write that fills its block completes another
+                # full token-block: index it so later admissions can share
+                # it (its last token came with the step before)
+                for slot, req in rows.items():
+                    if (self.scheduler.running.get(slot) is req
+                            and req.total_len % self.block_size == 0):
+                        self.cache.commit_prefix(req.rid, req.prefill_tokens)
+
+    def _read_decode(self, step):
+        """Wait for a dispatched step's tokens and emit them. A step that
+        failed on the device is found here: the rows of every step in
+        flight fail, the engine and the waiting queue survive."""
+        step.t["wait"] = time.monotonic()
+        toks = counters = None
+        try:
+            with telemetry.span("engine.decode_wait"):
+                toks = self._fetch(step.toks)
+                # the model's counters came with the tokens
+                counters = self._read_counters(step.counters)
+                if step.window_share is not None:
+                    counters["window_block_share"] = step.window_share
+        except Exception as e:
+            later, self._inflight = self._inflight, None
+            self._fail_rows(step, e)
+            if later is not None:       # dispatched behind it: dropped
+                self._fail_rows(later, e)
+                for req in later.rows.values():
+                    req.in_flight -= 1
+            self._tokens = jnp.zeros(self.max_slots, jnp.int32)
+        step.t["result"] = step.t["emit"] = time.monotonic()
+        with telemetry.span("engine.emit"):
+            for slot, req in step.rows.items():
+                req.in_flight -= 1
+                # a request that ended while this row was in flight (on
+                # eos_token_id, found a step late) leaves the row unread
+                if toks is not None and \
+                        self.scheduler.running.get(slot) is req:
+                    self._emit(slot, req, int(toks[slot]))
+        self._clock_decode(step, counters)
+
+    def _fail_rows(self, step, error):
+        for slot, req in step.rows.items():
+            if self.scheduler.running.get(slot) is req:
+                self._fail(slot, error)
+
+    def _clock_decode(self, step, counters):
+        """Book a decode step once its result is here (or it has failed:
+        then as far as it got). Its clocks end at the result: at dispatch
+        the device has only been asked. ``step_s`` is the interval between
+        consecutive results reaching the host, the period a client's gap is
+        made of; the watchdog and ``last_decode_s`` run from the step's
+        dispatch. Phases, occupancy, live share of the block tables,
+        whether a row sampled and the model's own counters go into the
+        StepTimeline, the call into the compile watcher."""
+        t, now = step.t, time.monotonic()   # an instant not reached: now
+        t_result = t.get("result", now)
+        since = self._last_result_t
+        step_s = t_result - (since if since is not None else t["assemble"])
+        self._last_result_t = t_result
+        self.last_decode_s = t_result - t.get("dispatch", now)
+        self._m.decode_step.observe(self.last_decode_s)
+        if (self.watchdog_timeout_s is not None
+                and self.last_decode_s > self.watchdog_timeout_s):
+            self.watchdog_trips += 1
+            self._m.watchdog.inc()
+            telemetry.record_event(
+                "engine.watchdog_trip", engine=self.engine_label,
+                decode_s=self.last_decode_s,
+                limit_s=self.watchdog_timeout_s)
+        with telemetry.span("engine.account"):
+            phases = {ph: t.get(end, now) - t.get(ph, now)
+                      for ph, end in self._DECODE_PHASES}
+            self._decode_tl.record_step(
+                step_s, phases, occupancy=len(step.rows) / self.max_slots,
+                live_block_share=step.live_share, sampled=step.sampled,
+                counters=counters)
+            self._watcher.record_call(
+                "engine.decode",
+                (("tokens", (self.max_slots,), "int32"),
+                 ("block_tables", (self.max_slots, self.max_blocks),
+                  "int32")),
+                wall_s=(t_result - t["assemble"]) if step.new_trace else None,
+                cost=step.cost_est)
+
+    def _drain(self, reason: str):
+        """Read and emit what is in flight (the decode step, then the
+        unread first tokens), so that what follows sees every token on the
+        host and goes on in the serial order. Counted by reason when there
+        was something to read."""
+        if not self._unread():
+            return
+        self._drains[reason] = self._drains.get(reason, 0) + 1
+        telemetry.record_event("engine.drain", reason=reason,
+                               engine=self.engine_label)
+        step, self._inflight = self._inflight, None
+        if step is not None:
+            self._read_decode(step)
+        self._read_prefills()
 
     def _emit(self, slot: int, req: Request, token: int):
         req.emit(token)
@@ -1421,6 +1584,11 @@ class LLMEngine:
 # uncached baseline
 # ---------------------------------------------------------------------------
 
+# one program a shape: called eagerly, the sampler's ``cond`` would be
+# compiled anew at every token
+_sample_once = jax.jit(sample_logits)
+
+
 def naive_generate(model, prompt, sampling: SamplingParams | None = None,
                    eos_token_id=None):
     """Reference decode loop with NO KV cache: every step re-runs the full
@@ -1437,8 +1605,8 @@ def naive_generate(model, prompt, sampling: SamplingParams | None = None,
             training=False)
         last = logits[0, -1]
         key = jax.random.fold_in(jax.random.PRNGKey(sp.seed), i)
-        tok = int(sample_logits(last, sp.temperature, sp.top_k, sp.top_p,
-                                key))
+        tok = int(_sample_once(last, sp.temperature, sp.top_k, sp.top_p,
+                               key))
         out.append(tok)
         toks.append(tok)
         if eos_token_id is not None and tok == eos_token_id:

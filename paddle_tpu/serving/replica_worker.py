@@ -218,7 +218,7 @@ def main() -> int:
     # OBSERVABILITY.md "Phase spans")
     while not closing:
         cmd = None
-        if not engine.scheduler.has_work():
+        if not engine.has_work():
             with telemetry.span("replica.idle"):
                 try:
                     cmd = cmds.get(timeout=0.02)
@@ -235,7 +235,7 @@ def main() -> int:
                                          on_token, emit)
         if closing:
             break
-        if engine.scheduler.has_work():
+        if engine.has_work():
             engine.step()
         with telemetry.span("replica.sweep"):
             sweep()

@@ -610,7 +610,7 @@ class LocalReplica:
             # 1) one command a turn (not blocking while the engine has
             #    work; a short block when idle so the thread doesn't spin)
             cmd = None
-            if not engine.scheduler.has_work():
+            if not engine.has_work():
                 with telemetry.span("replica.idle"):
                     try:
                         cmd = inbox.get(timeout=0.02)
@@ -629,7 +629,7 @@ class LocalReplica:
             # 2) one engine iteration
             if closing:
                 break
-            if engine.scheduler.has_work():
+            if engine.has_work():
                 try:
                     engine.step()
                 except Exception as e:     # engine itself died

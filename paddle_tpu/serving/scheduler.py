@@ -108,6 +108,10 @@ class Request:
     priority: int = 0
     state: RequestState = RequestState.WAITING
     output_tokens: list[int] = field(default_factory=list)
+    # tokens a dispatched step (a prefill, a decode step) has sampled for
+    # this request and the host has not read yet: the engine dispatches a
+    # step ahead of its readback (docs/SERVING.md "The pipelined loop")
+    in_flight: int = 0
     cached_tokens: int = 0             # prefix-cache hit at last admission
     cached_tokens_total: int = 0       # summed across (re-)admissions
     arrival_time: float = field(default_factory=time.monotonic)
@@ -134,6 +138,12 @@ class Request:
     @property
     def total_len(self) -> int:
         return len(self.prompt) + len(self.output_tokens)
+
+    @property
+    def dispatched(self) -> int:
+        """Output tokens, those in flight counted: what the next step's
+        sampler index, context length and ``max_new_tokens`` go by."""
+        return len(self.output_tokens) + self.in_flight
 
     @property
     def ttft(self) -> float | None:
@@ -344,29 +354,38 @@ class Scheduler:
         return admitted
 
     # -- decode-time capacity ---------------------------------------------
-    def ensure_decode_capacity(self) -> list[Request]:
+    def ensure_decode_capacity(self, may_preempt: bool = True
+                               ) -> list[Request] | None:
         """Before a decode step, every running sequence must own the block
         its next token writes into. On exhaustion, preempt the
         latest-arrived other running request and retry; returns the
         preempted requests (already re-queued). A sequence that cannot get
         a block even with no victims left is FAILED (not a crash): the
-        engine stays up for everyone else."""
+        engine stays up for everyone else. Lengths count the tokens in
+        flight (``Request.in_flight``); a victim's must be on the host
+        before it is re-queued, so with ``may_preempt`` false the first
+        shortage returns None with nothing preempted or failed: the engine
+        reads what is in flight and calls again (what was extended stays)."""
         preempted = []
         for slot in sorted(self.running):
             req = self.running.get(slot)
             if req is None:  # preempted/failed earlier in this very loop
                 continue
-            # the incoming token writes its K/V at position total_len - 1,
-            # so the table must cover total_len tokens AND the block it
-            # writes into must be privately owned (copy-on-write if it is
-            # shared with another sequence or the prefix index)
+            if req.dispatched >= req.sampling.max_new_tokens:
+                continue     # its last token is in flight: no further step
+            # the incoming token writes its K/V at position n - 1, so the
+            # table must cover n tokens AND the block it writes into must
+            # be privately owned (copy-on-write if it is shared with
+            # another sequence or the prefix index)
+            n = len(req.prompt) + req.dispatched
             while True:
-                ok = self.cache.extend(req.rid, req.total_len)
+                ok = self.cache.extend(req.rid, n)
                 if ok:
-                    ok = self.cache.ensure_writable(req.rid,
-                                                    req.total_len - 1)
+                    ok = self.cache.ensure_writable(req.rid, n - 1)
                 if ok:
                     break
+                if not may_preempt:
+                    return None
                 victim = self._pick_victim(exclude=req)
                 if victim is None:
                     self.fail(slot, RuntimeError(

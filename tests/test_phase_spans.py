@@ -16,16 +16,20 @@ from paddle_tpu.serving import (FleetRouter, LLMEngine, LocalReplica,
 from paddle_tpu.telemetry import reqtrace
 
 # one iteration of LocalReplica._drive without an admission, phase by
-# phase. engine.overlap (what needs no result, booked while the device
-# runs), the *_wait spans and replica.idle are left out of host-time sums.
+# phase, in the steady state of the decode pipeline: a step is dispatched,
+# then the wait and the emit are of the step dispatched an iteration ago.
+# engine.overlap (what needs no result of the step just dispatched, booked
+# while the device runs it), the *_wait spans and replica.idle are left out
+# of host-time sums.
 PLAIN = ["replica.inbox", "engine.schedule", "engine.schedule",
          "engine.assemble", "engine.upload", "engine.decode",
-         "engine.overlap", "engine.decode_wait", "engine.emit",
-         "engine.account", "replica.sweep"]
-# what an admission puts between the two engine.schedule spans
-ADMIT = ["engine.prefill", "engine.overlap", "engine.prefill_wait",
-         "engine.emit", "engine.account"]
-ORDER = set(PLAIN) | set(ADMIT) | {"replica.idle"}
+         "engine.decode_wait", "engine.emit", "engine.account",
+         "engine.overlap", "engine.account", "replica.sweep"]
+# what an admission puts between the two engine.schedule spans (its
+# dispatch), and behind the emit of the step before (its first token's read)
+ADMIT_OUT = ["engine.prefill", "engine.overlap"]
+ADMIT_BACK = ["engine.prefill_wait", "engine.emit", "engine.account"]
+ORDER = set(PLAIN) | set(ADMIT_OUT) | set(ADMIT_BACK) | {"replica.idle"}
 
 
 def _engine(late_s=0.0, **kw):
@@ -39,25 +43,22 @@ def _engine(late_s=0.0, **kw):
                     max_model_len=48, **kw)
     if late_s:
         eng.generate([[1, 2, 3]], SamplingParams(max_new_tokens=3))
-        real = eng._decode_fn
-
-        def late(*args):
-            toks, pool, counters = real(*args)
-            return _Late(toks, late_s), pool, counters
-
-        eng._decode_fn = late
+        _late(eng, late_s, ndim=1)
     return eng
 
 
-class _Late:
-    """A step's tokens whose readback takes ``delay`` seconds."""
+def _late(eng, delay, ndim):
+    """The readback of a decode step's tokens (``ndim`` 1) or of a prefill's
+    first token (0) takes ``delay`` seconds: ``LLMEngine._fetch`` is where
+    the host waits for a step's result."""
+    real = eng._fetch
 
-    def __init__(self, toks, delay):
-        self.toks, self.delay = toks, delay
+    def fetch(x):
+        if np.ndim(x) == ndim:
+            time.sleep(delay)
+        return real(x)
 
-    def __array__(self, dtype=None, copy=None):
-        time.sleep(self.delay)
-        return np.asarray(self.toks)
+    eng._fetch = fetch
 
 
 def _iterations(spans):
@@ -113,18 +114,38 @@ class TestPhaseSpansTileTheLoop:
             assert all(s.parent_id is None for s in it)
         plain = [it for it in decode
                  if not any(s.name == "engine.prefill" for s in it)]
-        assert plain
+        assert len(plain) >= 8
         for it in plain:
             assert [s.name for s in it] == PLAIN
-        # an admission puts its prefill, the wait for its first token, that
-        # token's emit and its booking before the decode's assembly
+        # an admission puts its prefill's dispatch before the decode's
+        # assembly, and the wait for its first token, that token's emit and
+        # its booking behind the emit of the step before; the iteration
+        # that fills the pipeline has no step before to wait for
         admit = [it for it in decode
                  if any(s.name == "engine.prefill" for s in it)]
         assert admit
-        for it in admit:
+        for i, it in enumerate(admit):
             names = [s.name for s in it if s.name != "replica.idle"]
             n = names.count("engine.prefill")
-            assert names == PLAIN[:2] + ADMIT * n + PLAIN[2:]
+            before = PLAIN[6:9] if "engine.decode_wait" in names else []
+            assert bool(before) == (i > 0)
+            assert names == (PLAIN[:2] + ADMIT_OUT * n + PLAIN[2:6] + before
+                             + ADMIT_BACK * n + PLAIN[9:])
+
+    def test_the_wait_is_for_the_step_dispatched_an_iteration_ago(
+            self, iterations):
+        """Every ``engine.decode`` ends before the ``engine.decode_wait``
+        of its own iteration begins, which is the wait for the step before:
+        there is one wait fewer than dispatches in the iterations that
+        dispatch, and the last step's wait is in an iteration of its own."""
+        decode = [it for it in iterations
+                  if any(s.name == "engine.decode" for s in it)]
+        waits = [it for it in iterations
+                 if any(s.name == "engine.decode_wait" for s in it)]
+        assert len(waits) == len(decode)
+        assert waits[0] is decode[1] and waits[-1] is not decode[-1]
+        tail = [s.name for s in waits[-1] if s.name != "replica.idle"]
+        assert tail == PLAIN[:4] + PLAIN[6:9] + PLAIN[10:]
 
     def test_spans_cover_the_iteration(self, iterations):
         decode = [it for it in iterations
@@ -151,13 +172,7 @@ class TestClocksEndAtTheResult:
         assert eng.watchdog_trips == 0 and 0 < eng.last_decode_s < 0.03
         h = eng._m.decode_step
         n0, sum0 = h.count, h.sum
-        real = eng._decode_fn
-
-        def late(*args):
-            toks, pool, counters = real(*args)
-            return _Late(toks, 0.05), pool, counters
-
-        eng._decode_fn = late
+        _late(eng, 0.05, ndim=1)
         telemetry.tracer().clear()
         outs = eng.generate([[1, 2, 3]], SamplingParams(max_new_tokens=4))
         assert len(outs[0]) == 4
@@ -190,25 +205,7 @@ class TestClocksEndAtTheResult:
             return record(name, signature, wall_s=wall_s, cost=cost)
 
         monkeypatch.setattr(eng._watcher, "record_call", record_call)
-        get_fn = eng._get_prefill_fn
-
-        class LateTok:
-            def __init__(self, tok):
-                self.tok = tok
-
-            def __int__(self):
-                time.sleep(0.04)
-                return int(self.tok)
-
-        def get_late_fn(P):
-            real = get_fn(P)
-
-            def late(*args):
-                tok, pool, counters = real(*args)
-                return LateTok(tok), pool, counters
-            return late
-
-        monkeypatch.setattr(eng, "_get_prefill_fn", get_late_fn)
+        _late(eng, 0.04, ndim=0)
         tr = telemetry.tracer()
         dispatches = []
         for prompt in ([1, 2, 3], [4, 5, 6]):   # first trace, then steady

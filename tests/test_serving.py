@@ -668,7 +668,7 @@ class TestDecodeSortsOnlyWhenARowSamples:
         _, host = eng._assemble_decode()
         eng._suspend_trace_counts = True
         text = eng._get_decode_fn().lower(
-            eng.params, eng.buffers, eng.cache.pool,
+            eng.params, eng.buffers, eng.cache.pool, eng._tokens,
             *map(jnp.asarray, host)).compile().as_text()
         eng.close()
         # computation name -> its lines
