@@ -52,8 +52,7 @@ from ..distributed.mp_layers import (
     VocabParallelEmbedding,
 )
 from ..nn import functional as F
-from ..nn.functional.attention import (CacheLayer, causal_window_mask,
-                                       sdpa_ref)
+from ..nn.functional.attention import CacheLayer, sdpa_window_ref
 from ..ops import manipulation as M
 from .llama import LlamaMLP, apply_rope_at
 
@@ -218,7 +217,8 @@ class LagunaAttention(nn.Layer):
         q = _apply(_rope_partial, q, cos, sin, positions, op_name="rope")
         k = _apply(_rope_partial, k, cos, sin, positions, op_name="rope")
         if cache is None:
-            out = _apply(functools.partial(_attend, window=self.window),
+            out = _apply(functools.partial(sdpa_window_ref,
+                                           window=self.window),
                          q, k, v, op_name="sdpa")
         else:
             # the cache absorbs this layer's K/V and answers attention over
@@ -229,14 +229,6 @@ class LagunaAttention(nn.Layer):
                          [B, S, self.num_heads, 1])
         out = M.reshape(out * gate, [B, S, q_sz])
         return self.o_proj(out)
-
-
-def _attend(q, k, v, window):
-    """Causal attention of a whole sequence, within ``window`` if given."""
-    if window is None:
-        return sdpa_ref(q, k, v, is_causal=True)
-    mask = causal_window_mask(q.shape[1], k.shape[1], window)
-    return sdpa_ref(q, k, v, attn_mask=mask[None, None])
 
 
 def _gated_mlp(hidden, inter):

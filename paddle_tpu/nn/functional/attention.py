@@ -19,7 +19,7 @@ from ...core.dispatch import apply
 
 __all__ = ["scaled_dot_product_attention", "flash_attention",
            "flash_attn_unpadded", "sdpa_ref", "CacheLayer", "StateLayer",
-           "causal_window_mask"]
+           "causal_window_mask", "sdpa_window_ref"]
 
 
 class CacheLayer(NamedTuple):
@@ -96,6 +96,20 @@ def sdpa_ref(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=False,
         keep = jax.random.bernoulli(key, 1.0 - dropout_p, probs.shape)
         probs = probs * keep.astype(probs.dtype) / (1.0 - dropout_p)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def sdpa_window_ref(q, k, v, is_causal=True, window=None, valid_len=None):
+    """:func:`sdpa_ref` with the queries the last ``Sq`` of ``Sk``
+    positions, causal within the latest ``window`` positions if given (a
+    query's own included). ``valid_len`` (a padded prompt's tokens) changes
+    nothing here: rows past it are finite and nobody's to read. The einsum
+    composition: it materialises ``[B, H, Sq, Sk]`` scores."""
+    if window is None:
+        return sdpa_ref(q, k, v, is_causal=is_causal)
+    if not is_causal:
+        raise ValueError("a window is a causal one")
+    mask = causal_window_mask(q.shape[1], k.shape[1], window)
+    return sdpa_ref(q, k, v, attn_mask=mask[None, None])
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,

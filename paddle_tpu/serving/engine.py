@@ -322,6 +322,8 @@ class LLMEngine:
         self._py_fns: dict = {}            # trace key -> python callable
         self.decode_traces = 0
         self.prefill_traces: dict[int, int] = {}
+        # prefill attention layers traced on each path (stats()["perf"])
+        self._prefill_attention = {"kernel": 0, "materialised": 0}
         # the KV pool is donated to every step: the step's output pool
         # reuses its buffer instead of holding two pools in device memory;
         # a model's state arrays ride at the end of a step's arguments and
@@ -731,6 +733,11 @@ class LLMEngine:
             block["prefill"] = telemetry.perf.nest_dotted(
                 {n: {"mean": sum(v) / len(v)}
                  for n, v in self._prefill_counters.items()})
+        if any(self._prefill_attention.values()):
+            # the prefill traces' attention layers by path: the flash
+            # kernel, or the einsum composition's [H, P, P] scores
+            block.setdefault("prefill", {})["attention"] = dict(
+                self._prefill_attention)
         return block
 
     # ------------------------------------------------------------------
@@ -1070,6 +1077,14 @@ class LLMEngine:
                                           4 * cfg.hidden_size)
         return int(tokens) * width * 4
 
+    def _book_attention_paths(self, view):
+        """Trace side: which path each of a prefill's attention layers took
+        (``PagedCacheView.attention_paths``), counted once a trace (the cost
+        walk's retrace not)."""
+        if not self._suspend_trace_counts:
+            for path, n in view.attention_paths.items():
+                self._prefill_attention[path] += n
+
     @staticmethod
     def _sampled_row(view, logits, length):
         """The logits a batch-1 prefill samples from: the last valid
@@ -1095,6 +1110,7 @@ class LLMEngine:
             logits, _ = functional_call(
                 model, params, buffers, tokens[None], cache=view,
                 positions=positions, training=False)
+            self._book_attention_paths(view)
             last = self._sampled_row(view, logits, length)
             key = jax.random.fold_in(jax.random.PRNGKey(seed), step_idx)
             tok = sample_logits(last, temp, top_k, top_p, key)
@@ -1132,6 +1148,7 @@ class LLMEngine:
             logits, _ = functional_call(
                 model, params, buffers, tokens[None], cache=view,
                 positions=positions, training=False)
+            self._book_attention_paths(view)
             last = self._sampled_row(view, logits, length)
             k = jax.random.fold_in(jax.random.PRNGKey(seed), step_idx)
             tok = sample_logits(last, temp, top_k, top_p, k)

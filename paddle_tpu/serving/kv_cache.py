@@ -75,7 +75,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import telemetry
-from ..nn.functional.attention import causal_window_mask
+from ..nn.functional.attention import sdpa_ref, sdpa_window_ref
 from ..utils import faults
 
 __all__ = ["BlockAllocator", "PagedKVCache", "PagedCacheView", "DenseKVCache",
@@ -994,8 +994,9 @@ class PagedCacheView:
       ``ctx_lens[s]`` through the block table and runs the ragged
       paged-attention kernel over ``ctx_lens + 1`` tokens.
     - prefill (S_new > 1, batch 1): the padded prompt; scatters whole blocks
-      into the pool and attends densely (causal) within the prompt — no pool
-      reads, so concurrent sequences are untouched.
+      into the pool and attends causally within the prompt
+      (``kernels.prefill_attention_impl``) — no pool reads, so concurrent
+      sequences are untouched.
     - tail prefill (S_new > 1 with ``prefix_block_tables``): the divergent
       tail of a prefix-cache hit; scatters the tail like prefill, then
       attends over [gathered cached prefix K/V ++ tail K/V] with the
@@ -1018,6 +1019,8 @@ class PagedCacheView:
         self.valid_len = valid_len            # prefill: tokens before padding
         self.kept_last_rows = False           # set by last_rows (prefill)
         self.counters: dict = {}
+        # prefill attention layers traced on each path (trace time)
+        self.attention_paths = {"kernel": 0, "materialised": 0}
 
     def _window(self, layer_idx):
         return None if self.windows is None else self.windows[layer_idx]
@@ -1128,6 +1131,15 @@ class PagedCacheView:
         self.pool = pool
 
     def _prefill(self, layer_idx, q, k, v):
+        """A batch-1 prompt: its whole blocks written, then causal
+        attention within it. The plain prefill takes
+        ``kernels.prefill_attention_impl`` (on the chip the flash kernel:
+        one Mosaic call, no ``[H, P, P]`` scores, no key block read outside
+        the window or past ``valid_len``; rows past it are garbage that
+        never flows back, by causality, and is never read: the engine takes
+        logits at the last valid position). The tail prefill keeps the
+        einsum composition over ``[prefix ++ tail]``: no benchmark cell
+        shares a prefix, and each asserts that its prefix counters read 0."""
         bs = self.block_size
         P = k.shape[1]
         if q.shape[0] != 1 or P % bs:
@@ -1135,21 +1147,20 @@ class PagedCacheView:
                 f"prefill expects batch 1 and a block-multiple length; got "
                 f"batch {q.shape[0]}, len {P}, block_size {bs}")
         self._write_prompt_blocks(layer_idx, k, v)
-        from ..nn.functional.attention import sdpa_ref
-
         window = self._window(layer_idx)
         if self.prefix_block_tables is None:
-            # causal within the prompt; padded tail positions produce
-            # garbage that never flows back (causality) and is never read
-            # (the engine takes logits at the last *valid* position)
-            if window is None:
-                return sdpa_ref(q, k, v, is_causal=True)
-            mask = causal_window_mask(P, P, window)
-            return sdpa_ref(q, k, v, attn_mask=mask[None, None])
+            from ..kernels import prefill_attention_impl
+
+            attend = prefill_attention_impl(P)
+            self.attention_paths[
+                "materialised" if attend is sdpa_window_ref else "kernel"] += 1
+            return attend(q, k, v, is_causal=True, window=window,
+                          valid_len=self.valid_len)
 
         # tail prefill: gather the cached prefix K/V through its block
         # table (padding entries point at scratch and are masked off by
         # prefix_len) and attend causally over [prefix ++ tail]
+        self.attention_paths["materialised"] += 1
         pbt = self.prefix_block_tables[0]                # [NPB]
         spfx = pbt.shape[0] * bs
         pkv = self.pool[layer_idx, pbt]                  # [NPB, 2, H, bs, D]
@@ -1193,12 +1204,7 @@ class DenseKVCache:
             k = jnp.concatenate([past[0], k], axis=1)
             v = jnp.concatenate([past[1], v], axis=1)
         self.layers[layer_idx] = (k, v)
-        from ..nn.functional.attention import sdpa_ref
-
-        Sq, Sk = q.shape[1], k.shape[1]
-        window = None if self.windows is None else self.windows[layer_idx]
-        if Sq == Sk and window is None:
-            return sdpa_ref(q, k, v, is_causal=True)
         # q token i sits at global position (Sk - Sq + i): attends j <= that
-        mask = causal_window_mask(Sq, Sk, window)
-        return sdpa_ref(q, k, v, attn_mask=mask[None, None])
+        return sdpa_window_ref(
+            q, k, v, window=None if self.windows is None
+            else self.windows[layer_idx])

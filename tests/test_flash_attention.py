@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from paddle_tpu.kernels.flash_attention import (
     _bwd_mirror, _flash_bhsd, _flash_fwd, _fwd_mirror, flash_attention_pallas,
 )
-from paddle_tpu.nn.functional.attention import sdpa_ref
+from paddle_tpu.nn.functional.attention import causal_window_mask, sdpa_ref
 
 
 def _rand_qkv(rng, B=2, S=64, Hq=4, Hk=4, D=16):
@@ -371,3 +371,104 @@ class TestMosaicUnderAMesh:
             fa._mesh_shard_specs(4, 4, "batch")
         with pytest.raises(ValueError, match="3 attention heads"):
             fa._mesh_shard_specs(4, 3, None)
+
+
+class TestPrefillKernel:
+    """``flash_attention_prefill``, the serving engine's prompt attention:
+    the forward kernel with a window and the prompt's valid length, GQA by
+    the index map. Interpret mode at blocks of 32, so a prompt of 128
+    spans four query and four key blocks."""
+
+    P, D, BLOCK = 128, 16, 32
+
+    @pytest.fixture(autouse=True)
+    def small_blocks(self, monkeypatch):
+        monkeypatch.setattr(fa, "PREFILL_BLOCK", self.BLOCK)
+
+    def _qkv(self, seed, rep, hk=1):
+        rng = np.random.default_rng(seed)
+        shape = (1, self.P, hk, self.D)
+        q = rng.standard_normal((1, self.P, hk * rep, self.D))
+        return tuple(jnp.asarray(x, jnp.float32) for x in (
+            q, rng.standard_normal(shape), rng.standard_normal(shape)))
+
+    @staticmethod
+    def _oracle(q, k, v, window):
+        mask = causal_window_mask(q.shape[1], k.shape[1], window)
+        return np.asarray(sdpa_ref(q, k, v, attn_mask=mask[None, None]))
+
+    # GQA 1 (two KV heads), 5, 6 and 8 (one); a window smaller than a
+    # block and one over several; a valid length at a block's edge (96)
+    # and inside one (77)
+    @pytest.mark.parametrize("valid", [96, 77])
+    @pytest.mark.parametrize("window", [None, 20, 70])
+    @pytest.mark.parametrize("rep", [1, 5, 6, 8])
+    def test_matches_sdpa_ref_on_every_valid_row(self, rep, window, valid):
+        """Every valid row as the einsum composition with
+        ``causal_window_mask``; the rows of a query block wholly past the
+        valid length are 0; K/V past the last valid key block are NaN
+        here, and no output row reads them."""
+        q, k, v = self._qkv(rep + (window or 0), rep, hk=2 if rep == 1 else 1)
+        want = self._oracle(q, k, v, window)
+        cut = -(-valid // self.BLOCK) * self.BLOCK
+        k_bad, v_bad = (x.at[:, cut:].set(jnp.nan) for x in (k, v))
+        got = np.asarray(fa.flash_attention_prefill(
+            q, k_bad, v_bad, window=window, valid_len=jnp.int32(valid)))
+        np.testing.assert_allclose(got[:, :valid], want[:, :valid],
+                                   atol=2e-5, rtol=2e-5)
+        assert np.isfinite(got).all()
+        assert not got[:, cut:].any()
+
+    def test_key_blocks_outside_the_window_are_never_read(self):
+        """For each query block, K/V of every key block wholly before its
+        window are NaN: its rows still match (a block read, even masked,
+        would put NaN into them through the zero weights)."""
+        window, rep = 40, 4
+        q, k, v = self._qkv(3, rep)
+        want = self._oracle(q, k, v, window)
+        for qb in range(self.P // self.BLOCK):
+            lo = max(qb * self.BLOCK - window + 1, 0) // self.BLOCK
+            k_bad, v_bad = (x.at[:, :lo * self.BLOCK].set(jnp.nan)
+                            for x in (k, v))
+            got = np.asarray(fa.flash_attention_prefill(
+                q, k_bad, v_bad, window=window,
+                valid_len=jnp.int32(self.P)))
+            rows = slice(qb * self.BLOCK, (qb + 1) * self.BLOCK)
+            np.testing.assert_allclose(got[:, rows], want[:, rows],
+                                       atol=2e-5, rtol=2e-5)
+
+    def test_without_a_window_or_length_it_is_the_causal_kernel(self):
+        q, k, v = self._qkv(5, 6)
+        got = fa.flash_attention_prefill(q, k, v)
+        want = flash_attention_pallas(q, k, v, is_causal=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-6, rtol=2e-6)
+
+    def test_the_trainers_kernel_lowers_as_without_the_options(
+            self, monkeypatch):
+        """At train-2k's shapes (4 x 2048, 32 query and 8 KV heads of 128,
+        bf16) the trainer's forward and both backward kernels lower, for
+        the TPU, to the same text when the prefill's options are passed as
+        their defaults; a window would change it."""
+        monkeypatch.setattr(fa, "_interpret_mode", lambda: False)
+        core = fa._core_fwd
+
+        def lowered(**options):
+            monkeypatch.setattr(fa, "_core_fwd", lambda *a: core(*a, **options))
+
+            def loss(q, k, v):
+                return jnp.sum(flash_attention_pallas(
+                    q, k, v, is_causal=True).astype(jnp.float32))
+
+            q = jax.ShapeDtypeStruct((4, 2048, 32, 128), jnp.bfloat16)
+            kv = jax.ShapeDtypeStruct((4, 2048, 8, 128), jnp.bfloat16)
+            return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
+                q, kv, kv).lower(lowering_platforms=("tpu",)).as_text()
+
+        # one call site: the kernels' debug locations hold the caller's line
+        plain, defaults, windowed = (lowered(**options) for options in (
+            {}, dict(window=None, valid_len=None, kv_rep=1, max_block=512),
+            dict(window=512)))
+        assert plain == defaults
+        assert plain.count("tpu_custom_call") == 3
+        assert plain != windowed

@@ -102,6 +102,34 @@ def test_a_prefix_cache_tail_keeps_the_window(model):
     _assert_greedy(model, second, b)
 
 
+def test_prefill_attention_through_the_kernel_serves_the_same_tokens(
+        model, monkeypatch):
+    """Prompts of 20 and 33 (buckets 32 and 64, past the window of 8),
+    greedy, through the flash kernel's forward (interpret mode, blocks of
+    16: two and four a prompt, each wider than the window) and through the
+    einsum composition: the same tokens. Each prefill trace's five
+    attention layers are counted on the path they took."""
+    from paddle_tpu import kernels
+    from paddle_tpu.kernels import flash_attention as fa
+
+    sp = SamplingParams(max_new_tokens=8, temperature=0.0)
+    prompts = _prompts(20, 33)
+    runs = {}
+    for path in ("materialised", "kernel"):
+        if path == "kernel":
+            monkeypatch.setattr(fa, "PREFILL_BLOCK", 16)
+            monkeypatch.setattr(kernels, "prefill_attention_impl",
+                                lambda length: fa.flash_attention_prefill)
+        eng = LLMEngine(model, block_size=8, max_slots=2, max_model_len=96)
+        runs[path] = eng.generate(prompts, sp)
+        assert sorted(eng.prefill_traces) == [32, 64]
+        assert eng.stats()["perf"]["prefill"]["attention"] == {
+            "kernel": 0, "materialised": 0, path: 5 * 2}
+    assert runs["kernel"] == runs["materialised"]
+    for p, o in zip(prompts, runs["kernel"]):
+        _assert_greedy(model, p, o)
+
+
 def test_dense_cache_decode_agrees_with_the_full_forward(model):
     toks = jnp.asarray([_prompts(21)[0]], jnp.int32)
     full = np.asarray(model(paddle_tpu.to_tensor(toks))._value)
@@ -165,7 +193,8 @@ def test_a_model_that_counts_nothing_adds_nothing_to_the_step(fresh_timeline):
                                              temperature=0.0))
     step = eng.stats()["perf"]["decode_step"]
     assert "moe" not in step and "window_block_share" not in step
-    assert "prefill" not in eng.stats()["perf"]
+    # a prefill's block holds the engine's own count of attention paths
+    assert set(eng.stats()["perf"]["prefill"]) == {"attention"}
     out = jax.eval_shape(
         eng._py_fns["decode"], eng.params, eng.buffers, eng.cache.pool,
         *(jnp.zeros((2,) + s, d) for s, d in (

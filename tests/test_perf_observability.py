@@ -323,7 +323,11 @@ class TestEnginePerf:
         assert all(len(o) == 4 for o in outs)
         p = eng.stats()["perf"]
         assert set(p) == {"compiles", "storms", "explain_recompile",
-                          "decode_step", "memory", "roofline"}
+                          "decode_step", "memory", "roofline", "prefill"}
+        # the prefill traces' attention layers, by path (the CPU takes the
+        # einsum composition)
+        att = p["prefill"]["attention"]
+        assert att["kernel"] == 0 and att["materialised"] > 0
         # the watcher is process-global (other suites' engines add their
         # own signatures), so assert THIS engine's exact signatures landed
         # rather than absolute counts: slots=2, max_blocks=48/8=6, and the

@@ -516,6 +516,74 @@ class TestDecodeKeepsThePoolInPlace:
         assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
 
 
+class TestPrefillAttentionIsOneKernelCall:
+    """A prompt's attention, compiled for the chip: from
+    ``PREFILL_KERNEL_MIN_LEN`` tokens on, one ``flash_attention_fwd`` Mosaic
+    call a layer and no ``[H, P, P]`` score buffer anywhere (the einsum
+    composition wrote one in bf16 and read it twice, 0.56 GB a 64-head
+    layer at P = 1024: ``jit_prefill/fusion``, PERF.md §5); below it, that
+    composition."""
+
+    # the serve cells' prefills: layers' query heads, windows, KV heads
+    CELLS = {
+        "mistral": ([32] * 8, None, 8),
+        "laguna": ([48, 64, 64, 64, 48], [None, 512, 512, 512, None], 8),
+        "falcon": ([20] * 6, None, 4),
+    }
+
+    @pytest.fixture
+    def on_the_chip(self, monkeypatch):
+        from paddle_tpu import kernels
+        from paddle_tpu.kernels import flash_attention
+
+        monkeypatch.setattr(flash_attention, "_interpret_mode", lambda: False)
+        kernels.set_use_pallas(True)
+        yield
+        kernels.set_use_pallas(None)
+
+    # 512 is under kernels.PREFILL_KERNEL_MIN_LEN: the einsum composition
+    @pytest.mark.parametrize("P", [512, 1024, 2048])
+    @pytest.mark.parametrize("cell", list(CELLS))
+    def test_one_kernel_call_a_layer_and_no_square(self, v5e_chip,
+                                                    on_the_chip, cell, P):
+        import re
+
+        from paddle_tpu.kernels import PREFILL_KERNEL_MIN_LEN
+        from paddle_tpu.serving.kv_cache import PagedCacheView
+
+        kernel = P >= PREFILL_KERNEL_MIN_LEN
+
+        heads, windows, Hkv = self.CELLS[cell]
+        L, D, bs = len(heads), 128, 16
+        N = P // bs + 1
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+        def prefill(pool, bt, valid_len, qs, k, v):
+            view = PagedCacheView(pool, bt, None, bs, windows=windows,
+                                  valid_len=valid_len)
+            x = jnp.zeros((), k.dtype)
+            for layer, q in enumerate(qs):
+                x = view.attend(layer, q + x, k + x, v + x).sum().astype(
+                    k.dtype)
+            assert view.attention_paths == {"kernel": L * kernel,
+                                            "materialised": L * (not kernel)}
+            return view.pool, x
+
+        text = jax.jit(prefill, donate_argnums=(0,)).trace(
+            sds((L, N, 2, Hkv, bs, D), jnp.bfloat16),
+            sds((1, P // bs), jnp.int32), sds((), jnp.int32),
+            [sds((1, P, h, D), jnp.bfloat16) for h in heads],
+            sds((1, P, Hkv, D), jnp.bfloat16),
+            sds((1, P, Hkv, D), jnp.bfloat16)).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+        calls = [l for l in text.splitlines()
+                 if "custom-call(" in l and "flash_attention_fwd" in l]
+        assert len(calls) == L * kernel
+        assert bool(re.search(rf"\[(\d+,)*{P},{P}\]", text)) != kernel
+
+
 class TestStateCacheStaysInPlace:
     """The per-slot state arrays of a model with state layers, beside the
     pool and held to the same structure: at Falcon-H1-34B's widths (6
