@@ -113,6 +113,15 @@ _ENGINE_IDS = itertools.count()
 _TOKEN_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
                   0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5)
 
+# stats()["perf"]["token_gaps"] and ["first_token"]: the latest gaps between
+# a request's consecutive tokens (a row each: the gap in seconds, and the
+# prefills, their padded tokens and the drains between its two reads), and
+# the latest first tokens (a row each: the three parts)
+_GAP_RING = 65536
+_FIRST_TOKEN_RING = 4096
+_GAP_CLASSES = ("plain", "prefill", "drain")
+_FIRST_TOKEN_PARTS = ("queue", "prep", "behind")
+
 
 def _engine_metrics(label: str) -> SimpleNamespace:
     """Resolve this engine's labeled children in the global registry once;
@@ -392,9 +401,28 @@ class LLMEngine:
         self._unread_prefills: list[SimpleNamespace] = []
         self._last_result_t: float | None = None
         # of the last 128 decode steps, those dispatched while the step
-        # before was unread; drains by reason (stats()["perf"])
+        # before was unread; drains by reason (stats()["perf"]), counted
+        # once the drain has read what was in flight
         self._pipelined: deque = deque(maxlen=128)
         self._drains: dict[str, int] = {}
+
+        # what each token waited for (stats()["perf"]["token_gaps"]). The
+        # device runs programs in the order they are dispatched, so a gap
+        # holds a prefill exactly when one was dispatched after the program
+        # of the earlier token and before that of the later. Every program
+        # is stamped with the prefills dispatched so far and their padded
+        # tokens (a prefill counts itself). Per slot, of its request's last
+        # read token: the read's instant, its program's two stamps and the
+        # count of drains then.
+        self._prefills = 0
+        self._prefill_padded = 0
+        self._slot_last = np.zeros((self.max_slots, 4))
+        self._gap_ring = np.zeros((_GAP_RING, 4), np.float32)
+        self._gaps_booked = 0
+        # queue, prep and behind of the latest first tokens
+        self._first_token_ring = np.zeros(
+            (_FIRST_TOKEN_RING, len(_FIRST_TOKEN_PARTS)))
+        self._first_tokens_booked = 0
 
         self.watchdog_timeout_s = watchdog_timeout_s
         self.watchdog_trips = 0
@@ -696,8 +724,9 @@ class LLMEngine:
                if self._has_state else {}),
             # performance observability (telemetry.perf): compile/retrace
             # counts per engine callable (+ any active storm with its
-            # signature diff), the decode step's phase breakdown, and the
-            # per-tag memory accounting incl. the leak sentinel
+            # signature diff), the decode step's period and shares, what
+            # each token waited for, and the per-tag memory accounting
+            # incl. the leak sentinel
             "perf": self._perf_block(),
             # per-tenant counters, roofline cost attribution and tenant
             # SLO windows (serving.tenancy.TenantAccounting.summary());
@@ -722,6 +751,8 @@ class LLMEngine:
             sum(tuple(self._pipelined)) / len(self._pipelined)
             if self._pipelined else None)
         block["decode_step"]["drains"] = dict(self._drains)
+        block["token_gaps"] = self._token_gaps_report()
+        block["first_token"] = self._first_token_report()
         if self._has_state and self._state_moved["bytes_moved"]:
             block["decode_step"]["state"] = {
                 name: {"mean": sum(v) / len(v),
@@ -739,6 +770,42 @@ class LLMEngine:
             block.setdefault("prefill", {})["attention"] = dict(
                 self._prefill_attention)
         return block
+
+    @staticmethod
+    def _percentiles(v, qs) -> dict:
+        if not len(v):
+            return {f"p{q}": None for q in qs}
+        return {f"p{q}": float(x) for q, x in zip(qs, np.percentile(v, qs))}
+
+    def _token_gaps_report(self) -> dict:
+        """The ring's gaps (seconds) by what lay between their two reads: a
+        drain, else one or more prefills, else nothing (``plain``)."""
+        n = min(self._gaps_booked, _GAP_RING)
+        ring = self._gap_ring[:n].astype(np.float64)
+        gap, prefills, padded, drains = ring.T
+        code = np.where(drains > 0, 2, np.where(prefills > 0, 1, 0))
+        out = {"gaps": n, "all": self._percentiles(gap, (50, 95, 99))}
+        for c, name in enumerate(_GAP_CLASSES):
+            sel = code == c
+            k = int(sel.sum())
+            out[name] = {"share": k / n if n else None,
+                         **self._percentiles(gap[sel], (50, 95))}
+            if name == "prefill":
+                out[name]["prefills_mean"] = (
+                    float(prefills[sel].mean()) if k else None)
+                out[name]["padded_tokens_mean"] = (
+                    float(padded[sel].mean()) if k else None)
+        return out
+
+    def _first_token_report(self) -> dict:
+        """Of the latest first tokens, the engine's time to each in three
+        parts: accepted -> admitted to a slot (``queue``), -> its prefill
+        dispatched (``prep``), -> the token read on the host (``behind``)."""
+        n = min(self._first_tokens_booked, _FIRST_TOKEN_RING)
+        ring = self._first_token_ring[:n]
+        return {"first_tokens": n,
+                **{part: self._percentiles(ring[:, i], (50, 95))
+                   for i, part in enumerate(_FIRST_TOKEN_PARTS)}}
 
     # ------------------------------------------------------------------
     # roofline cost model (telemetry.cost)
@@ -805,7 +872,7 @@ class LLMEngine:
     # ------------------------------------------------------------------
     # telemetry plumbing
     # ------------------------------------------------------------------
-    def _on_sched_event(self, kind: str, rid=None, req=None):
+    def _on_sched_event(self, kind: str, rid=None, req=None, slot=None):
         """Scheduler decisions feed this engine's labeled registry series
         (the flight-recorder events are recorded by the scheduler itself)."""
         m = self._m
@@ -819,6 +886,10 @@ class LLMEngine:
             m.rejected.inc()
         elif kind == "preempt":
             m.preemptions.inc()
+            if req is not None:
+                # its last token is on the host (a victim's is read before
+                # it is re-queued): the gap its re-prefill closes starts here
+                req.last_read_t = float(self._slot_last[slot, 0])
         elif kind == "admit" and req is not None:
             m.queue_time.observe(req.admit_time - req.arrival_time)
             # admitted-token attribution mirrors the DRR charge: the
@@ -1194,7 +1265,7 @@ class LLMEngine:
                 self.cache.state = tuple(state)
             self._tokens = _put_token(self._tokens, jnp.int32(slot), tok)
         self._finish_prefill(
-            slot, req, toks, tok, t0, f"P{P}",
+            slot, req, toks, tok, t0, f"P{P}", P,
             (("tokens", (P,), "int32"),
              ("block_table", (P // self.block_size,), "int32")),
             new_trace, cost_est, counters)
@@ -1242,23 +1313,28 @@ class LLMEngine:
             tok, self.cache.pool, counters = fn(*call_args)
             self._tokens = _put_token(self._tokens, jnp.int32(slot), tok)
         self._finish_prefill(
-            slot, req, toks, tok, t0, bucket,
+            slot, req, toks, tok, t0, bucket, P,
             (("tokens", (P,), "int32"),
              ("block_table", (P // bs,), "int32"),
              ("prefix_table", (NPB,), "int32")),
             new_trace, cost_est, counters)
 
     def _finish_prefill(self, slot: int, req: Request, toks, tok,
-                        t0: float, bucket: str, signature, new_trace: bool,
-                        cost_est, counters):
+                        t0: float, bucket: str, padded: int, signature,
+                        new_trace: bool, cost_est, counters):
         """What both prefills do once the step is dispatched and its first
         token is in its slot's place on the device: book what needs no
         result, and leave the token to be read once the iteration's decode
         step is dispatched behind it (:meth:`_read_prefills`)."""
+        t_dispatch = time.monotonic()
         req.in_flight += 1
+        self._prefills += 1
+        self._prefill_padded += padded
         self._send_home(tok, counters)
         self._unread_prefills.append(SimpleNamespace(
             slot=slot, req=req, tok=tok, counters=counters, t0=t0,
+            t_dispatch=t_dispatch,
+            stamp=(self._prefills, self._prefill_padded),
             signature=signature, new_trace=new_trace, cost_est=cost_est))
         with telemetry.span("engine.overlap"):
             self.cache.commit_prefix(req.rid, toks)
@@ -1281,17 +1357,60 @@ class LLMEngine:
                 if live:
                     self._fail(p.slot, e)
                 continue
-            wall = time.monotonic() - p.t0
+            t_read = time.monotonic()
+            first = p.req.first_token_time is None
             if live:        # else it ended while its token was in flight
                 with telemetry.span("engine.emit"):
                     self._emit(p.slot, p.req, tok)
             with telemetry.span("engine.account"):
+                if live and telemetry.enabled():
+                    self._book_prefill_token(p, t_read, first)
                 self._watcher.record_call(
                     "engine.prefill", p.signature,
-                    wall_s=wall if p.new_trace else None, cost=p.cost_est)
+                    wall_s=t_read - p.t0 if p.new_trace else None,
+                    cost=p.cost_est)
                 for name, v in counters.items():
                     self._prefill_counters.setdefault(
                         name, deque(maxlen=128)).append(v)
+
+    def _book_prefill_token(self, p, t_read: float, first: bool):
+        """A prefill's token, read at ``t_read``: a request's first token in
+        its three parts (they sum to its TTFT), or the token of a re-prefill
+        after preemption, a gap with a drain in it. Either way the slot's
+        record starts over from this read."""
+        req = p.req
+        if first:
+            at = self._first_tokens_booked % _FIRST_TOKEN_RING
+            self._first_token_ring[at] = (
+                req.admit_time - req.arrival_time,
+                p.t_dispatch - req.admit_time,
+                req.first_token_time - p.t_dispatch)
+            self._first_tokens_booked += 1
+        elif req.last_read_t is not None:
+            self._put_gaps(np.array([(t_read - req.last_read_t, 0, 0, 1)]))
+        self._slot_last[p.slot] = (t_read, *p.stamp,
+                                   sum(self._drains.values()))
+
+    def _put_gaps(self, rows):
+        """Rows of (gap, prefills, padded tokens, drains) into the ring."""
+        n = len(rows)
+        at = self._gaps_booked % _GAP_RING
+        end = at + n
+        if end <= _GAP_RING:
+            self._gap_ring[at:end] = rows
+        else:
+            self._gap_ring[at:] = rows[:_GAP_RING - at]
+            self._gap_ring[:end - _GAP_RING] = rows[_GAP_RING - at:]
+        self._gaps_booked += n
+
+    def _book_decode_gaps(self, slots, t_read: float, stamp):
+        """The gaps a decode step's rows close, read at ``t_read``: per row,
+        what lay between its request's last read and this one, from the
+        stamps in the slot's record, which then takes this step's."""
+        idx = np.array(slots)
+        now = np.array((t_read, *stamp, sum(self._drains.values())))
+        self._put_gaps(now - self._slot_last[idx])
+        self._slot_last[idx] = now
 
     # ------------------------------------------------------------------
     # decode
@@ -1325,14 +1444,6 @@ class LLMEngine:
                                   donate_argnums=self._donate_from(11))
         self._py_fns["decode"] = decode
         return self._decode_fn
-
-    # the decode StepTimeline's phases of one step, each with the instant
-    # that ends it: the first three on the step's way out, then, an
-    # iteration later, the wait for its tokens ("wait" ends at their arrival
-    # on the host) and their emit
-    _DECODE_PHASES = (("assemble", "upload"), ("upload", "dispatch"),
-                      ("dispatch", "in_flight"), ("wait", "result"),
-                      ("emit", "emitted"))
 
     @staticmethod
     def _send_home(*results):
@@ -1404,8 +1515,10 @@ class LLMEngine:
             new_trace=self._decode_fn is None, cost_est=None,
             live_share=None, window_share=None, sampled=None,
             pipelined=self._inflight is not None,
-            # when each of _DECODE_PHASES began and ended
-            t={"assemble": t0, "upload": time.monotonic()})
+            # the prefills dispatched ahead of it, and their padded tokens
+            stamp=(self._prefills, self._prefill_padded),
+            # its assembly began, its dispatch began, its tokens were read
+            t0=t0, t_dispatch=None, t_result=None)
         try:
             with telemetry.span("engine.upload"):
                 self._mm.set("activations_estimate",
@@ -1418,7 +1531,7 @@ class LLMEngine:
                 if step.new_trace:
                     step.cost_est = self._trace_cost(
                         "decode", "decode", "decode", call_args)
-            step.t["dispatch"] = time.monotonic()
+            step.t_dispatch = time.monotonic()
             # batch-level decode ticks carry every member request's
             # trace context so per-request merged traces include them
             tids = [r.trace_id for r in rows.values() if r.trace_id]
@@ -1431,14 +1544,13 @@ class LLMEngine:
                 if state:
                     self.cache.state = tuple(state)
                 self._send_home(step.toks, step.counters)
-            step.t["in_flight"] = time.monotonic()
         except Exception as e:  # lint: allow-silent(_fail_rows attaches the error to every request of the batch)
             # the fused step died on its way out: what is in flight is
             # read, then every request in the batch fails; the engine
             # itself (and the waiting queue) survives
             self._drain("fault")
             self._fail_rows(step, e)
-            self._clock_decode(step, None)      # as far as it got
+            self._clock_decode(step, None, ())      # as far as it got
             return None
         for req in rows.values():
             req.in_flight += 1
@@ -1479,7 +1591,6 @@ class LLMEngine:
         """Wait for a dispatched step's tokens and emit them. A step that
         failed on the device is found here: the rows of every step in
         flight fail, the engine and the waiting queue survive."""
-        step.t["wait"] = time.monotonic()
         toks = counters = None
         try:
             with telemetry.span("engine.decode_wait"):
@@ -1496,7 +1607,8 @@ class LLMEngine:
                 for req in later.rows.values():
                     req.in_flight -= 1
             self._tokens = jnp.zeros(self.max_slots, jnp.int32)
-        step.t["result"] = step.t["emit"] = time.monotonic()
+        step.t_result = time.monotonic()
+        read = []
         with telemetry.span("engine.emit"):
             for slot, req in step.rows.items():
                 req.in_flight -= 1
@@ -1504,29 +1616,32 @@ class LLMEngine:
                 # eos_token_id, found a step late) leaves the row unread
                 if toks is not None and \
                         self.scheduler.running.get(slot) is req:
+                    read.append(slot)
                     self._emit(slot, req, int(toks[slot]))
-        self._clock_decode(step, counters)
+        self._clock_decode(step, counters, read)
 
     def _fail_rows(self, step, error):
         for slot, req in step.rows.items():
             if self.scheduler.running.get(slot) is req:
                 self._fail(slot, error)
 
-    def _clock_decode(self, step, counters):
+    def _clock_decode(self, step, counters, read):
         """Book a decode step once its result is here (or it has failed:
         then as far as it got). Its clocks end at the result: at dispatch
         the device has only been asked. ``step_s`` is the interval between
         consecutive results reaching the host, the period a client's gap is
         made of; the watchdog and ``last_decode_s`` run from the step's
-        dispatch. Phases, occupancy, live share of the block tables,
+        dispatch. ``step_s``, occupancy, live share of the block tables,
         whether a row sampled and the model's own counters go into the
-        StepTimeline, the call into the compile watcher."""
-        t, now = step.t, time.monotonic()   # an instant not reached: now
-        t_result = t.get("result", now)
+        StepTimeline, the gaps of the rows ``read`` into the engine's ring,
+        the call into the compile watcher."""
+        now = time.monotonic()              # an instant not reached: now
+        t_result = step.t_result if step.t_result is not None else now
         since = self._last_result_t
-        step_s = t_result - (since if since is not None else t["assemble"])
+        step_s = t_result - (since if since is not None else step.t0)
         self._last_result_t = t_result
-        self.last_decode_s = t_result - t.get("dispatch", now)
+        self.last_decode_s = t_result - (
+            step.t_dispatch if step.t_dispatch is not None else now)
         self._m.decode_step.observe(self.last_decode_s)
         if (self.watchdog_timeout_s is not None
                 and self.last_decode_s > self.watchdog_timeout_s):
@@ -1537,34 +1652,35 @@ class LLMEngine:
                 decode_s=self.last_decode_s,
                 limit_s=self.watchdog_timeout_s)
         with telemetry.span("engine.account"):
-            phases = {ph: t.get(end, now) - t.get(ph, now)
-                      for ph, end in self._DECODE_PHASES}
             self._decode_tl.record_step(
-                step_s, phases, occupancy=len(step.rows) / self.max_slots,
+                step_s, occupancy=len(step.rows) / self.max_slots,
                 live_block_share=step.live_share, sampled=step.sampled,
                 counters=counters)
+            if read and telemetry.enabled():
+                self._book_decode_gaps(read, t_result, step.stamp)
             self._watcher.record_call(
                 "engine.decode",
                 (("tokens", (self.max_slots,), "int32"),
                  ("block_tables", (self.max_slots, self.max_blocks),
                   "int32")),
-                wall_s=(t_result - t["assemble"]) if step.new_trace else None,
+                wall_s=(t_result - step.t0) if step.new_trace else None,
                 cost=step.cost_est)
 
     def _drain(self, reason: str):
         """Read and emit what is in flight (the decode step, then the
         unread first tokens), so that what follows sees every token on the
         host and goes on in the serial order. Counted by reason when there
-        was something to read."""
+        was something to read; a token read after it closes a ``drain``
+        gap, one read in it not yet."""
         if not self._unread():
             return
-        self._drains[reason] = self._drains.get(reason, 0) + 1
         telemetry.record_event("engine.drain", reason=reason,
                                engine=self.engine_label)
         step, self._inflight = self._inflight, None
         if step is not None:
             self._read_decode(step)
         self._read_prefills()
+        self._drains[reason] = self._drains.get(reason, 0) + 1
 
     def _emit(self, slot: int, req: Request, token: int):
         req.emit(token)

@@ -118,6 +118,9 @@ class Request:
     admit_time: float | None = None    # first admission into a slot
     deadline: float | None = None      # absolute monotonic() cutoff
     first_token_time: float | None = None
+    # the host's read of its last token before its latest preemption: the
+    # engine's gap to the token its re-prefill samples starts there
+    last_read_t: float | None = None
     finish_time: float | None = None
     num_preemptions: int = 0
     finish_reason: str | None = None
@@ -425,7 +428,7 @@ class Scheduler:
         self.waiting.appendleft(victim)   # front: keep its progress hot
         telemetry.record_event("scheduler.preempt", rid=victim.rid,
                                slot=slot, nth=victim.num_preemptions)
-        self._on_event("preempt", rid=victim.rid)
+        self._on_event("preempt", rid=victim.rid, req=victim, slot=slot)
 
     # -- completion / removal ---------------------------------------------
     def _release_slot(self, slot: int) -> Request:

@@ -664,13 +664,16 @@ class StepTimeline:
         return _PhaseCtx(mine, name)
 
     # -- the core record (step() feeds it; tests can too) ---------------
-    def record_step(self, total_s: float, phases: dict,
+    def record_step(self, total_s: float, phases: dict | None = None,
                     occupancy: float | None = None,
                     live_block_share: float | None = None,
                     sampled: bool | None = None,
                     counters: dict | None = None):
-        """``occupancy`` is the share of the step's batch that did work
-        (the serving engine: running slots / ``max_slots``);
+        """``phases`` None books a step that has none (the serving engine's
+        decode step, which spans two iterations of its loop): no phase is
+        kept and no regression is looked for, since there is no phase to
+        name as its culprit. ``occupancy`` is the share of the step's batch
+        that did work (the serving engine: running slots / ``max_slots``);
         ``live_block_share`` the share of the running slots' block-table
         entries that hold context (what the paged kernel walks, of what a
         static grid over the table would); ``sampled`` whether some row of
@@ -685,14 +688,16 @@ class StepTimeline:
         if not ENABLED[0]:
             return    # telemetry.disable(): one flag check, like every
         total_s = float(total_s)  # other write path
-        attributed = sum(phases.values())
-        phases = dict(phases)
-        phases["other"] = max(0.0, total_s - attributed)
+        if phases is not None:
+            attributed = sum(phases.values())
+            phases = dict(phases)
+            phases["other"] = max(0.0, total_s - attributed)
         with self._lock:
-            baseline = _pct(sorted(self._totals), 0.5)
-            n_prior = len(self._totals)
+            if phases is not None:
+                baseline = _pct(sorted(self._totals), 0.5)
+                n_prior = len(self._totals)
             self._totals.append(total_s)
-            for ph, v in phases.items():
+            for ph, v in (phases or {}).items():
                 self._phases.setdefault(
                     ph, deque(maxlen=self.window)).append(v)
             for name, v in (("occupancy", occupancy),
@@ -706,6 +711,8 @@ class StepTimeline:
             self.steps += 1
         pm = _perf_metrics()
         pm.step_s.labels(timeline=self.name).observe(total_s)
+        if phases is None:
+            return
         for ph, v in phases.items():
             if v > 0:
                 pm.phase_s.labels(timeline=self.name, phase=ph).observe(v)
@@ -746,11 +753,12 @@ class StepTimeline:
                            "p90": _pct(totals, 0.9),
                            "p99": _pct(totals, 0.99),
                            "mean": total_sum / len(totals)},
-                "phases": {},
-                "regressions": self.regressions,
-                "last_regression": (dict(self.last_regression)
-                                    if self.last_regression else None),
             }
+            if self._phases:       # a timeline whose steps have phases
+                out["phases"] = {}
+                out["regressions"] = self.regressions
+                out["last_regression"] = (dict(self.last_regression)
+                                          if self.last_regression else None)
             for ph, d in self._phases.items():
                 vals = sorted(d)
                 s = sum(vals)

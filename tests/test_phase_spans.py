@@ -187,7 +187,6 @@ class TestClocksEndAtTheResult:
         assert all(s.duration >= 0.05 for s in tr.find("engine.decode_wait"))
         assert all(s.duration < 0.03 for s in tr.find("engine.decode"))
         tl = eng.stats()["perf"]["decode_step"]
-        assert tl["phases"]["wait"]["p99"] >= 0.05
         assert tl["step_s"]["p99"] >= 0.05
 
     def test_prefill_wall_ends_at_the_first_token(self, monkeypatch):
