@@ -11,7 +11,8 @@ gshard(top-2 + aux loss).
 
 :class:`SparseMoELayer` is the no-drop form that serving uses: top-k of all
 experts per token, no capacity, rows sorted by expert and multiplied by the
-experts this chip holds through ``kernels.moe_grouped_matmul``.
+experts this chip holds through ``kernels.moe_grouped_matmul`` (on a TPU
+one Mosaic call for the experts' whole gated MLP).
 """
 from __future__ import annotations
 
@@ -158,10 +159,12 @@ class SparseMoELayer(nn.Layer):
     ``experts_held = (first, count)`` says which experts live here (all by
     default). The layer routes over all of them and computes the part of the
     result that its own experts give: the (token, expert) rows of the held
-    experts are sorted by expert, multiplied group by group
-    (``kernels.moe_grouped_matmul``: no weight of an expert without rows is
-    read) and added back into their tokens by weight; rows of experts held
-    elsewhere add nothing. With every expert held that is the whole layer.
+    experts are sorted by expert, passed group by group through their
+    experts' gated MLP (``kernels.moe_grouped_matmul.moe_gated_mlp_impl``:
+    on a TPU one Mosaic call, which reads no weight of an expert without
+    rows; :attr:`fused` says whether this layer takes it) and added back
+    into their tokens by weight; rows of experts held elsewhere add
+    nothing. With every expert held that is the whole layer.
     What the shares of several chips add up to is the sum of their results;
     the exchange that would form it is not this layer's.
 
@@ -194,11 +197,24 @@ class SparseMoELayer(nn.Layer):
         if shared_expert is not None:
             self.shared_expert = shared_expert
 
-    def forward(self, x, row_mask=None):
-        from ..kernels.moe_grouped_matmul import moe_grouped_matmul
+    def _gated_mlp(self):
+        from ..kernels.moe_grouped_matmul import moe_gated_mlp_impl
 
+        _, d_expert, d_model = self.down_proj.shape
+        return moe_gated_mlp_impl(d_model, d_expert)
+
+    @property
+    def fused(self) -> bool:
+        """Whether the routed experts' gated MLP runs as the one kernel
+        call (on a TPU, for widths that tile) or as the plain form."""
+        from ..kernels.moe_grouped_matmul import moe_gated_mlp_ref
+
+        return self._gated_mlp() is not moe_gated_mlp_ref
+
+    def forward(self, x, row_mask=None):
         k, scaling = self.top_k, self.routed_scaling
         first, count = self.experts_held
+        gated_mlp = self._gated_mlp()
 
         def body(xv, wr, wgu, wd, mask=None):
             d = xv.shape[-1]
@@ -215,9 +231,7 @@ class SparseMoELayer(nn.Layer):
             order = jnp.argsort(flat, stable=True)
             sizes = jnp.bincount(flat, length=count + 1)[:count]
             rows = xf[order // k]                                # [n k, d]
-            gate, up = jnp.split(
-                moe_grouped_matmul(rows, wgu, sizes), 2, axis=-1)
-            y = moe_grouped_matmul(jax.nn.silu(gate) * up, wd, sizes)
+            y = gated_mlp(rows, wgu, wd, sizes)
             y = y[jnp.argsort(order)].reshape(n, k, d)           # by token
             out = jnp.sum(w[..., None] * y.astype(jnp.float32), axis=1)
             if mask is None:

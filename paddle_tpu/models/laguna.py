@@ -238,7 +238,8 @@ def _gated_mlp(hidden, inter):
 
 class LagunaSparseMLP(SparseMoELayer):
     """The sparse layer in a decoder: ``[B, S, H] -> [B, S, H]``, and a
-    serving step's cache view is told what was routed where."""
+    serving step's cache view is told what was routed where and which path
+    the experts' gated MLP took."""
 
     def __init__(self, config: LagunaConfig):
         c = config
@@ -256,6 +257,7 @@ class LagunaSparseMLP(SparseMoELayer):
 
         out, load = super().forward(
             x, row_mask=Tensor._wrap(cache.live_rows(tuple(x.shape[:2]))))
+        cache.moe_paths["fused" if self.fused else "plain"] += 1
         load = load._value.astype(jnp.float32)
         pairs, held = jnp.sum(load), load.shape[0]
         cache.count(**{

@@ -333,6 +333,9 @@ class LLMEngine:
         self.prefill_traces: dict[int, int] = {}
         # prefill attention layers traced on each path (stats()["perf"])
         self._prefill_attention = {"kernel": 0, "materialised": 0}
+        # sparse layers traced on each path of their experts' gated MLP
+        self._moe_paths = {program: {"fused": 0, "plain": 0}
+                           for program in ("decode", "prefill")}
         # the KV pool is donated to every step: the step's output pool
         # reuses its buffer instead of holding two pools in device memory;
         # a model's state arrays ride at the end of a step's arguments and
@@ -769,6 +772,13 @@ class LLMEngine:
             # kernel, or the einsum composition's [H, P, P] scores
             block.setdefault("prefill", {})["attention"] = dict(
                 self._prefill_attention)
+        for program, paths in self._moe_paths.items():
+            if any(paths.values()):
+                # the traces' sparse layers by path: the experts' gated MLP
+                # as one kernel call, or the plain form's two products
+                at = block["decode_step"] if program == "decode" else \
+                    block.setdefault("prefill", {})
+                at.setdefault("moe", {})["gated_mlp"] = dict(paths)
         return block
 
     @staticmethod
@@ -1148,13 +1158,18 @@ class LLMEngine:
                                           4 * cfg.hidden_size)
         return int(tokens) * width * 4
 
-    def _book_attention_paths(self, view):
-        """Trace side: which path each of a prefill's attention layers took
-        (``PagedCacheView.attention_paths``), counted once a trace (the cost
-        walk's retrace not)."""
-        if not self._suspend_trace_counts:
+    def _book_paths(self, view, program):
+        """Trace side: which path each of a prefill's attention layers and
+        each sparse layer of ``program`` ("decode" or "prefill") took
+        (``PagedCacheView.attention_paths``, ``.moe_paths``), counted once a
+        trace (the cost walk's retrace not)."""
+        if self._suspend_trace_counts:
+            return
+        if program == "prefill":
             for path, n in view.attention_paths.items():
                 self._prefill_attention[path] += n
+        for path, n in view.moe_paths.items():
+            self._moe_paths[program][path] += n
 
     @staticmethod
     def _sampled_row(view, logits, length):
@@ -1181,7 +1196,7 @@ class LLMEngine:
             logits, _ = functional_call(
                 model, params, buffers, tokens[None], cache=view,
                 positions=positions, training=False)
-            self._book_attention_paths(view)
+            self._book_paths(view, "prefill")
             last = self._sampled_row(view, logits, length)
             key = jax.random.fold_in(jax.random.PRNGKey(seed), step_idx)
             tok = sample_logits(last, temp, top_k, top_p, key)
@@ -1219,7 +1234,7 @@ class LLMEngine:
             logits, _ = functional_call(
                 model, params, buffers, tokens[None], cache=view,
                 positions=positions, training=False)
-            self._book_attention_paths(view)
+            self._book_paths(view, "prefill")
             last = self._sampled_row(view, logits, length)
             k = jax.random.fold_in(jax.random.PRNGKey(seed), step_idx)
             tok = sample_logits(last, temp, top_k, top_p, k)
@@ -1431,6 +1446,7 @@ class LLMEngine:
             logits, _ = functional_call(
                 model, params, buffers, tokens[:, None], cache=view,
                 positions=ctx[:, None], training=False)
+            self._book_paths(view, "decode")
             # in the model's dtype: the sampler casts, behind a barrier
             last = logits[:, -1]
             keys = jax.vmap(

@@ -1021,6 +1021,9 @@ class PagedCacheView:
         self.counters: dict = {}
         # prefill attention layers traced on each path (trace time)
         self.attention_paths = {"kernel": 0, "materialised": 0}
+        # sparse layers traced on each path of their experts' gated MLP
+        # (trace time; the layer books itself)
+        self.moe_paths = {"fused": 0, "plain": 0}
 
     def _window(self, layer_idx):
         return None if self.windows is None else self.windows[layer_idx]

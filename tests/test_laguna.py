@@ -160,7 +160,10 @@ def test_the_models_counters_reach_stats(model, fresh_timeline):
     step = eng.stats()["perf"]["decode_step"]
     moe = step["moe"]
     assert set(moe) == {"experts_touched_share", "expert_load_max_over_mean",
-                        "routed_pairs"}
+                        "routed_pairs", "gated_mlp"}
+    # the sparse layers of the traces, by path (the CPU takes the plain
+    # form): four of five layers are sparse
+    assert moe["gated_mlp"] == {"fused": 0, "plain": 4 * eng.decode_traces}
     # two of four slots run: 2 tokens x top-2 of 8 experts a sparse layer
     assert moe["routed_pairs"]["p50"] == 4.0
     assert 2 / 8 <= moe["experts_touched_share"]["mean"] <= 4 / 8
@@ -169,6 +172,40 @@ def test_the_models_counters_reach_stats(model, fresh_timeline):
     # a prompt's padding rows are routed but are nobody's load
     assert pre["routed_pairs"]["mean"] == (9 + 30) / 2 * 2
     assert 0 < pre["experts_touched_share"]["mean"] <= 1.0
+    assert pre["gated_mlp"] == {
+        "fused": 0, "plain": 4 * sum(eng.prefill_traces.values())}
+
+
+def test_the_experts_through_the_fused_kernel_serve_the_same_tokens(
+        model, monkeypatch, fresh_timeline):
+    """The sparse layers' gated MLP as the one kernel call (interpret
+    mode) and as the plain form: the same greedy tokens, and each decode
+    and prefill trace's four sparse layers counted on the path they
+    took, beside the model's own counters."""
+    from paddle_tpu.kernels import moe_grouped_matmul as gmm
+
+    sp = SamplingParams(max_new_tokens=6, temperature=0.0)
+    prompts = _prompts(9, 30)
+    runs = {}
+    for path in ("plain", "fused"):
+        if path == "fused":
+            monkeypatch.setattr(gmm, "moe_gated_mlp_impl",
+                                lambda d_model, d_expert:
+                                gmm.moe_gated_mlp_pallas)
+        eng = LLMEngine(model, block_size=8, max_slots=2, max_model_len=96)
+        runs[path] = eng.generate(prompts, sp)
+        perf = eng.stats()["perf"]
+        other = "plain" if path == "fused" else "fused"
+        for block, traces in ((perf["decode_step"], eng.decode_traces),
+                              (perf["prefill"],
+                               sum(eng.prefill_traces.values()))):
+            assert traces >= 1
+            assert block["moe"]["gated_mlp"] == {path: 4 * traces, other: 0}
+            assert "experts_touched_share" in block["moe"]
+    assert runs["fused"] == runs["plain"]
+    monkeypatch.undo()     # the uncached forward below runs with the tape
+    for p, o in zip(prompts, runs["fused"]):
+        _assert_greedy(model, p, o)
 
 
 def test_window_block_share_follows_the_contexts(model, fresh_timeline):

@@ -837,30 +837,76 @@ class TestDecodeSortsOnlyWhenARowSamples:
 
 
 # ---------------------------------------------------------------------------
-# grouped matrix product of the sparse experts (compiled for the chip here,
-# beside the other topology compiles; its numerics are in
+# the sparse experts' gated MLP (compiled for the chip here, beside the
+# other topology compiles; its numerics are in
 # tests/test_moe_grouped_matmul.py)
 # ---------------------------------------------------------------------------
 
 class TestGroupedMatmulCompiles:
-    # Laguna-XS.2: 256 experts, hidden 2048, expert width 512 (gate|up 1024)
+    """Laguna-XS.2's widths (256 experts, hidden 2048, expert width 512):
+    the experts' whole gated MLP is one ``moe_grouped_matmul`` Mosaic call,
+    alone and in a ``SparseMoELayer`` (which ran two before)."""
+
+    E, K, I = 256, 2048, 512
+
+    @pytest.fixture
+    def on_the_chip(self, monkeypatch):
+        from paddle_tpu import kernels
+        from paddle_tpu.kernels import moe_grouped_matmul
+
+        monkeypatch.setattr(moe_grouped_matmul, "_interpret_mode",
+                            lambda: False)
+        kernels.set_use_pallas(True)
+        yield
+        kernels.set_use_pallas(None)
+
+    @staticmethod
+    def _calls(text):
+        return sum("moe_grouped_matmul" in l and "tpu_custom_call" in l
+                   for l in text.splitlines())
+
     @pytest.mark.parametrize("rows", [256, 4096, 16384])   # decode, prefills
-    @pytest.mark.parametrize("k,n", [(2048, 1024), (512, 2048)])
-    def test_compiles_at_published_shapes_for_v5e(self, v5e_chip, rows, k, n):
+    def test_compiles_at_published_shapes_for_v5e(self, v5e_chip, rows):
         from paddle_tpu.kernels.moe_grouped_matmul import (
-            moe_grouped_matmul_pallas)
+            moe_gated_mlp_pallas)
 
         def sds(shape, dtype):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
 
-        fn = jax.jit(lambda *a: moe_grouped_matmul_pallas(
-            *a, interpret=False))
+        fn = jax.jit(lambda *a: moe_gated_mlp_pallas(*a, interpret=False))
         lowered = fn.trace(
-            sds((rows, k), jnp.bfloat16), sds((256, k, n), jnp.bfloat16),
-            sds((256,), jnp.int32)).lower(lowering_platforms=("tpu",))
-        text = lowered.as_text()
-        assert sum("moe_grouped_matmul" in l and "tpu_custom_call" in l
-                   for l in text.splitlines()) == 1
+            sds((rows, self.K), jnp.bfloat16),
+            sds((self.E, self.K, 2 * self.I), jnp.bfloat16),
+            sds((self.E, self.I, self.K), jnp.bfloat16),
+            sds((self.E,), jnp.int32)).lower(lowering_platforms=("tpu",))
+        assert self._calls(lowered.as_text()) == 1
+        lowered.compile()
+
+    @pytest.mark.parametrize("tokens", [32, 2048])         # decode, prefill
+    def test_a_sparse_layer_is_one_call(self, v5e_chip, on_the_chip, tokens):
+        from paddle_tpu.distributed.moe import SparseMoELayer
+        from paddle_tpu.nn.layer import functional_call
+
+        # built with two experts, traced with all 256 held: the parameters
+        # are shapes only, never made
+        layer = SparseMoELayer(self.K, self.I, self.E, 8,
+                               experts_held=(0, 2))
+        layer.experts_held = (0, self.E)
+        shapes = {"router.weight": (self.K, self.E),
+                  "gate_up_proj": (self.E, self.K, 2 * self.I),
+                  "down_proj": (self.E, self.I, self.K)}
+        assert {n for n, _ in layer.named_parameters()} == set(shapes)
+        assert layer.fused
+
+        def sds(shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+        fn = jax.jit(lambda params, x: functional_call(
+            layer, params, {}, x)[0][0])
+        lowered = fn.trace({n: sds(s) for n, s in shapes.items()},
+                           sds((tokens, self.K))).lower(
+            lowering_platforms=("tpu",))
+        assert self._calls(lowered.as_text()) == 1
         lowered.compile()
 
 
