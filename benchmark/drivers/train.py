@@ -1,6 +1,7 @@
 """Train driver: the program's pipeline trainer's ``step`` on a one-mesh job,
 as ``chip_smoke.train_leg`` builds it, with steps enqueued back to back for
-the window (fresh device-staged batches, rotated, as ``bench.py::_make_bufs``).
+the window on batches staged on the device before it and taken in turn
+(``stage_batches``).
 Which trainer, and how its state lays the weights' leaves out, is the
 configuration's architecture's to say (``ctx.arch``: ``build_trainer``,
 ``shapes``, ``trainer_layout``, ``trainer_leaf_norms``).

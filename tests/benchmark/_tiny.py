@@ -18,6 +18,20 @@ def _edit(path, fn):
         json.dump(doc, f)
 
 
+def block(entries, names):
+    """The entries named ``names``, which stand together in ``entries`` in
+    that order, wherever the list puts them. Later PRs append to every list
+    of BENCHMARK.json, so a test holds a block and its members, never a
+    list's last entries."""
+    listed = [e["name"] for e in entries]
+    missing = [n for n in names if listed.count(n) != 1]
+    assert not missing, f"{missing} are not each listed once"
+    at = listed.index(names[0])
+    assert listed[at:at + len(names)] == list(names), \
+        f"{list(names)} do not stand together in this order"
+    return entries[at:at + len(names)]
+
+
 def copy_root(dst, src=ROOT):
     """Copy ``src``'s BENCHMARK.json and benchmark/ to ``dst`` as they are."""
     dst = str(dst)

@@ -21,7 +21,6 @@ from benchmark.lib import harness, peaks as peaks_mod, spec as spec_mod
 from benchmark.lib import trace as T, weights
 
 import _tiny
-import test_benchmark_program_readers as pinned
 
 CELL, CONFIG, MIX = ("laguna-xs2-decode-closed", "laguna-xs.2-serve-l5",
                      "decode-closed-1k")
@@ -96,65 +95,6 @@ def test_the_cell_and_its_mix_are_as_the_issue_names_them():
             "paged_attention_roofline", "ttft_p50_ms.closed",
             "decode_batch_occupancy", "engine_host_ms_per_step"} <= names
     assert not {"ttft_p90_ms.open", "loadgen_lag_p95_ms", "train_mfu"} & names
-
-
-def _the_nine(doc):
-    """The nine program-span and program-counter metrics that
-    test_benchmark_program_readers.py names, where BENCHMARK.json has them,
-    and what later PRs appended after them."""
-    names = [m["name"] for m in doc["per_layer"]]
-    at = sorted(names.index(n) for n in pinned.NINE)
-    assert at == list(range(at[0], at[0] + 9))      # one block, none missing
-    return doc["per_layer"][at[0]:at[0] + 9], doc["per_layer"][at[0] + 9:]
-
-
-def test_the_nine_as_they_were_pinned(tmp_path, monkeypatch):
-    """test_the_nine_are_serve_metrics_added_at_the_end itself, unedited, on
-    BENCHMARK.json less what later PRs appended: the entries after the nine
-    and the cells after the two on the nine's lists. (On the whole file it
-    is expected to fail: tests/benchmark/conftest.py.) So all it held of the
-    nine, names, layers, sources, cells, still holds, and the only thing
-    that fails it is that the lists grew at their ends."""
-    with open(os.path.join(_tiny.ROOT, "BENCHMARK.json")) as f:
-        doc = json.load(f)
-    nine, later = _the_nine(doc)
-    assert later and all(m["name"] not in pinned.NINE for m in later)
-    doc["per_layer"] = doc["per_layer"][:-len(later)]
-    for m in nine:
-        assert m["workloads"][:2] == pinned.SERVE
-        m["workloads"] = m["workloads"][:2]
-    with open(tmp_path / "BENCHMARK.json", "w") as f:
-        json.dump(doc, f)
-    os.symlink(os.path.join(_tiny.ROOT, "benchmark"), tmp_path / "benchmark")
-    monkeypatch.setattr(_tiny, "ROOT", str(tmp_path))
-    pinned.test_the_nine_are_serve_metrics_added_at_the_end()
-
-
-def test_the_nine_are_read_in_every_serve_cell():
-    """The same assertions on the file as it is, for any number of serve
-    cells: the nine stand together where they were added, each lists every
-    serve cell (the two it had first, then the later ones in the cells'
-    order) and no other, its layer is its metric file's, its source names
-    its reader's kind; no train cell reports one, every serve cell reports
-    all."""
-    nine, _ = _the_nine(SPEC.doc)
-    assert {m["name"] for m in nine} == pinned.NINE
-    serve = [c["name"] for c in SPEC.doc["workloads"]
-             if SPEC.config(c["config"])["driver"] == "serve"]
-    assert serve[:2] == pinned.SERVE and CELL in serve[2:]
-    for m in nine:
-        assert m["workloads"] == serve
-        mdoc = SPEC.load_json("metrics", m["name"])
-        assert mdoc["layer"] == m["layer"]
-        assert m["source"] == {"span_time_per": "program_span",
-                               "program_counter": "program_counter"}[
-            mdoc["reader"]]
-    for c in SPEC.doc["workloads"]:
-        reported = {m["name"] for m in SPEC.per_layer(c["name"])}
-        if c["name"] in serve:
-            assert pinned.NINE <= reported
-        else:
-            assert not pinned.NINE & reported
 
 
 def test_leaves_and_bytes_at_the_published_widths():

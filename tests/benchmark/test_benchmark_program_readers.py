@@ -5,8 +5,6 @@ made by hand; then the nine metrics that use them, from the facts of a tiny
 serve run. Each reads only with a ``peaks`` (a chip): a host time from a CPU
 is no more a rate than a device time is."""
 import io
-import json
-import os
 
 import pytest
 
@@ -19,12 +17,13 @@ import _tiny
 D = "/device:TPU:0"
 PEAKS = {"bf16_flops_per_s": 1e12, "int8_ops_per_s": 2e12,
          "hbm_bytes_per_s": 1e11, "hbm_bytes": 1e9, "source": "a stand-in"}
-NINE = {"engine_host_ms_per_step", "engine_prepare_ms_per_step",
+# in the order BENCHMARK.json lists them
+NINE = ("engine_host_ms_per_step", "engine_prepare_ms_per_step",
         "engine_emit_ms_per_step", "replica_loop_ms_per_step",
         "decode_step_wall_ms", "decode_batch_occupancy",
         "engine_queue_wait_ms_p95", "frontdoor_admit_ms_mean",
-        "token_relay_ms_mean"}
-SERVE = ["mistral7b-decode-closed", "mistral7b-chat-open"]
+        "token_relay_ms_mean")
+SERVE = ["mistral7b-decode-closed", "mistral7b-chat-open"]   # the first two
 
 # two iterations of the replica loop, the second with an admission; ns
 HAND = {
@@ -138,22 +137,31 @@ def test_without_a_chip_nothing_is_read(name):
         assert reader.read(dict(facts, peaks=PEAKS), **args) > 0
 
 
-def test_the_nine_are_serve_metrics_added_at_the_end():
-    with open(os.path.join(_tiny.ROOT, "BENCHMARK.json")) as f:
-        doc = json.load(f)
-    added = doc["per_layer"][-9:]
-    assert {m["name"] for m in added} == NINE
+@pytest.mark.parametrize(
+    "cell", [c["name"] for c in spec_mod.Spec(_tiny.ROOT).doc["workloads"]])
+def test_the_nine_are_serve_metrics_added_at_the_end(cell):
+    """The nine stand together in ``per_layer`` in the order they were added,
+    wherever later PRs' entries put them; each lists every serve cell (the
+    two it had first, then the later ones in the cells' order) and no
+    other, its layer is its metric file's, its source names its reader's
+    kind. ``cell`` reports all nine if it is a serve cell, none if not."""
     spec = spec_mod.Spec(_tiny.ROOT)
-    for m in added:
-        assert m["workloads"] == SERVE
+    serve = [c["name"] for c in spec.doc["workloads"]
+             if spec.config(c["config"])["driver"] == "serve"]
+    assert serve[:2] == SERVE
+    for m in _tiny.block(spec.doc["per_layer"], NINE):
+        assert m["workloads"] == serve, \
+            f"{m['name']} lists {m['workloads']}, not every serve cell {serve}"
         mdoc = spec.load_json("metrics", m["name"])
         assert mdoc["layer"] == m["layer"]
         assert m["source"] == {"span_time_per": "program_span",
                                "program_counter": "program_counter"}[
             mdoc["reader"]]
-    assert not NINE & {m["name"] for m in spec.per_layer("mistral7b-train-2k")}
-    for cell in SERVE:
-        assert NINE <= {m["name"] for m in spec.per_layer(cell)}
+    reported = {m["name"] for m in spec.per_layer(cell)}
+    if cell in serve:
+        assert set(NINE) <= reported
+    else:
+        assert not set(NINE) & reported
 
 
 @pytest.fixture
@@ -185,7 +193,7 @@ def test_a_tiny_serve_run_yields_all_nine_with_a_stand_in_peaks(
                                root=root, require_chip=False, out=out)
     assert rc == 0 and res["correct"] is True
     got = {k: v["value"] for k, v in res["metrics"].items()}
-    assert NINE <= set(got)
+    assert set(NINE) <= set(got)
     assert all(got[k] > 0 for k in NINE)
     assert got["engine_host_ms_per_step"] == pytest.approx(
         got["engine_prepare_ms_per_step"] + got["engine_emit_ms_per_step"]

@@ -88,3 +88,21 @@ def test_each_names_a_layer_the_benchmark_names():
         assert SPEC.load_json("metrics", name)["layer"] == layer
         assert layer in layers
         assert moves in {m["name"] for m in SPEC.doc["end_to_end"]}
+
+
+def test_the_four_are_one_block_that_every_serve_cell_reports():
+    """Appended to ``per_layer`` together and with no ``workloads`` list:
+    each cell that reports the end-to-end metric one moves reports it, so
+    every serve cell does, a later one too, and no train cell."""
+    for m in _tiny.block(SPEC.doc["per_layer"], list(FOUR)):
+        _, _, layer, moves = FOUR[m["name"]]
+        assert "workloads" not in m
+        assert (m["layer"], m["moves"], m["better"], m["source"]) == (
+            layer, moves, "lower", "program_counter")
+        assert m["unit"] == ("%" if m["name"].endswith("_share") else "ms")
+    for c in SPEC.doc["workloads"]:
+        reported = {m["name"] for m in SPEC.per_layer(c["name"])}
+        if SPEC.config(c["config"])["driver"] == "serve":
+            assert set(FOUR) <= reported, c["name"]
+        else:
+            assert not set(FOUR) & reported, c["name"]
